@@ -29,7 +29,7 @@ package never imports tools/):
 * exemplar sizes are deliberately small — the IR rules check dtypes,
   primitives and constants, none of which depend on the exemplar's row
   count staying production-sized;
-* entries are traced under `jax.experimental.enable_x64` so weak-type
+* entries are traced under `jax.enable_x64` so weak-type
   float64 promotions (an np.float64 constant leaking into f32 device
   code) become VISIBLE instead of being silently squashed by the
   default x64-off config.

@@ -238,8 +238,10 @@ def _task_serve_fleet(cfg: Config, params: Dict[str, str]) -> None:
     """Serving fault domain (docs/Serving.md fleet section):
     `python -m lightgbm_tpu serve-fleet serve_models=m=model.txt
     serve_replicas=3 serve_port=0`.  Spawns `serve_replicas` replica
-    daemons (each a supervised task=serve child with its own device
-    context and ready file), health-checks them, and fronts them with
+    daemons (each a supervised task=serve child with its own ready
+    file; with more than one they run on the CPU, so the command needs
+    JAX_PLATFORMS=cpu in its environment — serving/fleet.py says why),
+    health-checks them, and fronts them with
     the retry/shed/canary router on `serve_port`.  SIGTERM drains the
     WHOLE fleet: the router stops accepting, every replica gets its own
     SIGTERM drain (each exits 143), and the runner re-delivers — exit
@@ -278,7 +280,6 @@ def _task_serve_fleet(cfg: Config, params: Dict[str, str]) -> None:
         workdir=workdir, params=replica_params,
         max_restarts=cfg.serve_max_replica_restarts,
         health_interval_s=cfg.serve_health_interval_s,
-        force_cpu=os.environ.get("LGBM_TPU_SERVE_FORCE_CPU") == "1",
     ).start()
     router = Router(fleet, cfg)
     for name, path in entries:
@@ -542,6 +543,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         argv = [f"task={argv[0]}"] + list(argv[1:])
     params = parse_args(argv)
     cfg = Config(dict(params))
+    from .observability import configure_compile_cache
+    configure_compile_cache(cfg.compile_cache_dir)
     _maybe_init_distributed(cfg)
     task = cfg.task
     handlers = {"train": _task_train, "predict": _task_predict,

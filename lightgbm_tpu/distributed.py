@@ -19,7 +19,11 @@ Two entry points:
   trains tree_learner=data across them, and returns the rank-0 model as
   a Booster.  Every worker loads the full host-side arrays (GSPMD owns
   the row sharding; workers' models are identical by construction —
-  tests/test_multiprocess.py pins this).
+  tests/test_multiprocess.py pins this).  The local workers run on the
+  CPU: a chip belongs to one process at a time and nothing assigns a
+  chip to a worker, so several chip-holding workers on one host are
+  refused.  To train over all local chips, call `lgb.train` with
+  `tree_learner=data` in ONE process — it drives every chip of the host.
 """
 
 from __future__ import annotations
@@ -160,8 +164,6 @@ if spec.get("heartbeat_dir"):
         spec["heartbeat_dir"], f"heartbeat-rank{rank}")
     os.environ["LGBM_TPU_STALL_DIR"] = spec["heartbeat_dir"]
 import jax
-if spec.get("force_cpu"):
-    jax.config.update("jax_platforms", "cpu")
 jax.distributed.initialize(coordinator_address=spec["coordinator"],
                            num_processes=spec["num_machines"],
                            process_id=rank)
@@ -246,8 +248,12 @@ def train_distributed(params: Dict[str, Any], data, label=None, *,
     `data` may be a file path (each worker loads it — pair with
     two_round for large files) or an array; arrays are shipped to
     workers through a temp file.  `worker_env` sets per-worker env vars
-    (e.g. XLA_FLAGS for virtual-device tests); `force_cpu` pins the CPU
-    backend inside the workers.
+    (e.g. XLA_FLAGS for virtual-device tests); `force_cpu` puts
+    JAX_PLATFORMS=cpu into the workers' environment.  With
+    `num_machines > 1` the workers must be on the CPU (`force_cpu`, or
+    JAX_PLATFORMS=cpu inherited / in `worker_env`): each local process
+    would otherwise ask for every chip of the host, and all but the
+    first fail or hang.
 
     Fault tolerance (docs/Reliability.md): workers are SUPERVISED — the
     first non-zero exit kills the remaining cluster immediately instead
@@ -316,8 +322,22 @@ def _train_distributed_in(work, params, data, label, weight, group,
     script = os.path.join(work, "worker.py")
     with open(script, "w") as f:
         f.write(_WORKER_MAIN)
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+    # the parent's XLA_FLAGS (e.g. a test harness's virtual devices) are
+    # not the workers'; worker_env sets theirs.  The platform pin is the
+    # environment alone.
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    if force_cpu:
+        env["JAX_PLATFORMS"] = "cpu"
+    child_platform = (worker_env or {}).get(
+        "JAX_PLATFORMS", env.get("JAX_PLATFORMS", "")).strip()
+    if num_machines > 1 and child_platform != "cpu":
+        log.fatal(
+            f"train_distributed: {num_machines} local worker processes "
+            "would each ask JAX for every chip of this host, and a chip "
+            "belongs to one process at a time — all but the first would "
+            "fail or hang at backend init.  Run the local workers on the "
+            "CPU (force_cpu=True), or train over all local chips in ONE "
+            "process: lgb.train(..., tree_learner='data')")
 
     # supervisor-side telemetry: with metrics_dir set, the workers write
     # their rank-tagged event logs and the parent adds a "supervisor"
@@ -359,7 +379,7 @@ def _train_distributed_in(work, params, data, label, weight, group,
                 "data": data_path, "model_out": model_out,
                 "repo": os.path.dirname(
                     os.path.dirname(os.path.abspath(__file__))),
-                "env": dict(worker_env or {}), "force_cpu": bool(force_cpu),
+                "env": dict(worker_env or {}),
                 "attempt": attempt, "checkpoint_dir": checkpoint_dir,
                 "checkpoint_freq": int(checkpoint_freq),
                 "heartbeat_dir": hb_dir,

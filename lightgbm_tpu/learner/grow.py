@@ -40,7 +40,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.histogram import build_histogram, build_histogram_rows_pallas
+from ..ops.histogram import (build_histogram, build_histogram_rows_pallas,
+                             snap_to_operand_grid)
 from ..ops.split import (K_MIN_SCORE, SplitParams, SplitResult,
                          cat_bitset_words, find_best_split,
                          MISSING_NAN, MISSING_ZERO)
@@ -336,8 +337,10 @@ def grow_tree_impl(binned: jnp.ndarray, grad: jnp.ndarray,
     f32 = jnp.float32
 
     row_mask = row_mask.astype(f32)
-    grad = grad.astype(f32) * row_mask
-    hess = hess.astype(f32) * row_mask
+    grad = snap_to_operand_grid(grad.astype(f32) * row_mask,
+                                params.hist_method)
+    hess = snap_to_operand_grid(hess.astype(f32) * row_mask,
+                                params.hist_method)
     gh = jnp.stack([grad, hess], axis=1)
     ones_mask = jnp.ones((n,), dtype=f32)  # grad/hess already carry row_mask
 

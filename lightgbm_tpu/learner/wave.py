@@ -48,7 +48,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.histogram import (build_histogram_wave, build_histogram_wave_hl,
-                             hl_split_of, wave_hl_profitable, wave_slot_pad)
+                             hl_split_of, snap_to_operand_grid,
+                             wave_hl_profitable, wave_slot_pad)
 from ..ops.split import (K_MIN_SCORE, SplitResult, cat_bitset_words,
                          find_best_split)
 from .grow import (FeatureMeta, GrowParams, TreeArrays,
@@ -96,15 +97,21 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
     f32 = jnp.float32
     i32 = jnp.int32
 
+    use_pallas = params.hist_method == "pallas"
+    use_int8 = (use_pallas and params.quant_bins > 0
+                and quant_scales is not None)
+
     row_mask = row_mask.astype(f32)
     grad = grad.astype(f32) * row_mask
     hess = hess.astype(f32) * row_mask
+    if not use_int8:
+        # (the int8 path recovers exact grid integers from k * scale)
+        grad = snap_to_operand_grid(grad, params.hist_method)
+        hess = snap_to_operand_grid(hess, params.hist_method)
     # 2 histogram channels; the trailing column is the count mask consumed
     # by the kernel's fused per-slot count output (output lanes are the MXU
     # cost driver — see _wave_kernel)
     gh = jnp.stack([grad, hess, row_mask], axis=1)
-
-    use_pallas = params.hist_method == "pallas"
 
     # Under shard_map (parallel/data_parallel.py) rows are the local shard:
     # every row-axis reduction is completed by a psum over the data axis —
@@ -123,9 +130,6 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
         with global_timer.device_scope("Network::psum"):
             # tpulint: disable-next=collective-discipline -- the wave engine's single histogram/count reduction point; parallel/data_parallel.py wraps this engine in shard_map and owns the data_axis contract
             return jax.lax.psum(x, params.data_axis)
-
-    use_int8 = (use_pallas and params.quant_bins > 0
-                and quant_scales is not None)
 
     binned_rm = None
     if use_pallas and not use_int8:

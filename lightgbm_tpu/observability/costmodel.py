@@ -1,7 +1,7 @@
 """Compiled-HLO cost accounting: measured per-phase MFU and roofline
 classification (docs/Observability.md).
 
-The MFU number the ROADMAP tracks (`b10m_useful_mac_mfu = 7e-05`) was a
+The MFU number earlier rounds tracked (`useful_mac_mfu`) was a
 single hand-derived analytic estimate in tools/bench_10m.py — a MAC
 guess divided by wall clock divided by a hardcoded peak.  It says the
 chip is idle but not WHERE, so the Pallas-histogram work has nothing to
@@ -18,7 +18,7 @@ so the accounting can never disagree with the watchdog about which
 executable ran.
 
 Combined with the per-phase `::device` times (`Timer.block` credits the
-settle wait to `<scope>::device`) and a per-backend peak table, the
+settle wait to `<scope>::device`) and a per-device-kind peak table, the
 per-iteration event and the serving stats gain measured MFU, arithmetic
 intensity (flops/byte), and a roofline classification: an entry whose
 intensity sits below the ridge point (peak_flops / peak_bytes_per_s) is
@@ -41,29 +41,36 @@ from typing import Any, Dict, Optional, Tuple
 
 from ..utils import log
 
-# Per-backend (peak_flops_per_s, peak_hbm_bytes_per_s).  The TPU row is
-# the v5e the BENCH trajectory anchors on (197 TFLOP/s bf16 MXU,
-# 819 GB/s HBM); cpu/gpu rows are nominal single-device figures so the
-# roofline CLASSIFICATION still works off-chip (the absolute MFU there
-# is not a number anyone tunes against).  Override with
-# LGBM_TPU_PEAK_FLOPS / LGBM_TPU_PEAK_BYTES_PER_S for other parts.
+# (peak_flops_per_s, peak_hbm_bytes_per_s) by `device_kind`, as
+# jax.devices()[0].device_kind reports it.  "TPU v5 lite" is one v5e
+# chip: 197 TFLOP/s bf16, 819 GB/s HBM (Google Cloud documentation,
+# "TPU v5e", system architecture table).  A TPU kind missing here is an
+# error on the roofline path, never a borrowed row.  The "cpu" row is a
+# nominal figure that exists only so the compute-/HBM-bound
+# CLASSIFICATION runs in the CPU tests; no MFU is reported against it.
+# LGBM_TPU_PEAK_FLOPS / LGBM_TPU_PEAK_BYTES_PER_S override either value
+# (and stand in for a part the table does not list).
 PEAK_TABLE: Dict[str, Tuple[float, float]] = {
-    "tpu": (197e12, 819e9),
-    "gpu": (312e12, 2.0e12),
+    "TPU v5 lite": (197e12, 819e9),
     "cpu": (1e11, 2e10),
 }
 
 
-def backend_peaks(backend: Optional[str] = None) -> Tuple[float, float]:
-    """(peak_flops_per_s, peak_bytes_per_s) for `backend` (default: the
-    active jax backend; "cpu" row when jax is not initialized)."""
-    if backend is None:
-        try:
-            import jax
-            backend = jax.default_backend()
-        except Exception:  # noqa: BLE001 - peaks must never raise
-            backend = "cpu"
-    flops, bw = PEAK_TABLE.get(str(backend), PEAK_TABLE["cpu"])
+def current_device_kind() -> str:
+    """The PEAK_TABLE key of the default backend's first device: its
+    `device_kind` on a TPU, its platform name otherwise."""
+    import jax
+    d = jax.devices()[0]
+    return d.device_kind if d.platform == "tpu" else d.platform
+
+
+def backend_peaks(device_kind: Optional[str] = None) -> Tuple[float, float]:
+    """(peak_flops_per_s, peak_bytes_per_s) for `device_kind` (default:
+    the active device).  Raises for a device the table does not list
+    unless both env overrides supply the peaks."""
+    if device_kind is None:
+        device_kind = current_device_kind()
+    flops, bw = PEAK_TABLE.get(device_kind, (None, None))
     env_f = os.environ.get("LGBM_TPU_PEAK_FLOPS")
     env_b = os.environ.get("LGBM_TPU_PEAK_BYTES_PER_S")
     try:
@@ -74,17 +81,18 @@ def backend_peaks(backend: Optional[str] = None) -> Tuple[float, float]:
     except ValueError:
         log.warning("Ignoring malformed LGBM_TPU_PEAK_FLOPS / "
                     "LGBM_TPU_PEAK_BYTES_PER_S override")
+    if flops is None or bw is None:
+        raise KeyError(
+            f"no peak FLOP/s / bytes/s known for device kind "
+            f"{device_kind!r}: add it to costmodel.PEAK_TABLE with its "
+            "source, or set LGBM_TPU_PEAK_FLOPS and "
+            "LGBM_TPU_PEAK_BYTES_PER_S")
     return flops, bw
 
 
 def _extract_cost(analysis) -> Optional[Tuple[float, float]]:
-    """(flops, bytes_accessed) out of a cost_analysis() result, which is
-    a dict on this jax (0.4.x) and a single-element list of dicts on
-    some other versions; None when the module reports neither."""
-    if isinstance(analysis, (list, tuple)):
-        analysis = analysis[0] if analysis else {}
-    if not isinstance(analysis, dict):
-        return None
+    """(flops, bytes_accessed) out of the dict `cost_analysis()` returns;
+    None when the module reports neither."""
     flops = float(analysis.get("flops", 0.0) or 0.0)
     bytes_accessed = float(analysis.get("bytes accessed", 0.0) or 0.0)
     if flops <= 0.0 and bytes_accessed <= 0.0:
@@ -110,10 +118,14 @@ GROUP_PHASES: Dict[str, str] = {
 
 
 def roofline(flops: float, bytes_accessed: float, seconds: float,
-             backend: Optional[str] = None) -> Dict[str, Any]:
-    """Measured utilization + roofline classification for `flops` /
-    `bytes_accessed` of work that took `seconds` of device time."""
-    peak_flops, peak_bw = backend_peaks(backend)
+             device_kind: Optional[str] = None) -> Dict[str, Any]:
+    """Roofline classification for `flops` / `bytes_accessed` of work
+    that took `seconds` of device time, plus measured utilization (`mfu`,
+    `bw_util`) when the device is a TPU — a share of a nominal CPU peak
+    is not a number anyone should read."""
+    if device_kind is None:
+        device_kind = current_device_kind()
+    peak_flops, peak_bw = backend_peaks(device_kind)
     out: Dict[str, Any] = {
         "flops": flops, "bytes": bytes_accessed,
         "peak_flops_per_s": peak_flops, "peak_bytes_per_s": peak_bw,
@@ -126,13 +138,13 @@ def roofline(flops: float, bytes_accessed: float, seconds: float,
     # caps achievable flops/s no matter how good the kernel is
     out["bound"] = ("unknown" if ai is None
                     else "compute" if ai >= ridge else "hbm")
+    out["mfu"] = None
     if seconds and seconds > 0:
-        out["mfu"] = flops / seconds / peak_flops
         out["achieved_flops_per_s"] = flops / seconds
         out["achieved_bytes_per_s"] = bytes_accessed / seconds
-        out["bw_util"] = bytes_accessed / seconds / peak_bw
-    else:
-        out["mfu"] = None
+        if device_kind.startswith("TPU"):
+            out["mfu"] = flops / seconds / peak_flops
+            out["bw_util"] = bytes_accessed / seconds / peak_bw
     return out
 
 
@@ -230,7 +242,7 @@ class CostModel:
     def phase_roofline(self, prev: Dict[str, Dict[str, float]],
                        cur: Dict[str, Dict[str, float]],
                        phases: Dict[str, float],
-                       backend: Optional[str] = None
+                       device_kind: Optional[str] = None
                        ) -> Dict[str, Dict[str, Any]]:
         """Per-group roofline over one window: `prev`/`cur` are
         snapshot() results bracketing it, `phases` the timer's seconds
@@ -252,7 +264,7 @@ class CostModel:
                 dev_s = phases.get(scope + "::device",
                                    phases.get(scope))
             entry = roofline(flops, bytes_accessed, dev_s or 0.0,
-                             backend=backend)
+                             device_kind=device_kind)
             entry["calls"] = calls
             entry["device_s"] = dev_s
             # trim the verbose constants out of the per-iteration event

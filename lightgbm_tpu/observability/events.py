@@ -64,7 +64,7 @@ class EventLogger:
         self.rotate_bytes = int(float(rotate_mb) * (1 << 20))
         self.writer = writer
         # the stall watchdog embeds the run's last record in its
-        # diagnosis — the "how far did we get" marker r05 never had
+        # diagnosis — the "how far did we get" marker a bare timeout lacks
         self.last_record = None
         # serializes appends against rotation's handle swap: _append
         # runs on the writer thread in async mode but on the calling

@@ -36,6 +36,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+# lowerings whose MXU operands are bf16: each row's accumuland is rounded
+# to bf16 on its way into the dot (the one-hot side is exact, the
+# accumulator is fp32)
+BF16_OPERAND_METHODS = ("pallas", "onehot")
+
+
+def snap_to_operand_grid(x: jnp.ndarray, method: str) -> jnp.ndarray:
+    """Round per-row accumulands (gradients, hessians) to the grid the
+    `method`'s MXU operands live on, ONCE, before anything sums them.
+
+    A leaf's sums reach the engines two ways: as histogram bins (sums of
+    the ROUNDED rows, for a bf16-operand lowering) and as root totals
+    minus sibling sums.  With exact fp32 root totals the two disagree by
+    (rounding bias) x (rows outside the leaf) — 4.4e-4 a row at hessian
+    0.2447, i.e. hundreds at 2^20 rows — and the leaf at the end of every
+    parent-minus-sibling chain got a sum near zero and an output in the
+    thousands (seen on the chip, PR 23).  Rounding first makes every
+    consumer sum the same numbers; the kernels' own casts become exact.
+    `reduce_precision`, not a convert pair: XLA may drop
+    f32->bf16->f32 as excess precision."""
+    if method in BF16_OPERAND_METHODS:
+        return jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+    return x
+
+
 def _pick_chunk(n: int, num_features: int, max_bin: int, method: str) -> int:
     """Row-chunk size.  For `onehot` the [F, R, B] one-hot materialization is
     the memory driver (keep it ~64MB); for `segment` the flat id/value copies

@@ -29,27 +29,24 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ._shard_compat import shard_map
-
 
 DATA_AXIS = "data"
 
 
 def make_mesh(n_devices: Optional[int] = None,
               devices=None) -> Mesh:
-    """1-D data-parallel mesh (multi-axis meshes come with feature-parallel)."""
+    """1-D data-parallel mesh (multi-axis meshes come with feature-parallel)
+    over the first `n_devices` devices of the default backend.  Asking for
+    more than the backend has raises: a mesh quietly built on another
+    backend's devices would train on the CPU while the caller believes it
+    is on the chips."""
     if devices is None:
         devices = jax.devices()
-        if n_devices is not None and len(devices) < n_devices:
-            # fall back to the virtual CPU devices (multi-chip dry-run model)
-            try:
-                devices = jax.devices("cpu")
-            except RuntimeError:
-                pass
         if n_devices is not None:
             if len(devices) < n_devices:
                 raise RuntimeError(
-                    f"need {n_devices} devices, have {len(devices)}")
+                    f"need {n_devices} {jax.default_backend()} devices, "
+                    f"have {len(devices)}")
             devices = devices[:n_devices]
     return Mesh(np.array(devices), (DATA_AXIS,))
 
@@ -97,7 +94,7 @@ def make_sharded_wave_fn(mesh: Mesh, donate: bool = False):
         # check_vma off: replication of the tree outputs is by
         # construction (all inputs to the bookkeeping are psum results),
         # which the static checker cannot see through the Pallas calls.
-        mapped = shard_map(
+        mapped = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(P(None, ax), P(ax), P(ax), P(ax), P(), P())
             + (P(),) * len(keys),
@@ -107,9 +104,10 @@ def make_sharded_wave_fn(mesh: Mesh, donate: bool = False):
             return jax.jit(mapped)
         # donated buffers entering a shard_map'd entry must carry
         # EXPLICIT shardings: leaving XLA to infer the donated layout
-        # from the arguments is the donation x SPMD interaction the
-        # MULTICHIP_r05 round implicated (tpulint spmd-axis-discipline
-        # enforces this statically).  The sharded grad/hess slices die
+        # from the arguments is the donation x SPMD interaction
+        # implicated when a multi-device dry run wedged until the
+        # wall-clock cap (tpulint spmd-axis-discipline enforces this
+        # statically).  The sharded grad/hess slices die
         # at the grow call, like the single-device donated entry
         # (learner/wave.py).
         row = NamedSharding(mesh, P(ax))

@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ._shard_compat import shard_map
 
 from ..ops.histogram import build_histogram
 from ..ops.split import K_MIN_SCORE, SplitParams, find_best_split
@@ -123,7 +122,7 @@ def voting_hist_elect(binned, gh, member_mask, col_mask, parent_output,
     # outputs are replicated by construction (psum/pmax of replicated
     # election indices) but the static replication checker cannot infer
     # it through top_k/scatter — hence check_vma=False
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=spec.mesh,
         in_specs=(P(None, axis), P(axis, None), P(axis),
                   repl, repl, repl, repl, repl, repl, repl),

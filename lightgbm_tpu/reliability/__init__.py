@@ -12,7 +12,7 @@ layer and whole-file model writes (ref survey §1, src/network/):
   recovery paths above are testable without real hardware faults
   (`faults.py`, `LGBM_TPU_FAULT=worker_crash@3,...`).
 * stall watchdog + degradation ladder — `guard.py` turns live-but-hung
-  runs (the MULTICHIP_r05 shape: a rank wedged in a collective) into a
+  runs (a rank wedged in a collective until the wall-clock cap) into a
   structured stall diagnosis and a distinct exit code, and with
   `auto_degrade=true` relaunches from checkpoint with the next risky
   knob disabled.

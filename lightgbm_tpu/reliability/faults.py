@@ -8,9 +8,9 @@ the fault).  Kinds:
 * `worker_crash@3`   — `os._exit(17)` at the start of boosting iteration 3
 * `nan_grad@5`       — poison the iteration-5 gradients with NaN
 * `ckpt_write_fail@2`— raise OSError from the iteration-2 checkpoint write
-* `hang@3`           — wedge forever at the start of iteration 3 (the
-  MULTICHIP_r05 shape: the process stays LIVE, so only the stall
-  watchdog / heartbeat staleness can catch it)
+* `hang@3`           — wedge forever at the start of iteration 3 (a
+  rank wedged until the wall-clock cap: the process stays LIVE, so only
+  the stall watchdog / heartbeat staleness can catch it)
 * `slow_iter@4`      — sleep `LGBM_TPU_FAULT_SLOW_S` (default 2.0)
   seconds inside iteration 4: slow, but NOT a stall — the watchdog's
   rolling-median deadline must not trip on it

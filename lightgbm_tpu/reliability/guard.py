@@ -1,7 +1,8 @@
 """Stall watchdog + graceful-degradation ladder (docs/Reliability.md).
 
-MULTICHIP_r05 died at the wall-clock cap with rc=124 and one stderr
-line: a rank wedged inside a collective is LIVE, so PR 1's dead-PID
+An early multi-chip dry run died at the wall-clock cap with rc=124 and
+one stderr line: a rank wedged inside a collective is LIVE, so PR 1's
+dead-PID
 supervision never fires, and the run eats the full deadline with no
 stack, no last-iteration marker and no record of which risky knobs were
 active.  The reference engine's posture is the opposite — its network
@@ -51,11 +52,12 @@ from .faults import WORKER_LOST_EXIT_CODE
 STALL_EXIT_CODE = 86
 
 # Deterministic degradation order: (knob, disabled-value, predicate
-# "is this knob currently enabled").  Donation first — the r05 suspect —
+# "is this knob currently enabled").  Donation first — the suspect in
+# that early multi-device hang —
 # then the compile cache, then async host I/O, then device eval.
 DEGRADE_LADDER: List[Tuple[str, Any]] = [
     ("tpu_donate_buffers", False),
-    ("compile_cache_dir", ""),
+    ("compile_cache_dir", "off"),
     ("async_host_io", False),
     ("device_eval", "false"),
 ]
@@ -75,7 +77,9 @@ def knob_enabled(knob: str, value: Any) -> bool:
     if knob == "tpu_donate_buffers" or knob == "async_host_io":
         return bool(value)
     if knob == "compile_cache_dir":
-        return bool(str(value or "").strip())
+        # "" is the default directory, so the cache is ON unless the
+        # value says off (observability/compile_cache.py)
+        return str(value or "").strip().lower() != "off"
     if knob == "device_eval":
         return str(value).strip().lower() != "false"
     return bool(value)

@@ -8,9 +8,9 @@ immediately instead of letting the survivors stall to the global
 timeout (ISSUE: a rank dead at t=0 previously blocked every other rank
 for the full 900 s deadline).
 
-Dead PIDs are the easy half.  The MULTICHIP_r05 failure mode is a rank
-that stays LIVE while wedged inside a collective — no exit code ever
-arrives.  Two complementary detectors close that hole (ISSUE 7):
+Dead PIDs are the easy half.  The other failure mode is a rank that
+stays LIVE while wedged inside a collective until the wall-clock cap —
+no exit code ever arrives.  Two complementary detectors close that hole (ISSUE 7):
 
 * each worker's `RunGuard` (reliability/guard.py) ticks a per-rank
   heartbeat FILE once per boosting iteration; the supervisor polls the

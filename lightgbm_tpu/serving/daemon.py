@@ -111,6 +111,10 @@ class ServingDaemon:
 
     # -------------------------------------------------------------- control
     def start(self) -> "ServingDaemon":
+        # every registered model compiles its warm-up ladder: a restarted
+        # daemon should deserialize those programs, not rebuild them
+        from ..observability import configure_compile_cache
+        configure_compile_cache(self.config.compile_cache_dir)
         self.coalescer.start()
         if self.config.metrics_port >= 0 and self.metrics_server is None:
             # fleet scrape surface (observability/prom.py): routers,

@@ -196,31 +196,109 @@ _CACHE_SCRIPT = textwrap.dedent("""
     sys.path.insert(0, {repo!r})
     import numpy as np
     import lightgbm_tpu as lgb
-    from lightgbm_tpu.observability import global_registry
+    from lightgbm_tpu.observability import (configure_compile_cache,
+                                            global_registry)
     rng = np.random.RandomState(0)
     X = rng.randn(400, 6); y = (X[:, 0] > 0).astype(float)
-    # num_leaves=31: the tree-program compile must clear the cache's
-    # >=1 s persistence gate (observability/compile_cache.py)
-    lgb.train({{"objective": "binary", "num_leaves": 31, "verbosity": -1,
-               "metric": "none", "compile_cache_dir": sys.argv[1]}},
+    # wave engine: its unrolled ladder compiles in ~3.5 s on the CPU,
+    # well clear of the cache's >=1 s persistence gate
+    # (observability/compile_cache.py); the leaf-wise program's ~1 s
+    # compile sits ON the gate and persists only some of the time
+    lgb.train({{"objective": "binary", "num_leaves": 15, "verbosity": -1,
+               "metric": "none", "tpu_growth_strategy": "wave",
+               "compile_cache_dir": sys.argv[1]}},
               lgb.Dataset(X, label=y), num_boost_round=2)
     snap = global_registry.snapshot()["counters"]
-    print(json.dumps({{k: v for k, v in snap.items() if "compile" in k}}))
+    out = {{k: v for k, v in snap.items() if "compile" in k}}
+    out["dir"] = configure_compile_cache()  # the placement in force
+    print(json.dumps(out))
 """)
+
+
+def _run_cache_child(param_dir, env_dir=None):
+    """One fresh training process; the suite's cache-off switch
+    (conftest.py) is lifted for it, and the placement comes from the
+    arguments alone."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=_REPO,
+               JAX_ENABLE_COMPILATION_CACHE="true")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    r = subprocess.run([sys.executable, "-c", _CACHE_SCRIPT.format(
+        repo=_REPO), param_dir], capture_output=True, text=True, env=env,
+        timeout=600)
+    assert r.returncode == 0, r.stderr[-2000:]
+    return json.loads(r.stdout.strip().splitlines()[-1])
 
 
 def test_compile_cache_second_run_hits(tmp_path):
     cache = str(tmp_path / "xla-cache")
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=_REPO)
-    outs = []
-    for _ in range(2):
-        r = subprocess.run([sys.executable, "-c", _CACHE_SCRIPT.format(
-            repo=_REPO), cache], capture_output=True, text=True, env=env,
-            timeout=600)
-        assert r.returncode == 0, r.stderr[-2000:]
-        outs.append(json.loads(r.stdout.strip().splitlines()[-1]))
-    first, second = outs
+    first, second = (_run_cache_child(cache) for _ in range(2))
+    assert first["dir"] == cache
     assert first.get("compile_cache_misses", 0) > 0
     assert os.listdir(cache), "no persistent cache entries written"
     # the second process deserializes instead of recompiling
     assert second.get("compile_cache_hits", 0) > 0
+
+
+def test_compile_cache_env_dir_beats_the_parameter(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR places the cache through JAX's own
+    handling; a compile_cache_dir that disagrees loses."""
+    env_dir = str(tmp_path / "from-env")
+    param_dir = str(tmp_path / "from-param")
+    out = _run_cache_child(param_dir, env_dir=env_dir)
+    assert out["dir"] == env_dir
+    assert out.get("compile_cache_misses", 0) > 0
+    assert os.listdir(env_dir), "no entries where the environment said"
+    assert not os.path.exists(param_dir)
+
+
+def test_compile_cache_defaults_to_the_checkout(tmp_path):
+    """No environment variable, no parameter: the cache is on, at the
+    fixed <checkout>/.jax_cache."""
+    out = _run_cache_child("")
+    assert out["dir"] == os.path.join(_REPO, ".jax_cache")
+    assert os.path.isdir(out["dir"])
+    assert (out.get("compile_cache_misses", 0)
+            + out.get("compile_cache_hits", 0)) > 0
+
+
+def test_compile_cache_off_wins_after_a_placement(tmp_path):
+    """`compile_cache_dir=off` (the auto_degrade rung) turns the cache
+    off even when an earlier call — the CLI's — has already placed it;
+    any other later request keeps the first placement."""
+    env = dict(os.environ, PYTHONPATH=_REPO,
+               JAX_ENABLE_COMPILATION_CACHE="true")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    code = textwrap.dedent("""
+        import sys, jax
+        from lightgbm_tpu.observability import configure_compile_cache as c
+        first, other = sys.argv[1], sys.argv[2]
+        assert c(first) == first
+        assert c(other) == first          # first placement wins
+        assert c("off") is None           # ... but off always wins
+        assert not jax.config.jax_enable_compilation_cache
+        assert c(first) is None           # and stays off
+        print("OFF_OK")
+    """)
+    r = subprocess.run([sys.executable, "-c", code, str(tmp_path / "a"),
+                        str(tmp_path / "b")], capture_output=True,
+                       text=True, env=env, timeout=120)
+    assert r.returncode == 0 and "OFF_OK" in r.stdout, r.stderr[-2000:]
+    assert not (tmp_path / "b").exists()
+
+
+def test_compile_cache_unwritable_dir_is_an_error(tmp_path):
+    blocker = tmp_path / "a-file"
+    blocker.write_text("x")
+    env = dict(os.environ, PYTHONPATH=_REPO,
+               JAX_ENABLE_COMPILATION_CACHE="true")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    r = subprocess.run(
+        [sys.executable, "-c",
+         "import sys; from lightgbm_tpu.observability import "
+         "configure_compile_cache; configure_compile_cache(sys.argv[1])",
+         str(blocker / "cache")],
+        capture_output=True, text=True, env=env, timeout=120)
+    assert r.returncode != 0
+    assert "NotADirectoryError" in r.stderr or "FileExistsError" in r.stderr

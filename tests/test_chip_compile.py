@@ -1,0 +1,139 @@
+"""The main path's kernels and programs COMPILE for the real chip.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached (on-chip-measurement guide, section 2): a tile
+that is not aligned, a kernel that wants more VMEM than the scoped limit,
+a program that does not fit 16 GB of HBM or a pallas_call that cannot be
+partitioned is refused here exactly as the chip's compiler would refuse
+it, at no chip time.  Nothing runs, so this says nothing about results
+or speed — `chip_smoke.py` on the chip is what executes these programs.
+
+Shapes are the headline configuration's (`chip_smoke.py`, `bench.py`):
+28 features, 255 bins, 2^20 rows, 255 leaves.
+
+The topology is described inside a module-scoped fixture and nowhere
+else: only one process may load the TPU library, the suite runs under
+several xdist workers that each import every test file, and a file that
+touched the library at import (a top-level call, a `skipif` condition, a
+`parametrize` argument) would give the workers different tests to
+collect.  Everything built from the topology is built in a fixture or a
+test, in this one file, in this process.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+F, B, N, LEAVES = 28, 255, 1 << 20, 255
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any refusal means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, np.dtype(dtype), sharding=sharding)
+
+
+def _kernel_args(sh):
+    """(binned_fm [F, N] u8, slot [N] i32, gh [N, 3] f32) on `sh`."""
+    return (_sds((F, N), "uint8", sh), _sds((N,), "int32", sh),
+            _sds((N, 3), "float32", sh))
+
+
+def _grow_args(row, by_row, repl):
+    """The positional arguments of a grow entry
+    (boosting/gbdt.py train_one_iter), as shapes with their shardings."""
+    from lightgbm_tpu.learner import FeatureMeta
+    meta = FeatureMeta(num_bin=_sds((F,), "int32", repl),
+                       missing_type=_sds((F,), "int32", repl),
+                       default_bin=_sds((F,), "int32", repl),
+                       penalty=_sds((F,), "float32", repl))
+    return (_sds((F, N), "uint8", by_row), _sds((N,), "float32", row),
+            _sds((N,), "float32", row), _sds((N,), "float32", row),
+            _sds((F,), "bool", repl), meta)
+
+
+def _grow_params(**kw):
+    from lightgbm_tpu.learner import GrowParams
+    from lightgbm_tpu.ops.split import SplitParams
+    return GrowParams(num_leaves=LEAVES, max_bin=B, hist_method="pallas",
+                      split=SplitParams(min_data_in_leaf=20), **kw)
+
+
+@pytest.mark.parametrize("num_slots", [8, 64, 255])
+def test_wave_kernel_compiles(one_chip, num_slots):
+    from lightgbm_tpu.ops.histogram import build_histogram_wave
+    compiled = build_histogram_wave.lower(
+        *_kernel_args(one_chip), max_bin=B, num_slots=num_slots).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_wave_kernel_int8_compiles(one_chip):
+    from lightgbm_tpu.ops.histogram import build_histogram_wave
+    compiled = build_histogram_wave.lower(
+        *_kernel_args(one_chip), max_bin=B, num_slots=255, quant_bins=16,
+        quant_scales=_sds((2,), "float32", one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("num_slots", [1, 2, 4])
+def test_wave_hl_kernel_compiles(one_chip, num_slots):
+    from lightgbm_tpu.ops.histogram import build_histogram_wave_hl
+    binned, slot, gh = _kernel_args(one_chip)
+    compiled = build_histogram_wave_hl.lower(
+        binned, _sds((N, F), "uint8", one_chip), slot, gh, max_bin=B,
+        num_slots=num_slots, out_slots=8).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_rows_kernel_compiles(one_chip):
+    from lightgbm_tpu.ops.histogram import build_histogram_rows_pallas
+    compiled = build_histogram_rows_pallas.lower(
+        _sds((N, F), "uint8", one_chip), _sds((N, 2), "float32", one_chip),
+        _sds((N,), "float32", one_chip), max_bin=B).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_grow_tree_wave_program_compiles_on_one_chip(one_chip):
+    """The whole-tree program `lgb.train` runs per iteration on a TPU."""
+    from lightgbm_tpu.learner.wave import grow_tree_wave
+    compiled = grow_tree_wave.lower(
+        *_grow_args(one_chip, one_chip, one_chip),
+        params=_grow_params()).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # bin matrix, scores and labels are resident next to it on a 16 GB chip
+    assert mem.temp_size_in_bytes < 8 << 30, mem
+
+
+def test_sharded_wave_program_compiles_on_four_chips(topo):
+    """`tree_learner=data`: the production shard_map (its own builder,
+    its own specs) with the Pallas kernel inside, histograms psum'd."""
+    from lightgbm_tpu.parallel import (grow_params_for_mesh,
+                                       make_sharded_wave_fn)
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    assert mesh.devices.size == 4
+    row = NamedSharding(mesh, P("data"))
+    by_row = NamedSharding(mesh, P(None, "data"))
+    repl = NamedSharding(mesh, P())
+    jitted = make_sharded_wave_fn(mesh).build(
+        grow_params_for_mesh(_grow_params()), ())
+    hlo = jitted.lower(*_grow_args(row, by_row, repl)).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert "all-reduce" in hlo

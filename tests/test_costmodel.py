@@ -32,24 +32,45 @@ def test_roofline_classification_and_mfu(monkeypatch):
     monkeypatch.setenv("LGBM_TPU_PEAK_FLOPS", "100.0")
     monkeypatch.setenv("LGBM_TPU_PEAK_BYTES_PER_S", "10.0")
     # ridge = 10 flops/byte; below it -> hbm-bound, above -> compute
-    lo = roofline(flops=50.0, bytes_accessed=10.0, seconds=1.0)
+    v5e = "TPU v5 lite"
+    lo = roofline(flops=50.0, bytes_accessed=10.0, seconds=1.0,
+                  device_kind=v5e)
     assert lo["bound"] == "hbm" and lo["arithmetic_intensity"] == 5.0
     assert lo["mfu"] == 0.5 and lo["bw_util"] == 1.0
-    hi = roofline(flops=500.0, bytes_accessed=10.0, seconds=2.0)
+    hi = roofline(flops=500.0, bytes_accessed=10.0, seconds=2.0,
+                  device_kind=v5e)
     assert hi["bound"] == "compute"
     assert hi["mfu"] == 2.5  # 500/2/100 — over "peak" only because the
     # peaks are synthetic; the math is what's pinned
-    z = roofline(flops=0.0, bytes_accessed=0.0, seconds=0.0)
+    z = roofline(flops=0.0, bytes_accessed=0.0, seconds=0.0,
+                 device_kind=v5e)
     assert z["bound"] == "unknown" and z["mfu"] is None
+    # off the TPU (the default device here) the classification still
+    # runs, but no share of a nominal CPU peak is reported
+    cpu = roofline(flops=50.0, bytes_accessed=10.0, seconds=1.0)
+    assert cpu["bound"] == "hbm" and cpu["mfu"] is None
+    assert "bw_util" not in cpu
 
 
 def test_backend_peaks_env_override(monkeypatch):
     monkeypatch.setenv("LGBM_TPU_PEAK_FLOPS", "123.0")
     monkeypatch.setenv("LGBM_TPU_PEAK_BYTES_PER_S", "7.0")
-    assert backend_peaks("tpu") == (123.0, 7.0)
+    assert backend_peaks("TPU v5 lite") == (123.0, 7.0)
+    # the overrides stand in for a part the table does not list
+    assert backend_peaks("TPU v99") == (123.0, 7.0)
     monkeypatch.setenv("LGBM_TPU_PEAK_FLOPS", "nonsense")
-    flops, _bw = backend_peaks("tpu")
+    flops, _bw = backend_peaks("TPU v5 lite")
     assert flops == 197e12  # malformed override ignored, table wins
+
+
+def test_unknown_tpu_kind_raises_instead_of_borrowing_a_row(monkeypatch):
+    monkeypatch.delenv("LGBM_TPU_PEAK_FLOPS", raising=False)
+    monkeypatch.delenv("LGBM_TPU_PEAK_BYTES_PER_S", raising=False)
+    assert backend_peaks("TPU v5 lite") == (197e12, 819e9)
+    with pytest.raises(KeyError, match="TPU v99"):
+        backend_peaks("TPU v99")
+    with pytest.raises(KeyError, match="TPU v99"):
+        roofline(1.0, 1.0, 1.0, device_kind="TPU v99")
 
 
 def test_harvest_real_jit_and_accumulate(cost_model_off):
@@ -107,7 +128,7 @@ def test_phase_roofline_diffs_windows(monkeypatch, cost_model_off):
     prev["idle"] = dict(cur["idle"])  # no calls this window -> omitted
     phases = {"GBDT::grow_tree": 2.0, "GBDT::grow_tree::device": 1.0,
               "GBDT::gradients": 0.5}
-    out = cm.phase_roofline(prev, cur, phases)
+    out = cm.phase_roofline(prev, cur, phases, device_kind="TPU v5 lite")
     assert set(out) == {"grow_tree", "gradients"}
     g = out["grow_tree"]
     # delta flops=200 over the ::device split (1.0 s), not the host scope

@@ -3,8 +3,6 @@ import pytest
 
 from lightgbm_tpu.io.dataset import Dataset, load_dataset_from_file
 
-BINARY_TRAIN = "/root/reference/examples/binary_classification/binary.train"
-
 
 def _toy(n=500, f=5, seed=0):
     rng = np.random.RandomState(seed)
@@ -72,8 +70,17 @@ def test_binary_save_load(tmp_path):
     assert ds2.bin_mappers[0].num_bin == ds.bin_mappers[0].num_bin
 
 
-def test_load_reference_example_file():
-    ds = load_dataset_from_file(BINARY_TRAIN)
+def test_load_reference_example_file(tmp_path):
+    """A file shaped like the reference's binary.train (7000 x 28, label
+    in column 0, tab-separated, `.weight` sidecar), made from a seed:
+    /root/reference is not mounted here."""
+    rng = np.random.RandomState(5)
+    X = rng.randn(7000, 28)
+    y = (rng.rand(7000) < 1 / (1 + np.exp(-2 * X[:, 0]))).astype(float)
+    path = tmp_path / "binary.train"
+    np.savetxt(path, np.column_stack([y, X]), delimiter="\t", fmt="%.6f")
+    np.savetxt(str(path) + ".weight", rng.rand(7000) + 0.5, fmt="%.4f")
+    ds = load_dataset_from_file(str(path))
     assert ds.num_data == 7000
     assert ds.num_total_features == 28
     assert set(np.unique(ds.metadata.label)) == {0.0, 1.0}

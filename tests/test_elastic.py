@@ -278,7 +278,6 @@ def test_dart_resume_byte_identical(tmp_path):
 _PREEMPT_CHILD = r"""
 import os, sys, time
 sys.path.insert(0, os.environ["ELASTIC_REPO"])
-import jax; jax.config.update("jax_platforms", "cpu")
 import numpy as np
 import lightgbm_tpu as lgb
 from tests.test_elastic import PARAMS, _data

@@ -37,7 +37,6 @@ pid = int(sys.argv[1]); port = sys.argv[2]
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax
-jax.config.update("jax_platforms", "cpu")
 jax.distributed.initialize(coordinator_address=f"localhost:{port}",
                            num_processes=2, process_id=pid)
 import numpy as np

@@ -10,8 +10,6 @@ import pytest
 import lightgbm_tpu as lgb
 from lightgbm_tpu.io.dataset import get_forced_bins
 
-REF_JSON = "/root/reference/examples/regression/forced_bins.json"
-
 
 def _data(n=3000, F=4, seed=0):
     rng = np.random.RandomState(seed)
@@ -53,11 +51,20 @@ def test_forced_bounds_change_boundaries(tmp_path):
     assert b.current_iteration() == 5
 
 
-def test_forced_bins_reference_example_round_trip():
-    """The reference's own forced_bins.json drives bin boundaries through
-    the file-loading (CLI) path."""
-    ds = lgb.Dataset("/root/reference/examples/regression/regression.train",
-                     params={"forcedbins_filename": REF_JSON,
+def test_forced_bins_reference_example_round_trip(tmp_path):
+    """A forced_bins.json with the content of the reference's own
+    (examples/regression/forced_bins.json) drives bin boundaries through
+    the file-loading (CLI) path; the data file is a seeded stand-in for
+    regression.train (/root/reference is not mounted here)."""
+    X, y = _data(n=2000, F=6, seed=4)
+    data = tmp_path / "regression.train"
+    np.savetxt(data, np.column_stack([y, X]), delimiter="\t", fmt="%.6f")
+    fb = tmp_path / "forced_bins.json"
+    fb.write_text(json.dumps([
+        {"feature": 0, "bin_upper_bound": [0.3, 0.35, 0.4]},
+        {"feature": 1, "bin_upper_bound": [-0.1, -0.15, -0.2]}]))
+    ds = lgb.Dataset(str(data),
+                     params={"forcedbins_filename": str(fb),
                              "max_bin": 32})
     ds._core_or_construct()
     ub0 = ds._core.bin_mappers[0].bin_upper_bound

@@ -1,6 +1,7 @@
 """Stall watchdog + graceful-degradation ladder (ISSUE 7 tentpole).
 
-MULTICHIP_r05 hung to the wall-clock cap with one stderr line; these
+A multi-device dry run once hung to the wall-clock cap with one stderr
+line (a rank wedged in a collective); these
 tests pin the machinery that turns that shape into a diagnosis and an
 auto-recovered run: the RunGuard trips on a missing heartbeat and writes
 a parseable stall diagnosis, a hung process exits with the distinct
@@ -149,11 +150,15 @@ def test_degradation_ladder_order_and_values():
     assert next_degradation({**enabled, "tpu_donate_buffers": False},
                             []) == "compile_cache_dir"
     assert next_degradation({"tpu_donate_buffers": False,
-                             "compile_cache_dir": "", "async_host_io": False,
+                             "compile_cache_dir": "off",
+                             "async_host_io": False,
                              "device_eval": "false"}, []) is None
     assert disabled_value("device_eval") == "false"
     assert knob_enabled("device_eval", "auto")
-    assert not knob_enabled("compile_cache_dir", "  ")
+    # "" is the default cache directory: on until the ladder says "off"
+    assert knob_enabled("compile_cache_dir", "  ")
+    assert disabled_value("compile_cache_dir") == "off"
+    assert not knob_enabled("compile_cache_dir", "off")
 
 
 def test_apply_auto_degrade_walks_the_ladder(tmp_path):
@@ -297,7 +302,6 @@ def test_register_stack_dump_signal():
 _E2E_CHILD = r"""
 import os, sys
 sys.path.insert(0, os.environ["GUARD_REPO"])
-import jax; jax.config.update("jax_platforms", "cpu")
 import numpy as np
 import lightgbm_tpu as lgb
 from lightgbm_tpu.boosting.model_io import save_model_to_string

@@ -1,15 +1,15 @@
 """8-device smoke test over the PR-5 host-boundary knob matrix.
 
-MULTICHIP_r05 timed out after PR 5 landed buffer donation, async host
-I/O and the compile cache; rounds r02-r04 (pre-PR-5) passed the same
-8-device check.  This file localizes that interaction and guards it
+An 8-device dry run wedged until the wall-clock cap (rc=124) after PR 5
+landed buffer donation, async host I/O and the compile cache; the same
+check had passed before PR 5.  This file localizes that interaction and guards it
 from silently regressing: a short sharded-wave training (the exact
 engine configuration the dry run compiles) runs across the knob
 matrix on the virtual 8-device CPU mesh the conftest provides.
 
 Invariants pinned:
 
-* every combination TRAINS (a hang here is the r05 signature — the
+* every combination TRAINS (a hang here is that signature — the
   per-run wall-clock guard turns it into a named failure instead of a
   silent tier-1 cap eat);
 * the model is IDENTICAL across knob combinations — donation, async
@@ -18,7 +18,7 @@ Invariants pinned:
 * no "Some donated buffers were not usable" warnings: grow-buffer
   donation is gated off under a device mesh (boosting/gbdt.py), since
   the row-sharded f32 grad/hess slices cannot alias any grow output —
-  the donation x SPMD interaction implicated in r05;
+  the donation x SPMD interaction implicated in that hang;
 * the compile cache composes with the 8-device mesh in a fresh
   process (subprocess-isolated: a cache-write crash or hang must not
   take the test process down with it).
@@ -34,7 +34,7 @@ import numpy as np
 
 import lightgbm_tpu as lgb
 
-# a genuine r05-style hang blows past this by an order of magnitude;
+# a genuine hang blows past this by an order of magnitude;
 # normal runs (incl. the one-time sharded compile) finish well inside it
 RUN_BUDGET_S = 300.0
 
@@ -89,7 +89,7 @@ def test_knob_matrix_trains_identically():
             booster, elapsed, donate_warns = _train(donate, async_io)
             assert elapsed < RUN_BUDGET_S, (
                 f"donate={donate} async={async_io} took {elapsed:.0f}s — "
-                "the MULTICHIP_r05 hang signature")
+                "the wedged-in-a-collective hang signature")
             assert not donate_warns, (
                 f"donate={donate} async={async_io}: grow-buffer donation "
                 "leaked through the mesh gate: "
@@ -140,7 +140,6 @@ def test_donation_gated_off_under_mesh():
 _STALL_CHILD = r"""
 import os, sys
 sys.path.insert(0, os.environ["STALL_REPO"])
-import jax; jax.config.update('jax_platforms', 'cpu')
 import numpy as np
 import lightgbm_tpu as lgb
 from tests.test_multichip_smoke import _problem, _params
@@ -213,13 +212,12 @@ def test_stall_injection_diagnosed_and_degraded_under_mesh(tmp_path):
 
 
 def test_compile_cache_under_mesh_subprocess(tmp_path):
-    """compile_cache_dir x 8-device mesh in a FRESH process (the r05 dry
-    run is also a fresh process): must train and exit 0 inside the
+    """compile_cache_dir x 8-device mesh in a FRESH process (the dry run that
+    hung was also a fresh process): must train and exit 0 inside the
     budget.  Subprocess isolation keeps a cache-layer crash or hang from
     killing the whole test session."""
     cache = tmp_path / "xla-cache"
     code = (
-        "import jax; jax.config.update('jax_platforms', 'cpu')\n"
         "import numpy as np, lightgbm_tpu as lgb\n"
         "from tests.test_multichip_smoke import _problem, _params\n"
         "X, y = _problem()\n"
@@ -230,6 +228,9 @@ def test_compile_cache_under_mesh_subprocess(tmp_path):
     )
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
+    # lift the suite's cache-off switch (conftest.py) for this child
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "true"
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     flags = env.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         env["XLA_FLAGS"] = (

@@ -20,7 +20,6 @@ port = sys.argv[3]
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax
-jax.config.update("jax_platforms", "cpu")
 jax.distributed.initialize(coordinator_address=f"localhost:{port}",
                            num_processes=2, process_id=pid)
 sys.path.insert(0, "/root/repo")
@@ -136,16 +135,15 @@ rank = sys.argv[1]
 port = sys.argv[2]
 ports = sys.argv[3]
 model_out = sys.argv[4]
+data = sys.argv[5]
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 os.environ["LIGHTGBM_TPU_MACHINE_RANK"] = rank
-import jax
-jax.config.update("jax_platforms", "cpu")
 sys.path.insert(0, "/root/repo")
 from lightgbm_tpu.cli import main
 rc = main([
     "task=train", "objective=regression", "tree_learner=data",
-    "data=/root/reference/examples/regression/regression.train",
+    f"data={data}",
     "num_trees=3", "num_leaves=15", "verbosity=-1",
     "tpu_growth_strategy=leafwise", "num_machines=2",
     f"machines={ports}", f"local_listen_port={port}",
@@ -166,6 +164,13 @@ def test_cli_machines_two_workers_identical_models(tmp_path):
     import socket
     script = tmp_path / "cli_worker.py"
     script.write_text(_CLI_WORKER)
+    # a seeded stand-in for the reference's regression.train (label in
+    # column 0, tab-separated): /root/reference is not mounted here
+    rng = np.random.RandomState(3)
+    Xd = rng.rand(2000, 8)
+    yd = 3 * (Xd[:, 0] - 0.5) + Xd[:, 1] * Xd[:, 2] + 0.1 * rng.randn(2000)
+    data = tmp_path / "regression.train"
+    np.savetxt(data, np.column_stack([yd, Xd]), delimiter="\t", fmt="%.6f")
     with socket.socket() as s1, socket.socket() as s2:
         s1.bind(("localhost", 0))
         s2.bind(("localhost", 0))
@@ -176,7 +181,7 @@ def test_cli_machines_two_workers_identical_models(tmp_path):
            if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
     procs = [subprocess.Popen(
         [sys.executable, str(script), str(i), (p1, p2)[i], machines,
-         str(outs[i])],
+         str(outs[i]), str(data)],
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
         env=env, cwd="/root/repo") for i in range(2)]
     logs = []
@@ -197,7 +202,6 @@ pid = int(sys.argv[1]); out_path = sys.argv[2]; port = sys.argv[3]
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax
-jax.config.update("jax_platforms", "cpu")
 jax.distributed.initialize(coordinator_address=f"localhost:{port}",
                            num_processes=2, process_id=pid)
 sys.path.insert(0, "/root/repo")
@@ -289,7 +293,6 @@ pid = int(sys.argv[1]); out_path = sys.argv[2]; port = sys.argv[3]
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax
-jax.config.update("jax_platforms", "cpu")
 jax.distributed.initialize(coordinator_address=f"localhost:{port}",
                            num_processes=2, process_id=pid)
 sys.path.insert(0, "/root/repo")
@@ -353,7 +356,6 @@ pid = int(sys.argv[1]); out_path = sys.argv[2]; port = sys.argv[3]
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax
-jax.config.update("jax_platforms", "cpu")
 jax.distributed.initialize(coordinator_address=f"localhost:{port}",
                            num_processes=2, process_id=pid)
 sys.path.insert(0, "/root/repo")
@@ -420,7 +422,6 @@ port = sys.argv[3]
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax
-jax.config.update("jax_platforms", "cpu")
 jax.distributed.initialize(coordinator_address=f"localhost:{port}",
                            num_processes=2, process_id=pid)
 sys.path.insert(0, "/root/repo")

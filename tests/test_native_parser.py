@@ -38,10 +38,15 @@ def test_libsvm_native_matches_python():
         feats, [[0.5, 0, 0, 2.5], [0, -1, 0, 0], [0, 0, 0, 0]])
 
 
-def test_parse_file_on_reference_examples():
-    """End-to-end parse of the reference's real example files goes through
-    the native path and matches numpy's own parse."""
-    path = "/root/reference/examples/binary_classification/binary.train"
+def test_parse_file_on_reference_examples(tmp_path):
+    """End-to-end parse of a file shaped like the reference's binary.train
+    (seeded: /root/reference is not mounted here) goes through the native
+    path and matches numpy's own parse."""
+    rng = np.random.RandomState(9)
+    path = str(tmp_path / "binary.train")
+    np.savetxt(path, np.column_stack([rng.randint(0, 2, 2000),
+                                      rng.randn(2000, 28)]),
+               delimiter="\t", fmt="%.6g")
     feats, labels, names = parse_file(path)
     ref = np.loadtxt(path)
     np.testing.assert_allclose(labels, ref[:, 0])
@@ -49,7 +54,15 @@ def test_parse_file_on_reference_examples():
 
 
 def test_parse_file_libsvm_rank(tmp_path):
-    path = "/root/reference/examples/lambdarank/rank.train"
+    # shaped like the reference's lambdarank/rank.train (graded labels,
+    # sparse `index:value` pairs), seeded
+    rng = np.random.RandomState(13)
+    path = str(tmp_path / "rank.train")
+    with open(path, "w") as f:
+        for _ in range(300):
+            idx = np.sort(rng.choice(np.arange(1, 301), 40, replace=False))
+            f.write(f"{rng.randint(0, 5)} " + " ".join(
+                f"{k}:{rng.rand():.4f}" for k in idx) + "\n")
     feats, labels, _ = parse_file(path)
     assert feats.shape[0] == len(labels) > 0
     assert np.isfinite(labels).all()

@@ -3,16 +3,22 @@
 and against the full wave kernel.
 
 The Pallas kernel needs real TPU hardware; under the CPU test platform
-these tests skip (same gating as test_wave_int8.py — the driver bench
-exercises the path on-device, and models were verified bit-identical with
-the kernel on/off there)."""
+these tests skip (same gating as test_wave_int8.py).  On the chip the
+path is executed by tools/kernel_checks.py, which chip_smoke.py runs;
+that the kernel COMPILES at the headline shapes is kept among the CPU
+tests (tests/test_chip_compile.py)."""
 
 import numpy as np
 import pytest
-import jax
 
-pytestmark = pytest.mark.skipif(jax.default_backend() != "tpu",
-                                reason="Pallas wave kernel needs TPU")
+
+@pytest.fixture(autouse=True, scope="module")
+def _needs_tpu():
+    """Decided when a test of this file starts, never while the module is
+    imported: every xdist worker must collect the same tests."""
+    import jax
+    if jax.default_backend() != "tpu":
+        pytest.skip("Pallas wave kernel needs TPU")
 
 
 @pytest.mark.parametrize("S,out_slots", [(1, 8), (2, 8), (4, 8), (8, 8)])

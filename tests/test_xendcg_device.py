@@ -113,12 +113,25 @@ def test_device_xendcg_deterministic_per_iteration():
     assert not np.array_equal(np.asarray(g1), np.asarray(g3))
 
 
+def _seeded_ranking_set():
+    """A seeded stand-in for the reference's rank.train (/root/reference
+    is not mounted here): 150 queries of 5-40 documents, graded 0-4
+    relevance that depends on the features plus noise."""
+    rng = np.random.RandomState(11)
+    group = rng.randint(5, 41, size=150)
+    n = int(group.sum())
+    X = rng.rand(n, 20)
+    util = 2.5 * X[:, 0] + 1.5 * X[:, 1] * X[:, 2] - X[:, 3] + rng.randn(n) * 0.4
+    label = np.clip(np.floor((util - util.min())
+                             / (np.ptp(util) + 1e-9) * 5), 0, 4)
+    return lgb.Dataset(X, label=label, group=group)
+
+
 def test_xendcg_training_quality_matches_host():
     b_dev = lgb.train(
         {"objective": "rank_xendcg", "num_leaves": 15, "verbosity": -1,
          "learning_rate": 0.1, "metric": "ndcg", "eval_at": [3]},
-        lgb.Dataset("/root/reference/examples/lambdarank/rank.train"),
-        num_boost_round=10)
+        _seeded_ranking_set(), num_boost_round=10)
     assert getattr(b_dev._gbdt, "_ranking_dev_fn", None), \
         "device path not engaged"
     orig = RankXENDCG.make_device_grad_fn
@@ -128,8 +141,7 @@ def test_xendcg_training_quality_matches_host():
             {"objective": "rank_xendcg", "num_leaves": 15,
              "verbosity": -1, "learning_rate": 0.1, "metric": "ndcg",
              "eval_at": [3]},
-            lgb.Dataset("/root/reference/examples/lambdarank/rank.train"),
-            num_boost_round=10)
+            _seeded_ranking_set(), num_boost_round=10)
     finally:
         RankXENDCG.make_device_grad_fn = orig
     # quality proxy: training NDCG via booster eval on the SAME data

@@ -3,8 +3,8 @@ leaves, >= 100 timed iterations on the real chip (BASELINE.md target #2;
 ref docs/Experiments.rst:110-123 trains 10.5M rows in 0.260 s/iter on a
 2015 28-core box).
 
-Writes docs/bench_10m.json; bench.py folds the numbers into its single
-driver JSON line.  Also derives the MFU/roofline accounting PERF_NOTES.md
+Writes docs/bench_10m.json (its own record; bench.py does not read it).
+Also derives the MFU/roofline accounting PERF_NOTES.md
 reports: per-iteration streamed one-hot volume from the wave ladder
 model, achieved bytes/s against the v5e's ~2 TB/s VMEM bandwidth, and
 useful-MAC utilization.
@@ -21,7 +21,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
-from bench import FEATURES, _auc, make_higgs_like
+from bench import FEATURES
+from tools.higgs_like import auc as _auc, make_higgs_like
 
 ROWS = int(os.environ.get("BENCH10M_ROWS", 10_000_000))
 ITERS = int(os.environ.get("BENCH10M_ITERS", 100))

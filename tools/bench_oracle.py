@@ -5,9 +5,8 @@ Higgs-like synthetic that bench.py uses (same generator, same seed, same
 params: 255 leaves, max_bin 255, lr 0.1, min_data_in_leaf 20), times
 sec/iter as (t(ITERS_HI) - t(ITERS_LO)) / (ITERS_HI - ITERS_LO) so data
 loading/binning is excluded, computes held-out AUC with the same
-tie-averaged AUC as bench.py, and writes docs/oracle_bench.json, which
-bench.py folds into its output as ref_auc / ref_sec_per_iter /
-vs_ref_measured.
+tie-averaged AUC as bench.py, and writes docs/oracle_bench.json (its own
+record of ref_auc / ref_sec_per_iter; bench.py does not read it).
 
 Run manually once per host class: the result records host facts
 (cpu count, model) so the judged numbers carry their context.
@@ -24,7 +23,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
-from bench import FEATURES, NUM_LEAVES, ROWS, _auc, make_higgs_like
+from bench import FEATURES, NUM_LEAVES, ROWS
+from tools.higgs_like import auc as _auc, make_higgs_like
 
 ORACLE = "/tmp/lgb_ref_src/lightgbm"
 ITERS_LO = 13

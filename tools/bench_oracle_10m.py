@@ -14,7 +14,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
-from bench import FEATURES, _auc, make_higgs_like
+from bench import FEATURES
+from tools.higgs_like import auc as _auc, make_higgs_like
 from tools.bench_10m import ROWS, TEST_ROWS
 
 ORACLE = "/tmp/lgb_ref_src/lightgbm"

@@ -1,11 +1,13 @@
-"""On-chip Pallas kernel correctness gate, run by bench.py every round.
+"""On-chip Pallas kernel correctness gate, run by chip_smoke.py and
+bench.py on the chip.
 
-The 7 kernel unit tests skip off-TPU, so without this gate a Mosaic/XLA
-regression in the histogram kernels would surface only as an unexplained
-AUC delta in the next BENCH json (round-4 verdict, weak #6).  bench.py
-calls run_checks() on the real chip and carries a pass/fail field in the
-driver JSON line — the TPU counterpart of the reference's dual-gate CI
-(.ci scripts running both CPU and CUDA test legs).
+The kernel unit tests (tests/test_wave_hl.py, tests/test_wave_int8.py)
+skip off-TPU, so without this gate a Mosaic/XLA regression in the
+histogram kernels would surface only as an unexplained AUC delta.
+chip_smoke.py and bench.py call run_checks() on the real chip and fail
+on anything but "ok" — the TPU counterpart of the reference's dual-gate
+CI (.ci scripts running both CPU and CUDA test legs).  A check that
+raises is a named failure in the verdict, with its traceback on stderr.
 
 Checks (small shapes, seconds of chip time):
   1. fused wave kernel == XLA one-hot fallback (fp32, exact histograms)
@@ -16,6 +18,7 @@ Checks (small shapes, seconds of chip time):
 """
 import os
 import sys
+import traceback
 
 import numpy as np
 
@@ -77,7 +80,8 @@ def run_checks():
         if not (np.allclose(np.asarray(h), want, rtol=1e-5, atol=1e-4)
                 and np.allclose(np.asarray(cnt), want_cnt)):
             failures.append("wave_vs_host")
-    except Exception as e:    # noqa: BLE001 - report, don't crash bench
+    except Exception as e:    # noqa: BLE001 - named in the verdict
+        traceback.print_exc()
         failures.append(f"wave_raised({type(e).__name__})")
 
     # 2. decomposed hi/lo kernel vs the full kernel (few computed slots)
@@ -92,7 +96,8 @@ def run_checks():
                             rtol=1e-5, atol=1e-4)
                 and np.allclose(np.asarray(cf)[:2], np.asarray(cd)[:2])):
             failures.append("hl_vs_full")
-    except Exception as e:
+    except Exception as e:    # noqa: BLE001 - named in the verdict
+        traceback.print_exc()
         failures.append(f"hl_raised({type(e).__name__})")
 
     # 3. int8 quantized kernel: grid-snapped grads accumulate EXACTLY
@@ -112,7 +117,8 @@ def run_checks():
         # int32 accumulation then dequant: exact up to one float32 scale
         if not np.allclose(np.asarray(hq), wq, rtol=1e-6, atol=1e-5):
             failures.append("int8_exactness")
-    except Exception as e:
+    except Exception as e:    # noqa: BLE001 - named in the verdict
+        traceback.print_exc()
         failures.append(f"int8_raised({type(e).__name__})")
 
     # 4. single-leaf row-major Pallas histogram vs segment lowering
@@ -125,7 +131,8 @@ def run_checks():
         if not np.allclose(np.asarray(hp), np.asarray(hs),
                            rtol=1e-5, atol=1e-4):
             failures.append("rows_pallas_vs_segment")
-    except Exception as e:
+    except Exception as e:    # noqa: BLE001 - named in the verdict
+        traceback.print_exc()
         failures.append(f"rows_raised({type(e).__name__})")
 
     return "ok" if not failures else "fail:" + ",".join(failures)

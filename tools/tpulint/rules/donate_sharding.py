@@ -6,7 +6,8 @@ the runtime arguments.  On a multi-device mesh the inferred sharding
 can disagree with what the aliasing pass needs, so the donation is
 silently dropped ("Some donated buffers were not usable") at best and
 destabilizes the multi-device compile at worst — the donation x SPMD
-interaction implicated in the MULTICHIP_r05 timeout.
+interaction implicated when a multi-device dry run wedged until the
+wall-clock cap.
 `parallel/data_parallel.py` now passes explicit shardings on its
 donate path and `boosting/gbdt.py` gates grow-buffer donation off
 under a mesh; this rule keeps both invariants from regressing.
@@ -36,7 +37,7 @@ class DonatedSharding(Rule):
     name = "donated-sharding"
     description = ("jax.jit over a shard_map'd entry donates buffers "
                    "without explicit in_shardings — XLA infers the "
-                   "donated layout from the arguments (MULTICHIP_r05)")
+                   "donated layout from the arguments")
 
     file_local = True
 
@@ -86,6 +87,7 @@ class DonatedSharding(Rule):
                             "buffers without explicit in_shardings — "
                             "XLA then infers the donated layout from "
                             "the arguments (the donation x SPMD "
-                            "interaction implicated in MULTICHIP_r05); "
+                            "interaction implicated in a multi-device "
+                            "hang); "
                             "pass in_shardings for every donated "
                             "argument or drop the donation"))

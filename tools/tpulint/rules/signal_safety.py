@@ -5,7 +5,7 @@ A SIGTERM handler (the preemption notice) and the stall watchdog's exit
 path both run when the rest of the process may already be wedged — the
 AsyncWriter worker stuck on a dead disk, the main thread parked inside a
 collective.  Any UNBOUNDED wait on that path turns a recoverable
-preemption into the r05 shape: a live process that never exits and never
+preemption into the worst shape: a live process that never exits and never
 explains itself.  PR 7 learned this by hand for the stall-file writer
 ("synchronously, never via the possibly-hung AsyncWriter"); this rule
 enforces it mechanically.

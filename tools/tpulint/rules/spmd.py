@@ -1,7 +1,7 @@
 """spmd-axis-discipline: mesh-axis and shard_map hygiene.
 
 Under SPMD three classes of mistake produce deadlocks, wrong numbers,
-or the r05-style multi-device stall — none of which a single-device
+or a multi-device stall (a rank wedged in a collective) — none of which a single-device
 test can see:
 
 * a collective naming an axis the mesh does not declare fails at run
