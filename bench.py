@@ -1406,9 +1406,9 @@ def main():
     from lightgbm_tpu.observability.costmodel import (backend_peaks,
                                                       global_cost_model)
     from lightgbm_tpu.utils.timer import global_timer
-    timer_prev = global_timer.enabled
+    timer_prev = global_timer.sync
     cost_prev = global_cost_model.enabled
-    global_timer.enabled = True
+    global_timer.sync = True
     global_cost_model.enabled = True
     global_timer.reset()
     cost_snap0 = global_cost_model.snapshot()
@@ -1448,7 +1448,7 @@ def main():
     host_block_ms_per_iter = round(sum(
         sec * 1000 for name, sec, _cnt in all_scopes
         if name in _HOST_BLOCK_SCOPES) / 3.0, 3)
-    global_timer.enabled = timer_prev
+    global_timer.sync = timer_prev
     global_timer.reset()
 
     # peak device memory over the run (empty off-TPU: the CPU backend

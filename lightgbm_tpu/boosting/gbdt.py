@@ -28,7 +28,8 @@ from ..models.tree import Tree
 from ..objective import ObjectiveFunction
 from ..ops.split import SplitParams
 from ..metric import Metric
-from ..observability import global_registry as _metrics
+from ..observability import (first_iter_compile_phases,
+                             global_registry as _metrics)
 from ..reliability import faults
 from ..utils import log
 from ..utils.timer import global_timer
@@ -714,13 +715,15 @@ class GBDT:
                 # ones; the per-dispatch cost on a local chip: not
                 # re-measured since bring-up).
                 if self.num_tree_per_iteration > 1:
-                    self._grad_fn_raw = jax.jit(
-                        lambda sc, lab, w: objective.get_gradients(
-                            sc, lab, w))
+                    def _gradk(sc, lab, w):
+                        with global_timer.device_scope("GBDT::gradients"):
+                            return objective.get_gradients(sc, lab, w)
+                    self._grad_fn_raw = jax.jit(_gradk)
                 else:  # single-model path: slice + expand inside jit
                     def _grad1(sc, lab, w):
-                        g, h = objective.get_gradients(sc[0], lab, w)
-                        return g[None, :], h[None, :]
+                        with global_timer.device_scope("GBDT::gradients"):
+                            g, h = objective.get_gradients(sc[0], lab, w)
+                            return g[None, :], h[None, :]
                     self._grad_fn_raw = jax.jit(_grad1)
                 from ..observability import RecompileDetector
                 self._grad_fn_raw = RecompileDetector(self._grad_fn_raw,
@@ -758,9 +761,10 @@ class GBDT:
         _donate0 = (0,) if config.tpu_donate_buffers else ()
 
         def _score_update(scores, class_id, leaf_vals, leaf_id, pad_mask):
-            delta = jnp.take(leaf_vals,
-                             jnp.clip(leaf_id, 0, leaf_vals.shape[0] - 1))
-            return scores.at[class_id].add(delta * pad_mask)
+            with global_timer.device_scope("GBDT::score_update"):
+                delta = jnp.take(
+                    leaf_vals, jnp.clip(leaf_id, 0, leaf_vals.shape[0] - 1))
+                return scores.at[class_id].add(delta * pad_mask)
         self._score_update_fn = jax.jit(_score_update,
                                         donate_argnums=_donate0)
 
@@ -784,7 +788,11 @@ class GBDT:
                 t.leaf_value, t.leaf_weight, as_f32(t.leaf_count),
                 as_f32(t.leaf_parent), as_f32(t.leaf_depth),
                 as_f32(t.split_is_cat),
-                as_f32(t.cat_bitset.reshape(-1))])
+                as_f32(t.cat_bitset.reshape(-1)),
+                # the wave engine's own count of the waves it ran rides
+                # along (no transfer of its own)
+                as_f32(jnp.asarray(0 if t.waves is None
+                                   else t.waves)[None])])
         if jax.process_count() > 1 and self.mesh is not None:
             # multi-process SPMD: GSPMD may assign the packed buffer a
             # sharding spanning other processes' devices, which the host
@@ -810,9 +818,11 @@ class GBDT:
 
         def _score_update_shrink(scores, class_id, leaf_vals, rate,
                                  leaf_id, pad_mask):
-            delta = jnp.take(leaf_vals * rate,
-                             jnp.clip(leaf_id, 0, leaf_vals.shape[0] - 1))
-            return scores.at[class_id].add(delta * pad_mask)
+            with global_timer.device_scope("GBDT::score_update"):
+                delta = jnp.take(
+                    leaf_vals * rate,
+                    jnp.clip(leaf_id, 0, leaf_vals.shape[0] - 1))
+                return scores.at[class_id].add(delta * pad_mask)
         self._score_update_shrink_fn = jax.jit(_score_update_shrink,
                                                donate_argnums=_donate0)
         # ---- quantized training (ref: gradient_discretizer.{hpp,cpp};
@@ -1176,6 +1186,18 @@ class GBDT:
     def train_one_iter(self, gradients=None, hessians=None) -> bool:
         """One boosting iteration; returns True when training should stop
         (ref: gbdt.cpp:338 TrainOneIter)."""
+        # the parent of the phase spans below: what it holds beyond them
+        # is the host loop's own work (slices, dispatch, bookkeeping)
+        with global_timer.scope("GBDT::iteration",
+                                iter=self.num_init_iteration_ + self.iter_):
+            if self.iter_ > 0:
+                return self._train_one_iter(gradients, hessians)
+            # the first iteration traces, lowers and compiles or loads
+            # the train programs: first_iter_jit_trace_s, ...
+            with first_iter_compile_phases():
+                return self._train_one_iter(gradients, hessians)
+
+    def _train_one_iter(self, gradients, hessians) -> bool:
         K = self.num_tree_per_iteration
         if faults.active():
             faults.maybe_crash(self.num_init_iteration_ + self.iter_)
@@ -1373,6 +1395,7 @@ class GBDT:
     def _packed_to_tree(self, flat: np.ndarray) -> Optional[Tree]:
         """Decode the packed flat tree buffer into a host Tree."""
         ints = flat.view(np.int32)
+        _metrics.inc("waves_total", int(ints[-1]))
         L = self.config.num_leaves
         ni = max(L - 1, 1)
         W = self._cat_words
@@ -1562,7 +1585,10 @@ class GBDT:
     def _drain_pending_now(self, keep_depth: int) -> None:
         while len(self._pending) > keep_depth:
             p = self._pending.pop(0)
-            tree = self._packed_to_tree(_fetch_host(p["packed"]))
+            # the one place the steady loop blocks on the device
+            with global_timer.scope("GBDT::wait_tree", tree=p["idx"]):
+                flat = _fetch_host(p["packed"])
+            tree = self._packed_to_tree(flat)
             if tree is None:
                 # grew no split: keep a 0-value stump for this class (ref:
                 # gbdt.cpp:372-391) and record it for the stop condition

@@ -170,7 +170,7 @@ def train(params: Dict[str, Any], train_set: Dataset,
     # ---- observability setup (docs/Observability.md) ----
     profile_dir = cfg.profile_dir or None
     event_logger = None
-    timer_was_enabled = global_timer.enabled
+    timer_was_syncing = global_timer.sync
     cost_was_enabled = None
     metrics_srv = None
     if metrics_dir:
@@ -180,8 +180,9 @@ def train(params: Dict[str, Any], train_set: Dataset,
                                    writer=writer)
         set_event_logger(event_logger)
         # the per-iteration phase breakdown diffs global_timer snapshots;
-        # a metrics run therefore always times (restored afterwards)
-        global_timer.enabled = True
+        # a metrics run syncs at every phase boundary so that each phase
+        # is charged its own device work (restored afterwards)
+        global_timer.sync = True
         if cfg.roofline:
             # compiled-cost accounting: per-phase measured MFU +
             # roofline classification in the iteration events
@@ -472,7 +473,7 @@ def train(params: Dict[str, Any], train_set: Dataset,
             clear_preemption_hook()
         if run_guard is not None:
             run_guard.stop()
-        global_timer.enabled = timer_was_enabled
+        global_timer.sync = timer_was_syncing
         if cost_was_enabled is not None:
             from .observability import enable_cost_model
             enable_cost_model(cost_was_enabled)

@@ -248,6 +248,9 @@ class TreeArrays(NamedTuple):
     leaf_depth: jnp.ndarray       # [L] int32
     split_is_cat: jnp.ndarray = None  # [L-1] bool (categorical split)
     cat_bitset: jnp.ndarray = None    # [L-1, W] int32 bins-left bitsets
+    # what growing it took (wave engine only; rides the packed tree to the
+    # registry's waves_total, boosting/gbdt.py)
+    waves: jnp.ndarray = None         # scalar int32: waves that ran
 
 
 class _PendingSplits(NamedTuple):

@@ -101,17 +101,18 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
     use_int8 = (use_pallas and params.quant_bins > 0
                 and quant_scales is not None)
 
-    row_mask = row_mask.astype(f32)
-    grad = grad.astype(f32) * row_mask
-    hess = hess.astype(f32) * row_mask
-    if not use_int8:
-        # (the int8 path recovers exact grid integers from k * scale)
-        grad = snap_to_operand_grid(grad, params.hist_method)
-        hess = snap_to_operand_grid(hess, params.hist_method)
-    # 2 histogram channels; the trailing column is the count mask consumed
-    # by the kernel's fused per-slot count output (output lanes are the MXU
-    # cost driver — see _wave_kernel)
-    gh = jnp.stack([grad, hess, row_mask], axis=1)
+    with global_timer.device_scope("Tree::hist_operands"):
+        row_mask = row_mask.astype(f32)
+        grad = grad.astype(f32) * row_mask
+        hess = hess.astype(f32) * row_mask
+        if not use_int8:
+            # (the int8 path recovers exact grid integers from k * scale)
+            grad = snap_to_operand_grid(grad, params.hist_method)
+            hess = snap_to_operand_grid(hess, params.hist_method)
+        # 2 histogram channels; the trailing column is the count mask
+        # consumed by the kernel's fused per-slot count output (output
+        # lanes are the MXU cost driver — see _wave_kernel)
+        gh = jnp.stack([grad, hess, row_mask], axis=1)
 
     # Under shard_map (parallel/data_parallel.py) rows are the local shard:
     # every row-axis reduction is completed by a psum over the data axis —
@@ -136,7 +137,8 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
         # row-major copy for the decomposed small-S kernel's lo side
         # (transposed once per tree; bins are static so XLA keeps it
         # resident for all waves of the tree)
-        binned_rm = binned.T
+        with global_timer.device_scope("Tree::hist_operands"):
+            binned_rm = binned.T
 
     def _hl_fits(true_slots):
         """VMEM gate for the decomposed kernel (no feature grouping)."""
@@ -346,7 +348,8 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
         leaf_parent=jnp.full(Lp, -1, i32),
         leaf_depth=jnp.zeros(Lp, i32),
         split_is_cat=jnp.zeros(ni, bool),
-        cat_bitset=jnp.zeros((ni, W), i32))
+        cat_bitset=jnp.zeros((ni, W), i32),
+        waves=jnp.asarray(0, i32))
 
     # per-leaf running sums / outputs for the gain scan
     leaf_sum_g0 = jnp.zeros(Lp, f32).at[0].set(sum_g0)
@@ -387,6 +390,15 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
         slot one-hot bucket — no per-row gather or gh masking needed
         here)."""
         H, cnt = hists_of(kslot, gh, Kb, Ks)           # [Kb, F', B', 2]
+        with global_timer.device_scope("Tree::cache"):
+            return _complete_cache(H, cnt, cache_h, cache_c, pend_sel,
+                                   pend_new, pend_rank, pend_sl, Kb,
+                                   first)
+
+    def _complete_cache(H, cnt, cache_h, cache_c, pend_sel, pend_new,
+                        pend_rank, pend_sl, Kb, first):
+        """Sibling subtraction and the scatter of both children into
+        the per-leaf cache (the part of `wave_hists` after the kernel)."""
         cnt = cnt.astype(f32)
         if first:
             # root wave: kslot is all zeros; one computed slot
@@ -655,7 +667,9 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
             leaf_value=leaf_value, leaf_weight=leaf_weight,
             leaf_count=leaf_count, leaf_parent=leaf_parent,
             leaf_depth=leaf_depth,
-            split_is_cat=split_is_cat, cat_bitset=cat_bitset)
+            split_is_cat=split_is_cat, cat_bitset=cat_bitset,
+            # one histogram pass over the rows per wave
+            waves=t.waves + 1)
 
         # 4. recolor rows: one packed table row-gather per row.  The table
         # is [NLp, 8] numerical-only; the categorical columns (is_cat +
@@ -664,35 +678,35 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
         # smaller side per split pair, chosen by the SCAN's (approximate,
         # RoundInt-parity) counts — either choice yields the same exact
         # pair of histograms by subtraction
-        small_left = best.left_count <= best.right_count
-        cols = [split_sel.astype(i32), best.feature, best.threshold,
-                best.default_left.astype(i32), newleaf_of,
-                jnp.take(meta.missing_type, best.feature),
-                jnp.take(meta.default_bin, best.feature),
-                jnp.take(meta.num_bin, best.feature),
-                rank_of, small_left.astype(i32)]
-        if params.has_bundles:
-            cols += [jnp.take(meta.group, best.feature),
-                     jnp.take(meta.offset, best.feature),
-                     jnp.take(meta.zero_bin, best.feature)]
-        n_base = len(cols)
-        if sp.has_categorical:
-            # cat bitset words carry full 32-bit patterns: pre-split into
-            # positive 16-bit halves so the byte decomposition below stays
-            # exact
-            bs = best.cat_bitset
-            cols = (cols + [best.is_cat.astype(i32)]
-                    + [bs[:, w] & 0xFFFF for w in range(W)]
-                    + [(bs[:, w] >> 16) & 0xFFFF for w in range(W)])
-        packed = jnp.stack(cols, axis=1)                # [NLp, nc] < 2^24
-        # per-row table lookup as a one-hot MXU matmul instead of an XLA
-        # row gather (~1GB/s on TPU): values are decomposed into bytes so
-        # the bf16 operands are exact, and each output sums exactly one
-        # nonzero product — bit-exact reconstruction
-        nc = packed.shape[1]
-        tab = jnp.concatenate([packed & 255, (packed >> 8) & 255,
-                               (packed >> 16) & 255], axis=1)
         with global_timer.device_scope("Tree::partition"):
+            small_left = best.left_count <= best.right_count
+            cols = [split_sel.astype(i32), best.feature, best.threshold,
+                    best.default_left.astype(i32), newleaf_of,
+                    jnp.take(meta.missing_type, best.feature),
+                    jnp.take(meta.default_bin, best.feature),
+                    jnp.take(meta.num_bin, best.feature),
+                    rank_of, small_left.astype(i32)]
+            if params.has_bundles:
+                cols += [jnp.take(meta.group, best.feature),
+                         jnp.take(meta.offset, best.feature),
+                         jnp.take(meta.zero_bin, best.feature)]
+            n_base = len(cols)
+            if sp.has_categorical:
+                # cat bitset words carry full 32-bit patterns: pre-split into
+                # positive 16-bit halves so the byte decomposition below stays
+                # exact
+                bs = best.cat_bitset
+                cols = (cols + [best.is_cat.astype(i32)]
+                        + [bs[:, w] & 0xFFFF for w in range(W)]
+                        + [(bs[:, w] >> 16) & 0xFFFF for w in range(W)])
+            packed = jnp.stack(cols, axis=1)                # [NLp, nc] < 2^24
+            # per-row table lookup as a one-hot MXU matmul instead of an XLA
+            # row gather (~1GB/s on TPU): values are decomposed into bytes so
+            # the bf16 operands are exact, and each output sums exactly one
+            # nonzero product — bit-exact reconstruction
+            nc = packed.shape[1]
+            tab = jnp.concatenate([packed & 255, (packed >> 8) & 255,
+                                   (packed >> 16) & 255], axis=1)
             oh_rows = (leaf_id[:, None] ==
                        jnp.arange(NLp, dtype=i32)[None, :]).astype(
                            jnp.bfloat16)
@@ -700,53 +714,53 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
                 oh_rows, tab.astype(jnp.bfloat16),
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)      # [n, 3*nc]
-        prow = (got[:, :nc].astype(i32)
-                + (got[:, nc:2 * nc].astype(i32) << 8)
-                + (got[:, 2 * nc:].astype(i32) << 16))
-        sel_r = prow[:, 0] > 0
-        feat_r = prow[:, 1]
-        thr_r = prow[:, 2]
-        dleft_r = prow[:, 3] > 0
-        new_r = prow[:, 4]
-        mt_r = prow[:, 5]
-        db_r = prow[:, 6]
-        nb_r = prow[:, 7]
-        rank_r = prow[:, 8]
-        sleft_r = prow[:, 9] > 0
-        if params.has_bundles:
-            grp_r = prow[:, 10]
-            off_r = prow[:, 11]
-            zb_r = prow[:, 12]
-            col_r = grp_r
-        else:
-            col_r = feat_r
-        # per-row bin of the row's split column (one-hot select over F')
-        fbin = jnp.sum(jnp.where(
-            col_r[None, :] == jnp.arange(binned.shape[0],
-                                         dtype=i32)[:, None],
-            binned.astype(i32), 0), axis=0)
-        if params.has_bundles:
-            local = fbin - off_r
-            fbin = jnp.where((local >= 0) & (local < nb_r), local, zb_r)
-        is_missing = (((mt_r == MISSING_NAN) & (fbin == nb_r - 1))
-                      | ((mt_r == MISSING_ZERO) & (fbin == db_r)))
-        go_left = jnp.where(is_missing, dleft_r, fbin <= thr_r)
-        if sp.has_categorical:
-            isc_r = prow[:, n_base] > 0
-            widx = jnp.clip(fbin // 32, 0, W - 1)[:, None]
-            w_lo = jnp.take_along_axis(
-                prow[:, n_base + 1:n_base + 1 + W], widx, 1)[:, 0]
-            w_hi = jnp.take_along_axis(
-                prow[:, n_base + 1 + W:n_base + 1 + 2 * W], widx, 1)[:, 0]
-            word_r = w_lo | (w_hi << 16)
-            cat_left = ((word_r >> (fbin % 32)) & 1) > 0
-            go_left = jnp.where(isc_r, cat_left, go_left)
-        leaf_id = jnp.where(sel_r & ~go_left, new_r, leaf_id)
-        # the NEXT wave's computed-slot assignment rides this recolor pass
-        # (no extra per-row gather): a row is in the computed set iff it
-        # landed in its pair's smaller child; everyone else gets the
-        # out-of-range sentinel Lp, which matches no slot one-hot bucket
-        kslot = jnp.where(sel_r & (go_left == sleft_r), rank_r, Lp)
+            prow = (got[:, :nc].astype(i32)
+                    + (got[:, nc:2 * nc].astype(i32) << 8)
+                    + (got[:, 2 * nc:].astype(i32) << 16))
+            sel_r = prow[:, 0] > 0
+            feat_r = prow[:, 1]
+            thr_r = prow[:, 2]
+            dleft_r = prow[:, 3] > 0
+            new_r = prow[:, 4]
+            mt_r = prow[:, 5]
+            db_r = prow[:, 6]
+            nb_r = prow[:, 7]
+            rank_r = prow[:, 8]
+            sleft_r = prow[:, 9] > 0
+            if params.has_bundles:
+                grp_r = prow[:, 10]
+                off_r = prow[:, 11]
+                zb_r = prow[:, 12]
+                col_r = grp_r
+            else:
+                col_r = feat_r
+            # per-row bin of the row's split column (one-hot select over F')
+            fbin = jnp.sum(jnp.where(
+                col_r[None, :] == jnp.arange(binned.shape[0],
+                                             dtype=i32)[:, None],
+                binned.astype(i32), 0), axis=0)
+            if params.has_bundles:
+                local = fbin - off_r
+                fbin = jnp.where((local >= 0) & (local < nb_r), local, zb_r)
+            is_missing = (((mt_r == MISSING_NAN) & (fbin == nb_r - 1))
+                          | ((mt_r == MISSING_ZERO) & (fbin == db_r)))
+            go_left = jnp.where(is_missing, dleft_r, fbin <= thr_r)
+            if sp.has_categorical:
+                isc_r = prow[:, n_base] > 0
+                widx = jnp.clip(fbin // 32, 0, W - 1)[:, None]
+                w_lo = jnp.take_along_axis(
+                    prow[:, n_base + 1:n_base + 1 + W], widx, 1)[:, 0]
+                w_hi = jnp.take_along_axis(
+                    prow[:, n_base + 1 + W:n_base + 1 + 2 * W], widx, 1)[:, 0]
+                word_r = w_lo | (w_hi << 16)
+                cat_left = ((word_r >> (fbin % 32)) & 1) > 0
+                go_left = jnp.where(isc_r, cat_left, go_left)
+            leaf_id = jnp.where(sel_r & ~go_left, new_r, leaf_id)
+            # the NEXT wave's computed-slot assignment rides this recolor pass
+            # (no extra per-row gather): a row is in the computed set iff it
+            # landed in its pair's smaller child; everyone else gets the
+            # out-of-range sentinel Lp, which matches no slot one-hot bucket
+            kslot = jnp.where(sel_r & (go_left == sleft_r), rank_r, Lp)
 
         if sp.has_cegb:
             # all of this wave's winning features become used (coupled
@@ -999,7 +1013,8 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
             leaf_count=leaf_count_f, leaf_parent=leaf_parent_f,
             leaf_depth=leaf_depth_f,
             split_is_cat=gat(tree.split_is_cat, False),
-            cat_bitset=gat(tree.cat_bitset))
+            cat_bitset=gat(tree.cat_bitset),
+            waves=tree.waves)
 
         # rows: overgrown leaf slot -> nearest kept ancestor's side leaf.
         # Walk up until the current node is kept (or the root is passed);
@@ -1053,7 +1068,8 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
 
     tree, leaf_id = state[0], state[1]
     if prune and num_waves > 0:
-        tree, leaf_id = _prune_to_leafwise(tree, leaf_id)
+        with global_timer.device_scope("Tree::prune"):
+            tree, leaf_id = _prune_to_leafwise(tree, leaf_id)
     elif num_waves > 0:
         # exact final counts from the final partition (ref: DataPartition
         # cnt_leaf_data).  A one-hot MXU matmul instead of a 1M-element

@@ -19,11 +19,14 @@ Knobs:
   * `profile_dir=` — brackets training with jax.profiler start/stop_trace
   * `roofline=` — compiled-cost harvesting + per-phase measured MFU
   * `metrics_port=` — Prometheus `GET /metrics` listener
-  * `LIGHTGBM_TPU_TIMETAG=1` — host phase timers (utils/timer.py)
-  * `LIGHTGBM_TPU_TRACE=1` — jax.profiler.TraceAnnotation per scope
+  * `LIGHTGBM_TPU_TIMETAG=1` — the phase timers sync at phase boundaries
+    (utils/timer.py; recording itself is always on)
+  * `global_timer.set_trace_annotations(True)` — a
+    jax.profiler.TraceAnnotation per scope
 """
 
-from .compile_cache import configure_compile_cache
+from .compile_cache import (configure_compile_cache,
+                            first_iter_compile_phases)
 from .costmodel import (backend_peaks, enable_cost_model,
                         global_cost_model, roofline)
 from .events import (EventLogger, emit_event, get_event_logger,
@@ -41,7 +44,7 @@ from .watchdog import (RecompileDetector, sample_device_memory,
                        update_memory_gauges)
 
 __all__ = [
-    "AsyncWriter", "configure_compile_cache",
+    "AsyncWriter", "configure_compile_cache", "first_iter_compile_phases",
     "backend_peaks", "enable_cost_model", "global_cost_model", "roofline",
     "EventLogger", "emit_event", "get_event_logger", "set_event_logger",
     "FlightRecorder", "dump_flight_record", "flight_file_path",

@@ -32,11 +32,28 @@ same config can assert hits > 0 (tests/test_async_io.py).
 Only programs whose compile takes >= 1 s are persisted (the ladder
 compile is the multi-second cost being amortized); the micro-jits
 around it recompile cheaply each process.
+
+Compile phases: the same bus carries how long JAX spent tracing to a
+jaxpr, lowering to MLIR, in the backend's compiler and reading the cache.
+A second listener adds them into the registry as `jit_trace_s`,
+`jit_lower_s`, `backend_compile_s` and `cache_load_s` (process totals, in
+seconds), each event's OWN time: on this jaxlib a cache hit is reported
+inside `backend_compile_duration`, and a jitted function traced inside
+another's trace reports inside it, so what an event holds of later-named
+events is taken off it and the four counters are disjoint — they add up
+to the wall-clock the thread spent compiling.  A booster's first
+iteration, where the train programs are traced, lowered and compiled or
+loaded, keeps its share apart as `first_iter_<counter>`
+(`first_iter_compile_phases`): the process totals also hold the binning program,
+later iterations' small jits and whatever predicts afterwards.
 """
 
 from __future__ import annotations
 
 import os
+import threading
+import time
+from contextlib import contextmanager
 from typing import Optional
 
 from ..utils import log
@@ -45,6 +62,13 @@ from .registry import global_registry
 _EVENT_COUNTERS = {
     "/jax/compilation_cache/cache_hits": "compile_cache_hits",
     "/jax/compilation_cache/cache_misses": "compile_cache_misses",
+}
+
+_DURATION_COUNTERS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit_trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit_lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_compile_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load_s",
 }
 
 CACHE_OFF = "off"
@@ -62,9 +86,60 @@ def _on_monitoring_event(event: str, **_kwargs) -> None:
         global_registry.inc(name)
 
 
+# per thread: the (start, seconds) of the compile-phase events seen so
+# far that no later event has contained.  JAX reports an event when it
+# ENDS, so the events an event contains are the stack's tail.
+_phase_tls = threading.local()
+_listeners_installed = False
+
+
+def _on_duration_event(event: str, duration: float, **_kwargs) -> None:
+    name = _DURATION_COUNTERS.get(event)
+    if name is None:
+        return
+    start = time.perf_counter() - duration
+    stack = getattr(_phase_tls, "stack", None)
+    if stack is None:
+        stack = _phase_tls.stack = []
+    own = duration
+    # events are nested or one after the other: what began after this one
+    # began is inside it (the slack is the listeners' own latency)
+    while stack and stack[-1][0] >= start - 1e-4:
+        own -= stack.pop()[1]
+    stack.append((start, duration))
+    if len(stack) > 8192:    # top-level events nothing will contain
+        del stack[:4096]
+    global_registry.inc(name, max(own, 0.0))
+
+
+@contextmanager
+def first_iter_compile_phases():
+    """Adds what the four compile-phase counters gain inside the block
+    (a booster's first iteration) to `first_iter_<counter>` as well."""
+    names = tuple(_DURATION_COUNTERS.values())
+    before = [global_registry.counter(n) for n in names]
+    try:
+        yield
+    finally:
+        for name, was in zip(names, before):
+            global_registry.inc("first_iter_" + name,
+                                global_registry.counter(name) - was)
+
+
+def _install_listeners() -> None:
+    global _listeners_installed
+    if _listeners_installed:
+        return
+    import jax
+    jax.monitoring.register_event_listener(_on_monitoring_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_duration_event)
+    _listeners_installed = True
+
+
 def configure_compile_cache(cache_dir: str = "") -> Optional[str]:
     """Turn on JAX's persistent compilation cache (placement rules in
-    the module docstring) and install the hit/miss counter listener.
+    the module docstring) and install the hit/miss and compile-phase
+    listeners (the latter also when the cache is off).
     Returns the directory in force, None when the cache is off.
     Idempotent — a later call returns the placement already made,
     except that `off` always wins (the auto_degrade rung may arrive
@@ -72,6 +147,7 @@ def configure_compile_cache(cache_dir: str = "") -> Optional[str]:
     created raises."""
     global _configured_dir
     import jax
+    _install_listeners()
     cache_dir = os.fspath(cache_dir or "").strip()
     turn_off = cache_dir.lower() == CACHE_OFF
     if _configured_dir is not None and not (turn_off and _configured_dir):
@@ -106,7 +182,6 @@ def configure_compile_cache(cache_dir: str = "") -> Optional[str]:
     # ladder compile, and persisting the dozens of micro-jits around it
     # buys nothing
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    jax.monitoring.register_event_listener(_on_monitoring_event)
     _configured_dir = target
     log.debug(f"Persistent compilation cache at {target}")
     return target

@@ -3,39 +3,42 @@
 TPU-native analogue of the reference's TIMETAG instrumentation
 (ref: include/LightGBM/utils/common.h:973-1010 Timer/FunctionTimer,
 instantiated as `global_timer` in src/boosting/gbdt.cpp:22 and printed at
-process exit).  Enabled by the LIGHTGBM_TPU_TIMETAG env var (the
-reference's compile-time flag becomes a runtime switch); scopes can also
-emit jax.profiler TraceAnnotations — driven by the LIGHTGBM_TPU_TRACE
-env var or `set_trace_annotations(True)` — so device timelines in a
-profiler carry the same names.
+process exit).
 
-Two scope flavours (docs/Observability.md):
+Recording and syncing are two switches (docs/Observability.md):
 
-* `scope(name)` — host-side phases (gradients, grow dispatch, finalize,
-  eval, checkpoint I/O).  Wall-clock accumulates per call.  Because jax
-  dispatch is asynchronous, callers of device work should `block()` the
-  phase's outputs inside the scope so the phase is charged for the work
-  it dispatched — `block()` is a no-op when timing is off, so the hot
-  path stays fully pipelined in production.
-* `device_scope(name)` — for code INSIDE jitted programs (histogram
-  build, split find, partition, collectives).  It wraps the traced ops
-  in `jax.named_scope`, so the phase name survives into the compiled
-  XLA program and shows up on profiler timelines; the host-side
-  accumulation only measures trace time (once per compile).
+* recording is always on: `scope(name)` adds `(seconds, calls)` to the
+  name's total — two clock reads and a dict add, like
+  `MetricsRegistry.inc`.  Because jax dispatch is asynchronous, a scope
+  around device work measures the HOST's part (trace, dispatch, the wait
+  of whoever fetches a result) unless the sync switch is on;
+* the sync switch (`sync`; the LIGHTGBM_TPU_TIMETAG env var, and
+  `train(metrics_dir=...)` for the run) makes `block(x)` call
+  `block_until_ready`, so the enclosing scope is charged for the device
+  work it dispatched, and credits the settle wait to a separate
+  `<scope>::device` entry.  That de-pipelines the loop it times: it is
+  for phase breakdowns, never for a throughput number.  Off, `block()`
+  is the identity and no sync is added anywhere.
 
-Device-time attribution: `block(x)` inside a scope additionally credits
-the settle wait to a separate `<scope>::device` entry, so a phase
-breakdown separates HOST dispatch time from DEVICE execution time — the
-serving bench and `timer_top_ms` read both.  The scope stack is
-thread-local (the serving coalescer times dispatches concurrently with
-the main thread); accumulator updates take a lock only when timing is
-enabled, so the production hot path is untouched.
+`set_trace_annotations(True)` additionally emits a
+`jax.profiler.TraceAnnotation` per scope (with the scope's keyword
+attributes, e.g. `iter=3`), so a profiler's host timeline carries the
+same names on the device's clock.
+
+`device_scope(name)` is for code INSIDE jitted programs (histogram build,
+split find, partition, collectives): it wraps the traced ops in
+`jax.named_scope`, so the name (with `::` as `.`) goes into each op's
+`op_name` metadata, which a profiler trace keeps per op
+(benchmarks/scope_trace.py reads it).  It records nothing on the host:
+the body runs once per compile, at trace time.
+
+The scope stack is thread-local (the serving coalescer times dispatches
+concurrently with the main thread).
 """
 
 from __future__ import annotations
 
 import atexit
-import functools
 import os
 import threading
 import time
@@ -47,15 +50,12 @@ from typing import Dict, Tuple
 class Timer:
     """Aggregates wall-clock per named scope (ref: common.h:973 Timer)."""
 
-    def __init__(self, enabled: bool = False,
-                 use_jax_profiler: bool = None):
-        self.enabled = enabled
+    def __init__(self, sync: bool = False, use_jax_profiler: bool = False):
+        self.sync = sync
         self._acc: Dict[str, float] = defaultdict(float)
         self._cnt: Dict[str, int] = defaultdict(int)
         self._alock = threading.Lock()
         self._tls = threading.local()
-        if use_jax_profiler is None:
-            use_jax_profiler = bool(os.environ.get("LIGHTGBM_TPU_TRACE", ""))
         self._use_jax_profiler = use_jax_profiler
 
     def _scope_stack(self):
@@ -64,10 +64,14 @@ class Timer:
             st = self._tls.stack = []
         return st
 
+    def _add(self, name: str, dt: float) -> None:
+        with self._alock:
+            self._acc[name] += dt
+            self._cnt[name] += 1
+
     # ------------------------------------------------------- profiler wiring
     def set_trace_annotations(self, on: bool) -> None:
-        """Toggle jax.profiler.TraceAnnotation emission from scopes (the
-        runtime form of the LIGHTGBM_TPU_TRACE env switch)."""
+        """Toggle jax.profiler.TraceAnnotation emission from scopes."""
         self._use_jax_profiler = bool(on)
 
     def trace_annotations_enabled(self) -> bool:
@@ -75,16 +79,13 @@ class Timer:
 
     # ---------------------------------------------------------------- scopes
     @contextmanager
-    def scope(self, name: str):
-        """RAII scope (ref: common.h:1000 FunctionTimer)."""
-        use_trace = self._use_jax_profiler
-        if not self.enabled and not use_trace:
-            yield
-            return
+    def scope(self, name: str, **attrs):
+        """RAII scope (ref: common.h:1000 FunctionTimer).  `attrs` go to
+        the TraceAnnotation (an iteration or tree index), not the total."""
         ctx = None
-        if use_trace:
+        if self._use_jax_profiler:
             import jax.profiler
-            ctx = jax.profiler.TraceAnnotation(name)
+            ctx = jax.profiler.TraceAnnotation(name, **attrs)
             ctx.__enter__()
         stack = self._scope_stack()
         stack.append(name)
@@ -92,38 +93,31 @@ class Timer:
         try:
             yield
         finally:
+            dt = time.perf_counter() - t0
             stack.pop()
             if ctx is not None:
                 ctx.__exit__(None, None, None)
-            if self.enabled:
-                dt = time.perf_counter() - t0
-                with self._alock:
-                    self._acc[name] += dt
-                    self._cnt[name] += 1
+            self._add(name, dt)
 
     @contextmanager
     def device_scope(self, name: str):
         """Scope for code traced INSIDE a jitted program: tags the traced
-        ops with jax.named_scope so the phase name reaches the XLA program
-        (and profiler device timelines); host accumulation sees trace time
-        only (once per compile), not per-call device time."""
+        ops with jax.named_scope so the name reaches each op's `op_name`
+        metadata (and through it a profiler's device timeline)."""
         import jax
         with jax.named_scope(name.replace("::", ".")):
-            with self.scope(name):
-                yield
+            yield
 
     def block(self, x):
-        """block_until_ready(x) when timing is on, so the enclosing scope
-        is charged for the device work it dispatched (async dispatch
-        otherwise bills whichever later phase syncs first).  Identity
-        when timing is off — production dispatch stays pipelined.
+        """block_until_ready(x) when the sync switch is on, so the
+        enclosing scope is charged for the device work it dispatched
+        (async dispatch otherwise bills whichever later phase syncs
+        first).  Identity when it is off — dispatch stays pipelined.
 
         The settle wait is ALSO credited to `<enclosing scope>::device`:
-        the enclosing scope's total is unchanged (dispatch + settle, as
-        before), and the ::device entry says how much of it the chip
-        owned — per-phase DEVICE time attribution with no call-site
-        changes."""
-        if not self.enabled or x is None:
+        the enclosing scope's total is unchanged (dispatch + settle), and
+        the ::device entry says how much of it the chip owned."""
+        if not self.sync or x is None:
             return x
         t0 = time.perf_counter()
         try:
@@ -133,21 +127,8 @@ class Timer:
             return x
         stack = self._scope_stack()
         if stack:
-            dt = time.perf_counter() - t0
-            with self._alock:
-                self._acc[stack[-1] + "::device"] += dt
-                self._cnt[stack[-1] + "::device"] += 1
+            self._add(stack[-1] + "::device", time.perf_counter() - t0)
         return x
-
-    def timeit(self, name: str):
-        """Decorator form."""
-        def deco(fn):
-            @functools.wraps(fn)
-            def wrapped(*a, **k):
-                with self.scope(name):
-                    return fn(*a, **k)
-            return wrapped
-        return deco
 
     # --------------------------------------------------------------- results
     def items(self) -> Tuple[Tuple[str, float, int], ...]:
@@ -178,7 +159,6 @@ class Timer:
             log.info(f"  {name}: {sec * 1000:.3f} ms ({cnt} calls)")
 
 
-global_timer = Timer(
-    enabled=bool(os.environ.get("LIGHTGBM_TPU_TIMETAG", "")))
-if global_timer.enabled:
+global_timer = Timer(sync=bool(os.environ.get("LIGHTGBM_TPU_TIMETAG", "")))
+if global_timer.sync:
     atexit.register(global_timer.print)

@@ -110,16 +110,38 @@ def test_rows_kernel_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_grow_tree_wave_program_compiles_on_one_chip(one_chip):
-    """The whole-tree program `lgb.train` runs per iteration on a TPU."""
+@pytest.fixture(scope="module")
+def grow_compiled(one_chip):
+    """The whole-tree program `lgb.train` runs per iteration on a TPU,
+    compiled once for the tests below."""
     from lightgbm_tpu.learner.wave import grow_tree_wave
-    compiled = grow_tree_wave.lower(
+    return grow_tree_wave.lower(
         *_grow_args(one_chip, one_chip, one_chip),
         params=_grow_params()).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    mem = compiled.memory_analysis()
+
+
+def test_grow_tree_wave_program_compiles_on_one_chip(grow_compiled):
+    assert "tpu_custom_call" in grow_compiled.as_text()
+    mem = grow_compiled.memory_analysis()
     # bin matrix, scores and labels are resident next to it on a 16 GB chip
     assert mem.temp_size_in_bytes < 8 << 30, mem
+
+
+def test_grow_program_names_its_kernels_and_scopes(grow_compiled):
+    """What the benchmark's trace readers hold on to: each Pallas
+    custom-call is named after its kernel (`pallas_call(name=...)`; the
+    profiler's event name starts with the instruction's), 9 calls a tree,
+    and the ops around them carry the program's scopes in `op_name`."""
+    import re
+    calls = re.findall(r"^\s*%([\w.\-]+) = .*custom_call_target="
+                       r'"tpu_custom_call"', grow_compiled.as_text(), re.M)
+    heads = sorted({re.sub(r"\.\d+$", "", c) for c in calls})
+    assert heads == ["build_histogram_wave", "build_histogram_wave_hl"]
+    assert len(calls) >= 9
+    text = grow_compiled.as_text()
+    for scope in ("Tree.hist_operands", "Tree.histogram", "Tree.cache",
+                  "Tree.split_find", "Tree.partition"):
+        assert f"/{scope}/" in text, scope
 
 
 def test_sharded_wave_program_compiles_on_four_chips(topo):

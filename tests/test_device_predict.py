@@ -343,13 +343,13 @@ def test_booster_predict_routes_device(binary_cat):
     g.config.device_predict = "true"
     try:
         from lightgbm_tpu.utils.timer import global_timer
-        was = global_timer.enabled
-        global_timer.enabled = True
+        was = global_timer.sync
+        global_timer.sync = True
         global_timer.reset()
         dev_pred = bst.predict(Xt)
         dev_leaf = bst.predict(Xt, pred_leaf=True)
         scopes = [name for name, _, _ in global_timer.items()]
-        global_timer.enabled = was
+        global_timer.sync = was
         global_timer.reset()
         assert "GBDT::predict_device" in scopes
         assert np.array_equal(dev_leaf, host_leaf)

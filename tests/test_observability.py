@@ -89,7 +89,7 @@ def test_event_log_one_iteration_event_per_round(tmp_path):
     # grow is always among the recorded phases
     assert any("GBDT::grow_tree" in e["phases"] for e in iters)
     # metrics run must not leave the global timer force-enabled
-    assert global_timer.enabled == bool(
+    assert global_timer.sync == bool(
         os.environ.get("LIGHTGBM_TPU_TIMETAG", ""))
 
 

@@ -31,9 +31,8 @@ import numpy as np
 
 
 def _time(fn, *args, reps=3):
-    """fn must return a SCALAR (the device-loop pattern of
-    tools/profile_hl.py: reduce on device, pull one float, so the D2H
-    copy of a whole array stays out of the timing)."""
+    """fn must return a SCALAR (reduce on device, pull one float, so the
+    D2H copy of a whole array stays out of the timing)."""
     float(fn(*args))                # compile + first-run autotune
     best = float("inf")
     for _ in range(reps):
