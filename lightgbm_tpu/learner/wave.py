@@ -59,18 +59,19 @@ from ..utils.timer import global_timer
 
 def _hist_wave_xla(binned_fm, slot, gh, *, max_bin, num_slots):
     """XLA fallback (CPU tests): per-slot masked histograms via one-hot
-    einsum.  Small shapes only.  gh's LAST column is the count mask;
-    returns (hist [NL, F, B, C], counts [NL]) like the Pallas kernel."""
+    einsum.  Small shapes only.  gh is [C+1, n] like the Pallas kernels'
+    (its LAST row is the count mask); returns (hist [NL, F, B, C],
+    counts [NL]) like them."""
     oh_slot = (slot[:, None]
                == jnp.arange(num_slots, dtype=jnp.int32)[None, :])  # [n, NL]
     oh_bin = (binned_fm[:, :, None] ==
               jnp.arange(max_bin, dtype=jnp.int32)[None, None, :])  # [F,n,B]
     # [NL, F, B, C]; histograms are exact accumulators, so force fp32
     # contraction (the TPU default would round operands to bf16)
-    hist = jnp.einsum("nl,fnb,nc->lfbc", oh_slot.astype(jnp.float32),
-                      oh_bin.astype(jnp.float32), gh[:, :-1],
+    hist = jnp.einsum("nl,fnb,cn->lfbc", oh_slot.astype(jnp.float32),
+                      oh_bin.astype(jnp.float32), gh[:-1],
                       precision=jax.lax.Precision.HIGHEST)
-    counts = jnp.einsum("nl,n->l", oh_slot.astype(jnp.float32), gh[:, -1],
+    counts = jnp.einsum("nl,n->l", oh_slot.astype(jnp.float32), gh[-1],
                         precision=jax.lax.Precision.HIGHEST)
     return hist, counts
 
@@ -109,10 +110,14 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
             # (the int8 path recovers exact grid integers from k * scale)
             grad = snap_to_operand_grid(grad, params.hist_method)
             hess = snap_to_operand_grid(hess, params.hist_method)
-        # 2 histogram channels; the trailing column is the count mask
+        # 2 histogram channels; the trailing row is the count mask
         # consumed by the kernel's fused per-slot count output (output
-        # lanes are the MXU cost driver — see _wave_kernel)
-        gh = jnp.stack([grad, hess, row_mask], axis=1)
+        # lanes are the MXU cost driver — see _wave_kernel).  [C+1, n],
+        # rows on lanes: the layout the three vectors are born in and the
+        # kernels' blocks read, so it is built once a tree and no wave
+        # re-lays it out (stacked on the minor axis it came out
+        # column-major, 512 B a row once padded to the chip's tiling)
+        gh = jnp.stack([grad, hess, row_mask], axis=0)
 
     # Under shard_map (parallel/data_parallel.py) rows are the local shard:
     # every row-axis reduction is completed by a psum over the data axis —

@@ -182,8 +182,10 @@ def _wave_kernel(C: int, Fg: int, Bg: int, NLg: int):
 
     Per (slot-group, bin-group, feature-group, row-tile) grid cell, build
     the [Fg, Bg, Rt] bin one-hot and the slot-separated channel matrix
-    [Rt, C*NLg] in VMEM, then ONE MXU dot accumulates all NLg leaves' and
-    all C channels' histograms at once.  The leaf-slot axis is what fills
+    [C*NLg, Rt] in VMEM — rows on lanes in both, like the operand blocks
+    slot [1, Rt] and gh [C+1, Rt] they are built from — then ONE MXU dot
+    (both operands contracted over their lane axis, the q·kᵀ form)
+    accumulates all NLg leaves' and all C channels' histograms at once.  The leaf-slot axis is what fills
     the MXU's 128-wide output dimension — a plain per-leaf histogram dot
     has C=2 output columns and idles 126/128 of the systolic array, which
     is the dominant cost of histogram construction on TPU.  Fusing the
@@ -213,55 +215,43 @@ def _wave_kernel(C: int, Fg: int, Bg: int, NLg: int):
         # iota: the one-hot construction is the per-wave VPU floor, so
         # every elementwise pass over the big shape counts
         rows = rows_ref[...].astype(jnp.int32) - bg * Bg  # [Fg, Rt]
-        slot = slot_ref[...].astype(jnp.int32)           # [Rt, 1]
-        gh = gh_ref[...]                                 # [Rt, C+1]
+        slot = slot_ref[...]                             # [1, Rt]
         Rt = rows.shape[1]
         biota = jax.lax.broadcasted_iota(jnp.int32, (Fg, Bg, Rt), 1)
         oh = (rows[:, None, :] == biota).astype(mxu_t)
         oh2 = oh.reshape(Fg * Bg, Rt)
+        lanes = (((1,), (1,)), ((), ()))     # contract both over rows
         S = out_ref.shape[-1] // (C * NLg)
         for s in range(S):  # slot groups REUSE the bin one-hot (its VPU
             # construction, not the MXU dot, is the per-wave cost floor)
-            loc = slot - s * NLg
-            soh = (loc == jax.lax.broadcasted_iota(jnp.int32, (Rt, NLg), 1))
-            # [Rt, C*NLg] (c-major): channel value where the slot matches
-            # (built 2-D per channel — Mosaic cannot insert a bf16 minor dim)
-            if int8_mode:
-                # select in int32 (Mosaic relayouts i1->i8 selects badly),
-                # then narrow to int8 for the MXU operand
-                sc = jnp.concatenate(
-                    [jnp.where(soh,
-                               jnp.broadcast_to(gh[:, c:c + 1], (Rt, NLg)),
-                               0).astype(jnp.int8)
-                     for c in range(C)], axis=1)
-            else:
-                sohb = soh.astype(jnp.bfloat16)
-                sc = jnp.concatenate(
-                    [sohb * gh[:, c:c + 1].astype(jnp.bfloat16)
-                     for c in range(C)], axis=1)
+            # rows stay on lanes: the slot one-hot [NLg, Rt] and the
+            # slot-separated channel matrix [C*NLg, Rt] (c-major) are
+            # built from sublane broadcasts of the [1, Rt] operand rows —
+            # dense vregs at any slot count, where a [Rt, NLg] one-hot
+            # padded NLg to 128 lanes
+            soh = (slot - s * NLg ==
+                   jax.lax.broadcasted_iota(jnp.int32, (NLg, Rt), 0))
+            # select in 32 bits (Mosaic relayouts i1->i8 selects badly and
+            # cannot multiply int8), then narrow for the MXU
+            sc = jnp.concatenate(
+                [jnp.where(soh, gh_ref[c:c + 1, :], 0)
+                 for c in range(C)], axis=0).astype(mxu_t)
             acc = jax.lax.dot_general(
-                oh2, sc, (((1,), (0,)), ((), ())),
+                oh2, sc, lanes,
                 preferred_element_type=acc_t)            # [Fg*Bg, C*NLg]
             # lane dim stays flat (Mosaic cannot split the lane dim); the
             # caller unscrambles the (slot-group, channel, slot) layout
             w = C * NLg
             out_ref[:, :, s * w:(s + 1) * w] += acc.reshape(Fg, Bg, w)
             # exact per-slot row counts ride along as a [8, NLg] dot of the
-            # mask column (gh[:, C]) against the slot one-hot — one cell
-            # only, replacing a separate 20ms scatter-add pass
+            # mask row (gh[C]) against the slot one-hot — one cell only,
+            # replacing a separate 20ms scatter-add pass
             @pl.when((bg == 0) & (g == 0))
             def _count():
-                if int8_mode:
-                    mask8 = jnp.broadcast_to(gh[:, C:C + 1],
-                                             (Rt, 8)).T.astype(jnp.int8)
-                    sohm = jnp.where(
-                        soh, 1, 0).astype(jnp.int8)
-                else:
-                    mask8 = jnp.broadcast_to(
-                        gh[:, C:C + 1].astype(mxu_t), (Rt, 8)).T
-                    sohm = soh.astype(mxu_t)
+                mask8 = jnp.broadcast_to(gh_ref[C:C + 1, :],
+                                         (8, Rt)).astype(mxu_t)
                 cacc = jax.lax.dot_general(
-                    mask8, sohm, (((1,), (0,)), ((), ())),
+                    mask8, jnp.where(soh, 1, 0).astype(mxu_t), lanes,
                     preferred_element_type=acc_t)        # [8, NLg]
                 cnt_ref[:, s * NLg:(s + 1) * NLg] += cacc
     return kernel
@@ -290,7 +280,10 @@ def _wave_kernel_hl(C: int, Fg: int, Bh: int, Bl: int, S: int, P: int):
 
         d  = [lo_rm | 1] @ [E ; -bl_pat]   (lo minus the column's target
                                             bl; zero exactly on match)
-        wt = w_sc @ T                      (tile CS channels across cols)
+        wt = w_sc_tᵀ @ T                   (tile CS channels across cols;
+                                            w_sc_t [C*S, Rt] is built rows
+                                            on lanes from the slot [1, Rt]
+                                            and gh [C+1, Rt] blocks)
         sc = where(d == 0, wt, 0)
 
     Main dots pack P features into M and P column blocks into N; only the
@@ -310,18 +303,20 @@ def _wave_kernel_hl(C: int, Fg: int, Bh: int, Bl: int, S: int, P: int):
         rows = rows_ref[...].astype(i32)          # [Fg, Rt] (lanes=Rt)
         Rt = rows.shape[1]
         rows_rm = rows_rm_ref[...].astype(i32)    # [Rt, Fg] (sublanes=Rt)
-        slot = slot_ref[...].astype(i32)          # [Rt, 1]
-        gh = gh_ref[...]                          # [Rt, C+1]
+        slot = slot_ref[...]                      # [1, Rt]
 
         hi = rows >> shift
         biota = jax.lax.broadcasted_iota(i32, (Fg, Bh, Rt), 1)
         hi_oh = (hi[:, None, :] == biota).astype(bf16)
 
-        # w_sc [Rt, C*S]: slot one-hot x channels (c-major)
-        soh = (slot == jax.lax.broadcasted_iota(i32, (Rt, S), 1))
-        sohb = soh.astype(bf16)
-        w_sc = jnp.concatenate(
-            [sohb * gh[:, c:c + 1].astype(bf16) for c in range(C)], axis=1)
+        # w_sc_t [C*S, Rt] (c-major), rows on lanes: row c*S + s holds
+        # channel c where the row's slot is s (selected by a row iota, not
+        # concatenated per channel: S < 8 rows are no whole sublane tile)
+        r_cs = jax.lax.broadcasted_iota(i32, (CS, Rt), 0)
+        val = gh_ref[C - 1:C, :]
+        for c in range(C - 2, -1, -1):
+            val = jnp.where(r_cs < (c + 1) * S, gh_ref[c:c + 1, :], val)
+        w_sc_t = jnp.where(slot == r_cs % S, val, 0.0).astype(bf16)
 
         lo = (rows_rm & (Bl - 1)).astype(bf16)    # [Rt, Fg]
         ones = jnp.ones((Rt, 1), bf16)
@@ -336,7 +331,7 @@ def _wave_kernel_hl(C: int, Fg: int, Bh: int, Bl: int, S: int, P: int):
         csp = jax.lax.broadcasted_iota(i32, (CS, Wd), 1)
         Tm = (csp % CS ==
               jax.lax.broadcasted_iota(i32, (CS, Wd), 0)).astype(bf16)
-        wt = jax.lax.dot_general(w_sc, Tm, (((1,), (0,)), ((), ())),
+        wt = jax.lax.dot_general(w_sc_t, Tm, (((0,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         sc = jnp.where(d == 0.0, wt, 0.0).astype(bf16)        # [Rt, Wd]
 
@@ -349,10 +344,14 @@ def _wave_kernel_hl(C: int, Fg: int, Bh: int, Bl: int, S: int, P: int):
             for p in range(P):
                 out_ref[f0 + p] += acc[p * Bh:(p + 1) * Bh,
                                        p * BCS:(p + 1) * BCS]
-        # ride-along exact counts (mask column against the slot one-hot)
-        mask8 = jnp.broadcast_to(gh[:, C:C + 1].astype(bf16), (Rt, 8)).T
-        cacc = jax.lax.dot_general(mask8, sohb, (((1,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
+        # ride-along exact counts (mask row against the slot one-hot)
+        # (a whole sublane tile of slots: Mosaic cannot widen a one-row
+        # i1 one-hot)
+        mask8 = jnp.broadcast_to(gh_ref[C:C + 1, :], (8, Rt)).astype(bf16)
+        soh_t = (slot == jax.lax.broadcasted_iota(i32, (max(S, 8), Rt), 0))
+        cacc = jax.lax.dot_general(
+            mask8, soh_t.astype(bf16), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)[:, :S]        # [8, S]
         cnt_ref[...] += cacc
     return kernel
 
@@ -388,27 +387,30 @@ def build_histogram_wave_hl(binned_fm: jnp.ndarray, binned_rm: jnp.ndarray,
                             max_bin: int, num_slots: int, out_slots: int,
                             row_tile: int = 512):
     """Decomposed-kernel variant of `build_histogram_wave` for waves with
-    few computed slots (see `_wave_kernel_hl`).  `num_slots` is the TRUE
-    computed-slot bound; the output is zero-padded to `out_slots` rows so
-    callers keep the padded-Kb contract.  Returns
+    few computed slots (see `_wave_kernel_hl`).  Same operands —
+    binned_fm [F, n], slot [n] int32, gh [C+1, n] with the count mask as
+    its last row — plus binned_rm [n, F], the row-major copy of the bins
+    for the kernel's lo side.  `num_slots` is the TRUE computed-slot
+    bound; the output is zero-padded to `out_slots` rows so callers keep
+    the padded-Kb contract.  Returns
     (hist [out_slots, F, B, C] float32, counts [out_slots] float32)."""
     F, n = binned_fm.shape
-    C = gh.shape[-1] - 1
+    C = gh.shape[0] - 1
     S = num_slots
     Bh, Bl = hl_split_of(max_bin, S, C)
     P = next((p for p in (4, 2, 1) if F % p == 0 and p * Bh <= 256), 1)
     if n % row_tile != 0:
         raise ValueError(f"n {n} not a multiple of row_tile {row_tile}")
     with global_timer.device_scope("Tree::hist_operands"):
-        slot_col = slot.reshape(n, 1)
+        slot_row = slot.reshape(1, n)
     out, cnt = pl.pallas_call(
         _wave_kernel_hl(C, F, Bh, Bl, S, P),
         grid=(n // row_tile,),
         in_specs=[
             pl.BlockSpec((F, row_tile), lambda i: (0, i)),
             pl.BlockSpec((row_tile, F), lambda i: (i, 0)),
-            pl.BlockSpec((row_tile, 1), lambda i: (i, 0)),
-            pl.BlockSpec((row_tile, C + 1), lambda i: (i, 0))],
+            pl.BlockSpec((1, row_tile), lambda i: (0, i)),
+            pl.BlockSpec((C + 1, row_tile), lambda i: (0, i))],
         out_specs=[
             pl.BlockSpec((F, Bh, Bl * C * S), lambda i: (0, 0, 0)),
             pl.BlockSpec((8, S), lambda i: (0, 0))],
@@ -416,7 +418,7 @@ def build_histogram_wave_hl(binned_fm: jnp.ndarray, binned_rm: jnp.ndarray,
             jax.ShapeDtypeStruct((F, Bh, Bl * C * S), jnp.float32),
             jax.ShapeDtypeStruct((8, S), jnp.float32)],
         name="build_histogram_wave_hl",
-    )(binned_fm, binned_rm, slot_col, gh)
+    )(binned_fm, binned_rm, slot_row, gh)
     # [F, Bh, (bl, c, s)] -> [S, F, B, C], zero-padded to out_slots
     h = out.reshape(F, Bh, Bl, C, S).transpose(4, 0, 1, 2, 3)
     h = h.reshape(S, F, Bh * Bl, C)[:, :, :max_bin, :]
@@ -480,8 +482,11 @@ def build_histogram_wave(binned_fm: jnp.ndarray, slot: jnp.ndarray,
     Args:
       binned_fm: [F, n] feature-major bin codes.
       slot: [n] int32 leaf slot per row.
-      gh: [n, C+1] per-row accumulands (gradient, hessian, ..., row-mask);
-        the LAST column is the count mask (zeros for excluded rows).
+      gh: [C+1, n] per-row accumulands (gradient, hessian, ..., row-mask),
+        rows on the minor axis — the layout the [n] vectors are born in
+        and the kernel's (C+1, row_tile) blocks read, so no operand is
+        padded to 128 lanes in HBM or VMEM; the LAST row is the count
+        mask (zeros for excluded rows).
       max_bin: B (static).  num_slots: NL leaf slots (static).
       quant_bins: when > 0, gh's channels carry grid-snapped quantized
         values (ref: gradient_discretizer.cpp DiscretizeGradients): the
@@ -493,7 +498,7 @@ def build_histogram_wave(binned_fm: jnp.ndarray, slot: jnp.ndarray,
     Returns: (hist [NL, F, B, C] float32, counts [NL] float32).
     """
     F, n = binned_fm.shape
-    C = gh.shape[-1] - 1
+    C = gh.shape[0] - 1
     use_int8 = quant_scales is not None
     if use_int8:
         assert quant_bins <= 126, "int8 grid bound"
@@ -502,9 +507,9 @@ def build_histogram_wave(binned_fm: jnp.ndarray, slot: jnp.ndarray,
         # round() recovers the exact ints
         with global_timer.device_scope("Tree::hist_operands"):
             gh = jnp.concatenate(
-                [jnp.round(gh[:, :C] / quant_scales[None, :])
+                [jnp.round(gh[:C] / quant_scales[:, None])
                  .astype(jnp.int32),
-                 (gh[:, C:] > 0).astype(jnp.int32)], axis=1)
+                 (gh[C:] > 0).astype(jnp.int32)], axis=0)
     NLp = wave_slot_pad(num_slots)
     NLg = min(NLp, 128)
     Bp = max(8, (max_bin + 7) // 8 * 8)
@@ -536,14 +541,14 @@ def build_histogram_wave(binned_fm: jnp.ndarray, slot: jnp.ndarray,
         Fg = _pick_feature_group(Fp, unit, 6 << 20)
     acc_t = jnp.int32 if use_int8 else jnp.float32
     with global_timer.device_scope("Tree::hist_operands"):
-        slot_col = slot.reshape(n, 1)
+        slot_row = slot.reshape(1, n)
     out, cnt = pl.pallas_call(
         _wave_kernel(C, Fg, Bg, NLg),
         grid=(Bp // Bg, Fp // Fg, n // row_tile),
         in_specs=[
             pl.BlockSpec((Fg, row_tile), lambda bg, g, i: (g, i)),
-            pl.BlockSpec((row_tile, 1), lambda bg, g, i: (i, 0)),
-            pl.BlockSpec((row_tile, C + 1), lambda bg, g, i: (i, 0))],
+            pl.BlockSpec((1, row_tile), lambda bg, g, i: (0, i)),
+            pl.BlockSpec((C + 1, row_tile), lambda bg, g, i: (0, i))],
         out_specs=[
             pl.BlockSpec((Fg, Bg, S * C * NLg),
                          lambda bg, g, i: (g, bg, 0)),
@@ -552,7 +557,7 @@ def build_histogram_wave(binned_fm: jnp.ndarray, slot: jnp.ndarray,
             jax.ShapeDtypeStruct((Fp, Bp, S * C * NLg), acc_t),
             jax.ShapeDtypeStruct((8, NLp), acc_t)],
         name="build_histogram_wave",
-    )(binned_fm, slot_col, gh)
+    )(binned_fm, slot_row, gh)
     # [Fp, Bp, (s, c, lg)] -> [NL, F, B, C]
     out = out.reshape(Fp, Bp, S, C, NLg).transpose(2, 4, 0, 1, 3)
     hist = out.reshape(S * NLg, Fp, Bp, C)[:num_slots, :F, :max_bin, :]

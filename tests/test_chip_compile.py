@@ -20,6 +20,8 @@ collect.  Everything built from the topology is built in a fixture or a
 test, in this one file, in this process.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -51,9 +53,9 @@ def _sds(shape, dtype, sharding):
 
 
 def _kernel_args(sh):
-    """(binned_fm [F, N] u8, slot [N] i32, gh [N, 3] f32) on `sh`."""
+    """(binned_fm [F, N] u8, slot [N] i32, gh [3, N] f32) on `sh`."""
     return (_sds((F, N), "uint8", sh), _sds((N,), "int32", sh),
-            _sds((N, 3), "float32", sh))
+            _sds((3, N), "float32", sh))
 
 
 def _grow_args(row, by_row, repl):
@@ -123,8 +125,63 @@ def grow_compiled(one_chip):
 def test_grow_tree_wave_program_compiles_on_one_chip(grow_compiled):
     assert "tpu_custom_call" in grow_compiled.as_text()
     mem = grow_compiled.memory_analysis()
-    # bin matrix, scores and labels are resident next to it on a 16 GB chip
-    assert mem.temp_size_in_bytes < 8 << 30, mem
+    # the program's temporaries: 406,655,488 B at these 2^20 rows (388 B a
+    # row) plus 10%.  With gh [N, 3] and slot [N, 1] padded to 128 lanes
+    # it read 1,825,379,840 (1.74 KB a row): a rise to that is a per-row
+    # operand back in a padded layout (PERF.md section 4)
+    assert mem.temp_size_in_bytes < int(406_655_488 * 1.1), mem
+
+
+def _top_level_instructions(text):
+    """(name, result type with layout, opcode) of every instruction that
+    is not inside a fusion's own computation."""
+    fused, out = False, []
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            fused = head.group(1).startswith("fused_computation")
+            continue
+        m = re.match(r"^\s*(?:ROOT )?%([\w.\-]+) = (\(.*?\)|\S+) "
+                     r"([\w\-]+)\(", line)
+        if m and not fused:
+            out.append(m.groups())
+    return out
+
+
+def test_grow_program_hands_the_kernels_unpadded_row_operands(grow_compiled):
+    """The layout contract between the wave engine and its kernels: gh
+    [3, N] and slot [1, N], rows on lanes, built once a tree.  With gh
+    [N, 3] and slot [N, 1] this program held 8 + 8 copies into
+    `{1,0:T(8,128)}` (one a wave, 512 B a row each for 12 and 4 B of
+    content): 35 ms of a 214 ms iteration on the chip (PERF.md, PR 29)."""
+    text = grow_compiled.as_text()
+    instrs = _top_level_instructions(text)
+    assert len(instrs) > 1000                     # the parser still reads
+    padded = re.compile(rf"^(f32\[{N},3\]|s32\[{N},1\])\{{1,0:T\(8,128\)")
+    copies = [i for i in instrs if i[2] == "copy" and padded.match(i[1])]
+    assert not copies, copies
+    # no 32-bit kernel operand is a per-row array with fewer than 128
+    # minor elements, row-major.  (u8[N, 28], the decomposed kernel's
+    # row-major bins, is not this contract's and stays out.)
+    types = {name: typ for name, typ, _ in instrs}
+    calls = re.findall(r"= .*? custom-call\(([^)]*)\), "
+                       r'custom_call_target="tpu_custom_call"', text)
+    assert len(calls) >= 9
+    narrow = re.compile(rf"^[fs]32\[{N},(\d+)\]\{{1,0")
+    for operands in calls:
+        for name in re.findall(r"%([\w.\-]+)", operands):
+            m = narrow.match(types[name])
+            assert not (m and int(m.group(1)) < 128), (name, types[name])
+    # gh is materialised once, before the first wave: everything else of
+    # its shape hands it on (the conds' and the while's tuples; the
+    # compiler's own same-layout move out of the memory space `S(1)` it
+    # places the fusion's result in — an async `copy-start`/`copy-done`,
+    # where a relayout is a `copy`)
+    hands_on = {"get-tuple-element", "parameter", "bitcast", "copy-start",
+                "copy-done"}
+    made = [i for i in instrs if i[1].startswith(f"f32[3,{N}]")
+            and i[2] not in hands_on]
+    assert len(made) <= 1, made
 
 
 def test_grow_program_names_its_kernels_and_scopes(grow_compiled):
@@ -132,7 +189,6 @@ def test_grow_program_names_its_kernels_and_scopes(grow_compiled):
     custom-call is named after its kernel (`pallas_call(name=...)`; the
     profiler's event name starts with the instruction's), 9 calls a tree,
     and the ops around them carry the program's scopes in `op_name`."""
-    import re
     calls = re.findall(r"^\s*%([\w.\-]+) = .*custom_call_target="
                        r'"tpu_custom_call"', grow_compiled.as_text(), re.M)
     heads = sorted({re.sub(r"\.\d+$", "", c) for c in calls})

@@ -35,6 +35,51 @@ def test_kernel_checks_pass_in_interpret_mode(interpret_pallas):
     assert run_checks() == "ok"
 
 
+@pytest.mark.parametrize("num_slots", [1, 2, 4, 8, 64, 255])
+def test_wave_kernels_equal_numpy_exactly(interpret_pallas, num_slots):
+    """One operand contract — binned [F, n], slot [n], gh [C+1, n] with
+    the count mask as its last row — for the fused kernel, the decomposed
+    kernel (few slots) and the XLA stand-in: on grid-snapped inputs
+    (eighths, so every fp32 sum is exact in any order) histograms AND
+    ride-along counts equal a numpy scatter to the last bit, with a third
+    of the rows carrying the out-of-range sentinel slot the recolour
+    gives rows outside every computed leaf."""
+    from lightgbm_tpu.learner.wave import _hist_wave_xla
+    from lightgbm_tpu.ops.histogram import (build_histogram_wave,
+                                            build_histogram_wave_hl,
+                                            wave_slot_pad)
+    n, F, B = 1536, 5, 32                          # shapes of no other test
+    sentinel = wave_slot_pad(255)
+    rng = np.random.RandomState(num_slots)
+    binned = rng.randint(0, B, (F, n)).astype(np.uint8)
+    slot = np.where(rng.rand(n) < 2 / 3, rng.randint(0, num_slots, n),
+                    sentinel).astype(np.int32)
+    mask = (rng.rand(n) < 0.9).astype(np.float32)
+    gh = np.stack([rng.randint(-16, 17, n) / 8.0 * mask,
+                   rng.randint(1, 17, n) / 8.0 * mask,
+                   mask]).astype(np.float32)        # [3, n]
+    want = np.zeros((num_slots, F, B, 2), np.float32)
+    inb = slot < num_slots
+    for f in range(F):
+        for c in range(2):
+            np.add.at(want[:, f, :, c], (slot[inb], binned[f][inb]),
+                      gh[c][inb])
+    want_cnt = np.bincount(slot[inb], weights=mask[inb],
+                           minlength=num_slots).astype(np.float32)
+    args = (jnp.asarray(binned), jnp.asarray(slot), jnp.asarray(gh))
+    got = {"wave": build_histogram_wave(*args, max_bin=B,
+                                        num_slots=num_slots),
+           "xla": _hist_wave_xla(*args, max_bin=B, num_slots=num_slots)}
+    if num_slots <= 8:
+        got["hl"] = build_histogram_wave_hl(
+            args[0], args[0].T, *args[1:], max_bin=B, num_slots=num_slots,
+            out_slots=num_slots)
+    for name, (hist, cnt) in got.items():
+        np.testing.assert_array_equal(np.asarray(hist), want, err_msg=name)
+        np.testing.assert_array_equal(np.asarray(cnt), want_cnt,
+                                      err_msg=name)
+
+
 def _binary_problem(n, F, B, seed=0):
     """Binned rows plus binary-logloss gradients at a score whose hessian
     (0.2447...) is NOT a bf16 value: rounding it costs 4.4e-4 a row."""

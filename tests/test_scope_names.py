@@ -94,7 +94,7 @@ def test_kernel_wrappers_scope_their_operand_building():
                                             build_histogram_wave_hl)
     binned = jnp.zeros((F, N), jnp.uint8)
     slot = jnp.zeros(N, jnp.int32)
-    gh = jnp.zeros((N, 3), jnp.float32)
+    gh = jnp.zeros((3, N), jnp.float32)
     for jaxpr in (
             jax.make_jaxpr(lambda *a: build_histogram_wave(
                 *a, max_bin=B, num_slots=8))(binned, slot, gh),
@@ -102,7 +102,7 @@ def test_kernel_wrappers_scope_their_operand_building():
                 *a, max_bin=B, num_slots=2, out_slots=8))(
                     binned, binned.T, slot, gh),
             jax.make_jaxpr(lambda *a: build_histogram_rows_pallas(
-                *a, max_bin=B))(binned.T, gh[:, :2], gh[:, 2])):
+                *a, max_bin=B))(binned.T, gh[:2].T, gh[2])):
         text = jaxpr.pretty_print(name_stack=True)
         assert "Tree.hist_operands" in text
         assert "pallas_call" in text

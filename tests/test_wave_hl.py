@@ -34,14 +34,14 @@ def test_hl_wave_matches_scatter_oracle(S, out_slots):
     g = rng.randn(n).astype(np.float32)
     h = rng.rand(n).astype(np.float32)
     mask = (rng.rand(n) < 0.9).astype(np.float32)
-    gh = np.stack([g * mask, h * mask, mask], 1).astype(np.float32)
+    gh = np.stack([g * mask, h * mask, mask], 0).astype(np.float32)
     hist, cnt = build_histogram_wave_hl(
         jnp.asarray(binned), jnp.asarray(binned.T), jnp.asarray(slot),
         jnp.asarray(gh), max_bin=B, num_slots=S, out_slots=out_slots)
     assert hist.shape == (out_slots, F, B, 2)
     # oracle at the kernel's bf16 operand precision
-    gb = np.asarray(jnp.asarray(gh[:, 0]).astype(jnp.bfloat16), np.float64)
-    hb = np.asarray(jnp.asarray(gh[:, 1]).astype(jnp.bfloat16), np.float64)
+    gb = np.asarray(jnp.asarray(gh[0]).astype(jnp.bfloat16), np.float64)
+    hb = np.asarray(jnp.asarray(gh[1]).astype(jnp.bfloat16), np.float64)
     exp = np.zeros((out_slots, F, B, 2))
     inb = slot < S
     for f in range(F):
@@ -65,7 +65,7 @@ def test_hl_wave_matches_full_kernel():
     slot = rng.randint(0, 2 * S, n).astype(np.int32)
     slot = np.where(slot < S, slot, 10 ** 6).astype(np.int32)
     gh = np.stack([rng.randn(n), rng.rand(n), np.ones(n)],
-                  1).astype(np.float32)
+                  0).astype(np.float32)
     h1, c1 = build_histogram_wave_hl(
         jnp.asarray(binned), jnp.asarray(binned.T), jnp.asarray(slot),
         jnp.asarray(gh), max_bin=B, num_slots=S, out_slots=8)

@@ -34,7 +34,7 @@ def test_int8_wave_matches_integer_oracle():
     hi = rng.randint(0, qbins + 1, n)
     mask = (rng.rand(n) < 0.9).astype(np.float32)
     gh = np.stack([gi * gscale * mask, hi * hscale * mask, mask],
-                  1).astype(np.float32)
+                  0).astype(np.float32)
     h, c = build_histogram_wave(
         jnp.asarray(binned), jnp.asarray(slot), jnp.asarray(gh),
         max_bin=B, num_slots=NL, quant_bins=qbins,

@@ -34,7 +34,7 @@ def _mk(n=2048, F=8, B=64, slots=8, seed=0):
     grad = rng.randn(n).astype(np.float32)
     hess = np.abs(rng.rand(n).astype(np.float32)) + 0.5
     mask = (rng.rand(n) < 0.9).astype(np.float32)
-    gh = np.stack([grad * mask, hess * mask, mask], 1)
+    gh = np.stack([grad * mask, hess * mask, mask], 0)    # [C+1, n]
     # the kernels' MXU operands are bf16 (single-precision histograms,
     # like the reference GPU learner): snap inputs to the bf16 grid so
     # host fp64 ground truth and on-chip fp32 accumulation agree exactly
@@ -44,9 +44,9 @@ def _mk(n=2048, F=8, B=64, slots=8, seed=0):
 
 
 def _host_hist(binned, slot, gh, B, slots):
-    """NumPy ground truth [slots, F, B, C]."""
+    """NumPy ground truth [slots, F, B, C] from gh [C+1, n]."""
     F, n = binned.shape
-    C = gh.shape[1] - 1
+    C = gh.shape[0] - 1
     out = np.zeros((slots, F, B, C), np.float64)
     cnt = np.zeros(slots, np.float64)
     for r in range(n):
@@ -54,8 +54,8 @@ def _host_hist(binned, slot, gh, B, slots):
         if s >= slots:
             continue
         for f in range(F):
-            out[s, f, binned[f, r], :] += gh[r, :C]
-        cnt[s] += gh[r, C]
+            out[s, f, binned[f, r], :] += gh[:C, r]
+        cnt[s] += gh[C, r]
     return out, cnt
 
 
@@ -104,12 +104,12 @@ def run_checks():
     try:
         qb = 16
         scales = np.array([0.11, 0.07], np.float32)
-        kg = np.random.RandomState(1).randint(-qb, qb + 1, gh.shape[0])
-        kh = np.random.RandomState(2).randint(0, qb + 1, gh.shape[0])
-        mk = np.asarray(gh)[:, 2]
+        kg = np.random.RandomState(1).randint(-qb, qb + 1, gh.shape[1])
+        kh = np.random.RandomState(2).randint(0, qb + 1, gh.shape[1])
+        mk = gh_np[2]
         # grid values pre-masked like the engine (grad*mask stays on grid)
         ghq = np.stack([kg * scales[0] * mk, kh * scales[1] * mk,
-                        mk], 1).astype(np.float32)
+                        mk], 0).astype(np.float32)
         hq, cq = build_histogram_wave(
             binned, slot, jnp.asarray(ghq), max_bin=B, num_slots=slots,
             quant_bins=qb, quant_scales=jnp.asarray(scales))
@@ -124,9 +124,9 @@ def run_checks():
     # 4. single-leaf row-major Pallas histogram vs segment lowering
     try:
         rows = jnp.asarray(np.ascontiguousarray(np.asarray(binned).T))
-        mask = gh[:, 2]
-        hp = build_histogram_rows_pallas(rows, gh[:, :2], mask, max_bin=B)
-        hs = build_histogram(binned, gh[:, :2], mask, max_bin=B,
+        mask = gh[2]
+        hp = build_histogram_rows_pallas(rows, gh[:2].T, mask, max_bin=B)
+        hs = build_histogram(binned, gh[:2].T, mask, max_bin=B,
                              method="segment")
         if not np.allclose(np.asarray(hp), np.asarray(hs),
                            rtol=1e-5, atol=1e-4):
