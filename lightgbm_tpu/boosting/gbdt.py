@@ -285,18 +285,23 @@ class GBDT:
         elif (config.enable_bundle and train_data.num_features > 1
                 and not voting_engages):
             from ..io.bundle import build_bundled, plan_bundles
-            plan_src = binned
-            if isinstance(binned, jax.Array):
-                # device-binned: plan from host bins of the construction
-                # sample, which the dataset kept — no device gather, no
-                # D2H of matrix columns (the cost of gathering them from
-                # a local chip instead: not re-measured since bring-up)
-                plan_src = train_data.efb_sample_bins()
-                if plan_src is None:
-                    plan_src = train_data.binned_host()
-            plan = plan_bundles(plan_src, train_data.bin_mappers,
-                                train_data.used_features,
-                                max_conflict_rate=config.max_conflict_rate)
+            # host span: the planner and the binning of its row sample
+            # (benchmarks' efb_plan_s)
+            with global_timer.scope("GBDT::plan_bundles"):
+                plan_src = binned
+                if isinstance(binned, jax.Array):
+                    # device-binned: plan from host bins of the
+                    # construction sample, which the dataset kept — no
+                    # device gather, no D2H of matrix columns (the cost
+                    # of gathering them from a local chip instead: not
+                    # re-measured since bring-up)
+                    plan_src = train_data.efb_sample_bins()
+                    if plan_src is None:
+                        plan_src = train_data.binned_host()
+                plan = plan_bundles(
+                    plan_src, train_data.bin_mappers,
+                    train_data.used_features,
+                    max_conflict_rate=config.max_conflict_rate)
             if plan.effective:
                 self.bundle_plan = plan
                 if isinstance(binned, jax.Array):

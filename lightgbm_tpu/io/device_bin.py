@@ -37,10 +37,16 @@ def bounds_to_f32_floor(bounds64: np.ndarray) -> np.ndarray:
 
 
 def device_binnable(mappers, used_features, data_dtype, num_data: int,
-                    min_rows: int = 1 << 20) -> bool:
-    """Gate for the device second pass: float32 data, large-n, numeric
-    features only, uint8-range bins, and a TPU backend present."""
-    if data_dtype != np.float32 or num_data < min_rows:
+                    min_cells: int = 28 << 20) -> bool:
+    """Gate for the device second pass: float32 data, a matrix large
+    enough to pay for the transfer and the program, numeric features
+    only, uint8-range bins, and a TPU backend present.  Large is counted
+    in cells, rows x features, as the host pass's cost is: the gate was
+    set at 2^20 rows of 28 features and stays there, and 400,000 rows of
+    2,000 features (27x the cells; 800M host `searchsorted`s at 47 ns)
+    pass it where a row count refused them (PERF.md, PR 30)."""
+    if (data_dtype != np.float32
+            or num_data * len(used_features) < min_cells):
         return False
     for f in used_features:
         m = mappers[f]

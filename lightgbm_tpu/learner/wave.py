@@ -137,14 +137,6 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
             # tpulint: disable-next=collective-discipline -- the wave engine's single histogram/count reduction point; parallel/data_parallel.py wraps this engine in shard_map and owns the data_axis contract
             return jax.lax.psum(x, params.data_axis)
 
-    binned_rm = None
-    if use_pallas and not use_int8:
-        # row-major copy for the decomposed small-S kernel's lo side
-        # (transposed once per tree; bins are static so XLA keeps it
-        # resident for all waves of the tree)
-        with global_timer.device_scope("Tree::hist_operands"):
-            binned_rm = binned.T
-
     def _hl_fits(true_slots):
         """VMEM gate for the decomposed kernel (no feature grouping)."""
         F_, Rt, C_ = binned.shape[0], 512, 2
@@ -153,6 +145,24 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
         vmem = (F_ * Bh * Rt * 2 + Rt * Wd * 10 + F_ * Bh * Bl
                 * C_ * true_slots * 4)
         return vmem <= (12 << 20)
+
+    def _hl_serves(true_slots):
+        """The decomposed kernel takes a wave of `true_slots` computed
+        slots: static, from shapes alone."""
+        return (use_pallas and not use_int8
+                and wave_hl_profitable(hist_B, true_slots)
+                and _hl_fits(true_slots))
+
+    binned_rm = None
+    # both gates only close as the slots grow: where one slot is refused
+    # (2,000 features: 40 MB of VMEM) no wave of this tree can use the
+    # row-major copy, and it is not built (0.8 GB there)
+    if _hl_serves(1):
+        # row-major copy for the decomposed small-S kernel's lo side
+        # (transposed once per tree; bins are static so XLA keeps it
+        # resident for all waves of the tree)
+        with global_timer.device_scope("Tree::hist_operands"):
+            binned_rm = binned.T
 
     def hists_of(kslot, ghm, num_slots, true_slots=None):
         """Group-space histograms for the COMPUTED (compact) slots only;
@@ -172,8 +182,7 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
                         num_slots=num_slots, quant_bins=params.quant_bins,
                         quant_scales=quant_scales)
                 elif (true_slots is not None and binned_rm is not None
-                        and wave_hl_profitable(hist_B, true_slots)
-                        and _hl_fits(true_slots)):
+                        and _hl_serves(true_slots)):
                     H, cnt = build_histogram_wave_hl(
                         binned, binned_rm, kslot, ghm, max_bin=hist_B,
                         num_slots=true_slots, out_slots=num_slots)

@@ -35,12 +35,25 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..observability.registry import global_registry
 from ..utils.timer import global_timer
 
 # Each kernel's `name` is its custom-call's name in a profiler trace
 # (`%build_histogram_wave.23 = ...`): the benchmark's readers find the
 # kernels by the head `build_histogram` (benchmarks/layer_metrics/), so a
 # rename here drops `hist_kernel_ms` out of the result line.
+
+
+def _count_traced_call(feature_groups: int) -> None:
+    """Registry counters of the wave kernels' wrappers, added where a
+    wrapper is TRACED (its body runs once per signature and enclosing
+    trace, not once per call on the device): `hist_kernel_calls` and the
+    feature groups those calls run, each of which re-streams `slot` and
+    `gh` over all rows.  Their ratio is groups per call however often
+    the program is compiled (benchmarks' `hist_groups_per_call`)."""
+    global_registry.inc("hist_kernel_calls")
+    global_registry.inc("hist_feature_group_passes", feature_groups)
+
 
 # lowerings whose MXU operands are bf16: each row's accumuland is rounded
 # to bf16 on its way into the dot (the one-hot side is exact, the
@@ -403,6 +416,7 @@ def build_histogram_wave_hl(binned_fm: jnp.ndarray, binned_rm: jnp.ndarray,
         raise ValueError(f"n {n} not a multiple of row_tile {row_tile}")
     with global_timer.device_scope("Tree::hist_operands"):
         slot_row = slot.reshape(1, n)
+    _count_traced_call(1)       # no feature grouping: blocks are (F, Rt)
     out, cnt = pl.pallas_call(
         _wave_kernel_hl(C, F, Bh, Bl, S, P),
         grid=(n // row_tile,),
@@ -432,6 +446,41 @@ def build_histogram_wave_hl(binned_fm: jnp.ndarray, binned_rm: jnp.ndarray,
     return h, cntv
 
 
+# VMEM gates of `build_histogram_wave`.  A call's footprint is counted in
+# `_wave_unit_bytes` a feature of its block: the f32 accumulator
+# [Bg, S*C*NLg] plus the bf16 bin one-hot [Bg, Rt].  The gates were set on
+# an older runtime at 28 features; PR 30 put them to the compiler that is
+# installed now (compile-only for a described v5e, whose scoped-VMEM
+# limit reads 16.00 MiB; tests/test_chip_compile.py keeps the wide cases)
+# and ran the grouped path on the chip at 2,000 features
+# (tools/kernel_checks.py --wide).  What was read:
+# * a grouped call's scoped allocation IS `Fg * unit` (25.0 MB counted,
+#   25.21 MiB allocated and refused; 10.0 and 13.3 MB compile), so groups
+#   up to ~15 MB would compile and 6 MB leaves room (raising it doubles
+#   Fg at 128 slots: a `perf_opt`, judged by the wide cell);
+# * a one-group call allocates the one-hot only (F * unit = 24 MB
+#   compiles at 128 slots, 32 MB misses by 248 KB), so 16 MB admits no
+#   shape the compiler refuses at any slot count: 240 features at 8
+#   slots, 232 at 1, 128 at 128 (63 bins), 32 at 128 and 20 at 255 slots
+#   (255 bins), each 15.0-16.0 MB, all compile;
+# * the smallest legal group (8 features) fits exactly while
+#   `8 * unit <= 16 MiB`: at 255 bins 895 slots (16.0 MB) compile and
+#   1,023 (18.0) do not; at 63 bins 2,047 (8.5) do and 4,095 (16.5) do
+#   not.  (`wave_pallas_vmem_ok` counted one slot group and was true for
+#   every shape.)
+_SCOPED_VMEM = 16 << 20     # the compiler's limit for one kernel
+_FULL_F_VMEM = 16 << 20     # one full-F block when F * unit fits this
+_GROUP_VMEM = 6 << 20       # else feature groups of at most this
+
+
+def _wave_unit_bytes(max_bin: int, num_slots: int, C: int = 2,
+                     row_tile: int = 512) -> int:
+    """VMEM bytes a feature of a `build_histogram_wave` block costs."""
+    NLp = wave_slot_pad(num_slots)
+    Bg = min(max(8, (max_bin + 7) // 8 * 8), 256)
+    return Bg * (NLp * C * 4 + row_tile * 2)
+
+
 def _pick_feature_group(Fp: int, unit_bytes: int, budget: int) -> int:
     """Largest 8-multiple divisor of Fp whose VMEM cost Fg*unit_bytes fits
     the budget (TPU blocks need 8-aligned sublane dims; 8 is the floor)."""
@@ -452,11 +501,11 @@ def wave_slot_pad(num_slots: int) -> int:
 
 def wave_pallas_vmem_ok(num_features: int, max_bin: int,
                         num_slots: int) -> bool:
-    """True when the wave kernel's VMEM accumulator fits at the smallest
-    legal tile (Fg=8, Bg<=128, NLg<=128, 3 channels)."""
-    Bg = min((max_bin + 7) // 8 * 8, 128)
-    NLg = min(wave_slot_pad(num_slots), 128)
-    return 3 * 8 * Bg * NLg * 4 <= (8 << 20)
+    """True when the wave kernel fits the chip's scoped VMEM at its
+    smallest legal feature group (8): accumulator and one-hot of all
+    `num_slots` slot groups, as the compiler counts them (readings
+    above)."""
+    return 8 * _wave_unit_bytes(max_bin, num_slots) <= _SCOPED_VMEM
 
 
 @functools.partial(jax.jit,
@@ -525,11 +574,8 @@ def build_histogram_wave(binned_fm: jnp.ndarray, slot: jnp.ndarray,
     # full-F group when its VMEM footprint fits — it avoids padding F up
     # to a multiple of 8 (12.5% wasted one-hot volume and MXU rows at the
     # bench's 28 features) and cuts grid-cell overheads.
-    unit = Bg * (S * C * NLg * 4 + row_tile * 2)
-    # gate at the measured 16 MB scoped-VMEM limit (wave.py's documented
-    # Mosaic bound) — shapes in the 16-24 MB window compile on CPU tests
-    # but can fail Mosaic on device; fall back to the grouped path there
-    if F * unit <= (16 << 20):
+    unit = _wave_unit_bytes(max_bin, num_slots, C, row_tile)
+    if F * unit <= _FULL_F_VMEM:
         Fp = Fg = F
     else:
         Fp = (F + 7) // 8 * 8
@@ -538,10 +584,11 @@ def build_histogram_wave(binned_fm: jnp.ndarray, slot: jnp.ndarray,
                 binned_fm = jnp.pad(binned_fm, ((0, Fp - F), (0, 0)))
         # feature group bounded by the VMEM accumulator [Fg, Bg, S*C*NLg]
         # plus the [Fg, Bg, Rt] bf16 one-hot
-        Fg = _pick_feature_group(Fp, unit, 6 << 20)
+        Fg = _pick_feature_group(Fp, unit, _GROUP_VMEM)
     acc_t = jnp.int32 if use_int8 else jnp.float32
     with global_timer.device_scope("Tree::hist_operands"):
         slot_row = slot.reshape(1, n)
+    _count_traced_call(Fp // Fg)
     out, cnt = pl.pallas_call(
         _wave_kernel(C, Fg, Bg, NLg),
         grid=(Bp // Bg, Fp // Fg, n // row_tile),

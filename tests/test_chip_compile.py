@@ -9,7 +9,10 @@ it, at no chip time.  Nothing runs, so this says nothing about results
 or speed — `chip_smoke.py` on the chip is what executes these programs.
 
 Shapes are the headline configuration's (`chip_smoke.py`, `bench.py`):
-28 features, 255 bins, 2^20 rows, 255 leaves.
+28 features, 255 bins, 2^20 rows, 255 leaves — and, in the `wide` cases,
+the widest benchmark cell's (`epsilon-400k-b63.train`): 2,000 features,
+63 bins, 400,384 padded rows, where the wave kernel runs in feature
+groups and the decomposed kernel does not fit.
 
 The topology is described inside a module-scoped fixture and nowhere
 else: only one process may load the TPU library, the suite runs under
@@ -31,6 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 F, B, N, LEAVES = 28, 255, 1 << 20, 255
+WIDE_F, WIDE_B, WIDE_N = 2000, 63, 400_384
 
 
 @pytest.fixture(scope="module")
@@ -52,13 +56,13 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, np.dtype(dtype), sharding=sharding)
 
 
-def _kernel_args(sh):
+def _kernel_args(sh, F=F, N=N):
     """(binned_fm [F, N] u8, slot [N] i32, gh [3, N] f32) on `sh`."""
     return (_sds((F, N), "uint8", sh), _sds((N,), "int32", sh),
             _sds((3, N), "float32", sh))
 
 
-def _grow_args(row, by_row, repl):
+def _grow_args(row, by_row, repl, F=F, N=N):
     """The positional arguments of a grow entry
     (boosting/gbdt.py train_one_iter), as shapes with their shardings."""
     from lightgbm_tpu.learner import FeatureMeta
@@ -71,10 +75,11 @@ def _grow_args(row, by_row, repl):
             _sds((F,), "bool", repl), meta)
 
 
-def _grow_params(**kw):
+def _grow_params(max_bin=B, **kw):
     from lightgbm_tpu.learner import GrowParams
     from lightgbm_tpu.ops.split import SplitParams
-    return GrowParams(num_leaves=LEAVES, max_bin=B, hist_method="pallas",
+    return GrowParams(num_leaves=LEAVES, max_bin=max_bin,
+                      hist_method="pallas",
                       split=SplitParams(min_data_in_leaf=20), **kw)
 
 
@@ -84,6 +89,39 @@ def test_wave_kernel_compiles(one_chip, num_slots):
     compiled = build_histogram_wave.lower(
         *_kernel_args(one_chip), max_bin=B, num_slots=num_slots).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("num_slots", [1, 8, 128])
+def test_wave_kernel_compiles_in_feature_groups(one_chip, num_slots):
+    """2,000 features: `F * unit` is 139-262 MB against the 16 MB gate,
+    so the kernel runs in groups of `_pick_feature_group(.., 6 MB)`
+    features (80 / 80 / 40 here).  Mosaic takes each (the gates date
+    from an older runtime and no shape wider than 28 had tried them);
+    the chip runs them in `tools/kernel_checks.py --wide`."""
+    from lightgbm_tpu.ops.histogram import build_histogram_wave
+    compiled = build_histogram_wave.lower(
+        *_kernel_args(one_chip, WIDE_F, WIDE_N), max_bin=WIDE_B,
+        num_slots=num_slots).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("num_slots,fits", [(895, True), (1023, False)])
+def test_smallest_group_gate_is_the_compilers(one_chip, num_slots, fits):
+    """`wave_pallas_vmem_ok` (the booster takes the leaf-wise engine
+    where it is false) against the compiler at 255 bins: 8 features of
+    895 slots count 16.0 MB and compile, of 1,023 slots 18.0 MB and are
+    refused for scoped VMEM."""
+    from lightgbm_tpu.ops.histogram import (build_histogram_wave,
+                                            wave_pallas_vmem_ok)
+    assert wave_pallas_vmem_ok(F, B, num_slots) is fits
+    lowered = build_histogram_wave.lower(
+        *_kernel_args(one_chip, F, 1 << 16), max_bin=B,
+        num_slots=num_slots)
+    if fits:
+        assert "tpu_custom_call" in lowered.compile().as_text()
+    else:
+        with pytest.raises(Exception, match="vmem"):
+            lowered.compile()
 
 
 def test_wave_kernel_int8_compiles(one_chip):
@@ -130,6 +168,44 @@ def test_grow_tree_wave_program_compiles_on_one_chip(grow_compiled):
     # it read 1,825,379,840 (1.74 KB a row): a rise to that is a per-row
     # operand back in a padded layout (PERF.md section 4)
     assert mem.temp_size_in_bytes < int(406_655_488 * 1.1), mem
+
+
+def test_wide_grow_program_compiles_on_one_chip(one_chip):
+    """The whole-tree program at the wide cell's shape: every wave
+    through the full kernel (the decomposed one has no feature grouping
+    and wants 40 MB of VMEM at one slot), no row-major copy of the bins,
+    and temporaries of 2,027,847,168 B (the leaf cache [384, 256,000]
+    f32 and its update's operands) plus 10%."""
+    from lightgbm_tpu.learner.wave import grow_tree_wave
+    compiled = grow_tree_wave.lower(
+        *_grow_args(one_chip, one_chip, one_chip, WIDE_F, WIDE_N),
+        params=_grow_params(max_bin=WIDE_B)).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"^\s*%([\w.\-]+) = .*custom_call_target="
+                       r'"tpu_custom_call"', text, re.M)
+    assert len(calls) >= 9
+    assert {re.sub(r"\.\d+$", "", c) for c in calls} == {
+        "build_histogram_wave"}
+    assert f"u8[{WIDE_N},{WIDE_F}]" not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < int(2_027_847_168 * 1.1), mem
+
+
+def test_bucketize_program_compiles_at_the_wide_shape(one_chip):
+    """`io/device_bin.py` at 458,752 padded rows x 2,000 features: the
+    float matrix (3.67 GB), its transposed copy and the bins fit the
+    chip together, and the [65,536, 2,000, 62] compare is fused into its
+    count (unfused it is 8.1 GB of its own: the temporaries read
+    7,428,338,176 B with it fused)."""
+    from lightgbm_tpu.io.device_bin import _bucketize_program
+    n_pad = 458_752
+    compiled = _bucketize_program().lower(
+        _sds((n_pad, WIDE_F), "float32", one_chip),
+        _sds((WIDE_F, WIDE_B - 1), "float32", one_chip),
+        _sds((WIDE_F,), "bool", one_chip),
+        _sds((WIDE_F,), "int32", one_chip), 1 << 16).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < int(7_428_338_176 * 1.05), mem
 
 
 def _top_level_instructions(text):

@@ -35,6 +35,14 @@ def test_kernel_checks_pass_in_interpret_mode(interpret_pallas):
     assert run_checks() == "ok"
 
 
+def test_wide_kernel_checks_pass_in_interpret_mode(interpret_pallas):
+    """tools/kernel_checks.py --wide, which runs on the chip at the
+    widest cell's 400,384 x 2,000: here at a size the interpreter
+    carries, wide enough for the feature-grouped path at 128 slots."""
+    from tools.kernel_checks import run_wide_checks
+    assert run_wide_checks(n=1024, F=200) == "ok"
+
+
 @pytest.mark.parametrize("num_slots", [1, 2, 4, 8, 64, 255])
 def test_wave_kernels_equal_numpy_exactly(interpret_pallas, num_slots):
     """One operand contract — binned [F, n], slot [n], gh [C+1, n] with

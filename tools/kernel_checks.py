@@ -15,6 +15,12 @@ Checks (small shapes, seconds of chip time):
   3. int8 quantized kernel: exact int32 accumulation of grid-snapped
      gradients (dequantized result equals the fp32 kernel on grid values)
   4. single-leaf Pallas histogram == segment lowering
+
+`run_wide_checks()` (`python tools/kernel_checks.py --wide`; a minute of
+chip time, so not part of `run_checks`) holds the fused wave kernel to a
+plain float32 reference at the widest benchmark cell's own shape,
+400,384 x 2,000 at 63 bins and 1 / 8 / 128 slots: the feature-grouped
+path, which 28 features never take.
 """
 import os
 import sys
@@ -138,5 +144,82 @@ def run_checks():
     return "ok" if not failures else "fail:" + ",".join(failures)
 
 
+def _wide_reference(binned_blk, slot, gh, B, slots):
+    """[slots, fb, B, 2] float32 histograms of a block of features, as
+    plain one-hot products in float32 (`highest`: the MXU's six-pass
+    float32 matmul, no bf16 anywhere)."""
+    import jax
+    import jax.numpy as jnp
+    fb, n = binned_blk.shape
+    with jax.default_matmul_precision("highest"):
+        oh_bin = (binned_blk[:, None, :]
+                  == jnp.arange(B, dtype=binned_blk.dtype)[None, :, None])
+        oh_slot = slot[None, :] == jnp.arange(slots, dtype=slot.dtype)[:, None]
+        w = (oh_slot[:, None, :].astype(jnp.float32)
+             * gh[None, :2, :]).reshape(slots * 2, n)
+        out = jnp.einsum("fbn,kn->fbk", oh_bin.astype(jnp.float32), w)
+    return out.reshape(fb, B, slots, 2).transpose(2, 0, 1, 3)
+
+
+def run_wide_checks(n=400_384, F=2000, B=63, slot_counts=(1, 8, 128),
+                    block=8, rel_tol=1e-5):
+    """The fused wave kernel through its feature-grouped path against
+    `_wide_reference`, computed `block` features at a time.  Returns
+    "ok" or "fail:<which>"; the readings go to stderr.
+
+    `rel_tol` 1e-5 of the largest sum, per channel: gradients and
+    hessians are on the bf16 grid, so the kernel's operand casts are
+    exact and the two sides differ only in the order of their float32
+    adds (read: 1e-7 and under).  A bf16 accumulator would miss by 1e-2.
+    Counts are exact."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.histogram import (build_histogram_wave,
+                                            snap_to_operand_grid,
+                                            wave_slot_pad)
+    kb, ks, kw, kg, kh, km = jax.random.split(jax.random.PRNGKey(30), 6)
+    binned = jax.random.randint(kb, (F, n), 0, B, jnp.int32).astype(jnp.uint8)
+    mask = (jax.random.uniform(km, (n,)) < 0.9).astype(jnp.float32)
+    gh = jnp.stack([
+        snap_to_operand_grid(jax.random.normal(kg, (n,)), "pallas") * mask,
+        snap_to_operand_grid(jax.random.uniform(kh, (n,)) * 0.25,
+                             "pallas") * mask,
+        mask])
+    reference = jax.jit(_wide_reference, static_argnums=(3, 4))
+    failures = []
+    for slots in slot_counts:
+        # a fifth of the rows outside every computed leaf, as the
+        # recolour leaves them
+        slot = jnp.where(jax.random.uniform(kw, (n,)) < 0.8,
+                         jax.random.randint(ks, (n,), 0, slots),
+                         wave_slot_pad(255)).astype(jnp.int32)
+        try:
+            hist, cnt = build_histogram_wave(binned, slot, gh, max_bin=B,
+                                             num_slots=slots)
+            err = jnp.zeros(2)
+            top = jnp.zeros(2)
+            for f0 in range(0, F, block):
+                ref = reference(binned[f0:f0 + block], slot, gh, B, slots)
+                diff = jnp.abs(hist[:, f0:f0 + block] - ref)
+                err = jnp.maximum(err, diff.max(axis=(0, 1, 2)))
+                top = jnp.maximum(top, jnp.abs(ref).max(axis=(0, 1, 2)))
+            rel = np.asarray(err / top)
+            s_np, m_np = np.asarray(slot), np.asarray(mask)
+            inb = s_np < slots
+            want_cnt = np.bincount(s_np[inb], weights=m_np[inb],
+                                   minlength=slots)
+            counts_exact = bool(np.array_equal(np.asarray(cnt), want_cnt))
+            print(f"wide {n}x{F} B={B} slots={slots}: rel_err={rel.tolist()}"
+                  f" counts_exact={counts_exact}", file=sys.stderr)
+            if not (rel <= rel_tol).all():
+                failures.append(f"wide_sums_{slots}")
+            if not counts_exact:
+                failures.append(f"wide_counts_{slots}")
+        except Exception as e:    # noqa: BLE001 - named in the verdict
+            traceback.print_exc()
+            failures.append(f"wide_raised_{slots}({type(e).__name__})")
+    return "ok" if not failures else "fail:" + ",".join(failures)
+
+
 if __name__ == "__main__":
-    print(run_checks())
+    print(run_wide_checks() if "--wide" in sys.argv[1:] else run_checks())
