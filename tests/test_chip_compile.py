@@ -291,3 +291,48 @@ def test_sharded_wave_program_compiles_on_four_chips(topo):
     hlo = jitted.lower(*_grow_args(row, by_row, repl)).compile().as_text()
     assert "tpu_custom_call" in hlo
     assert "all-reduce" in hlo
+
+
+HIGGS_N = 2_625_536     # the Higgs cells' padded rows a chip
+
+
+def _score_update_onehot(scores, class_id, leaf_vals, rate, leaf_id,
+                         pad_mask):
+    """`boosting/gbdt.py _score_update_shrink` with the form a TPU takes
+    (the booster's own closure picks by `jax.default_backend()`, which
+    is the CPU here)."""
+    from lightgbm_tpu.boosting.leaf_lookup import lookup_onehot
+    delta = lookup_onehot(leaf_vals * rate, leaf_id)
+    return scores.at[class_id].add(delta * pad_mask)
+
+
+def _score_update_compiled(scores_sh, row, repl, n):
+    return jax.jit(_score_update_onehot, donate_argnums=(0,)).lower(
+        _sds((1, n), "float32", scores_sh), 0,
+        _sds((LEAVES,), "float32", repl), 0.1, _sds((n,), "int32", row),
+        _sds((n,), "float32", row)).compile()
+
+
+def test_score_update_onehot_fuses_its_compare_on_one_chip(one_chip):
+    """An `[n, 255]` one-hot in memory would be 2.7 GB of int32 at the
+    Higgs cells' rows: the compiler folds the compare and the select
+    into the reduction, so the program's temporaries stay under a
+    megabyte (322,560 B when this was written; the gather's program
+    129,024 B), and no gather is left in it."""
+    compiled = _score_update_compiled(one_chip, one_chip, one_chip, HIGGS_N)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert not re.search(r"\bgather\(", compiled.as_text())
+
+
+def test_score_update_onehot_needs_no_collective_on_four_chips(topo):
+    """`tree_learner=data`: scores, leaf ids and the pad mask sharded by
+    rows, the leaf values replicated — every chip looks its own rows up
+    and nothing crosses chips."""
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    compiled = _score_update_compiled(
+        NamedSharding(mesh, P(None, "data")), NamedSharding(mesh, P("data")),
+        NamedSharding(mesh, P()), 4 * HIGGS_N)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert not re.search(r"\b(gather|all-reduce|all-gather|all-to-all|"
+                         r"collective-permute|reduce-scatter)[-a-z]*\(",
+                         compiled.as_text())

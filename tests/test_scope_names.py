@@ -3,7 +3,8 @@ of the train path is in the `op_name` metadata of the ops it covers
 (`jax.named_scope` -> MLIR location -> HLO `op_name` -> the profiler's
 `tf_op` stat, which benchmarks/scope_trace.py reads).  Checked on the
 lowered text of the three programs of a steady iteration at a tiny size:
-the wave grow program, the gradient program, the score update."""
+the wave grow program, the gradient program, the score update — which,
+under the TPU's rule, holds no gather."""
 
 import numpy as np
 import pytest
@@ -12,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 import lightgbm_tpu as lgb
+from lightgbm_tpu.boosting import leaf_lookup
 from lightgbm_tpu.learner import FeatureMeta, GrowParams
 from lightgbm_tpu.learner.wave import grow_tree_wave
 from lightgbm_tpu.ops.split import SplitParams
@@ -83,6 +85,35 @@ def test_score_update_programs_carry_their_scope(gbdt, fn_name):
             else (gbdt.scores, 0, leaf_vals, leaf_id, gbdt.pad_mask))
     text = _lowered_text(getattr(gbdt, fn_name).lower(*args))
     assert "GBDT.score_update" in text
+
+
+@pytest.mark.parametrize("backend,gathers", [("tpu", False), ("cpu", True)])
+@pytest.mark.parametrize("fn_name", ["_score_update_shrink_fn",
+                                     "_score_update_fn"])
+def test_score_update_on_a_tpu_holds_no_gather(monkeypatch, fn_name,
+                                               backend, gathers):
+    """At the benchmark cells' `num_leaves=255`, under the rule
+    `leaf_lookup.pick_form` applies on a TPU, the program reads each
+    row's leaf value by one-hot: no `stablehlo.gather` in its lowered
+    text, the scope still on it (`score_update_ms` reads the same label).
+    The CPU rule keeps the gather, which shows that the text would name
+    one."""
+    rule = leaf_lookup.pick_form
+    monkeypatch.setattr(leaf_lookup, "pick_form",
+                        lambda L, _: rule(L, backend))
+    rng = np.random.RandomState(0)
+    X = rng.randn(400, 3)
+    g = lgb.Booster({"objective": "binary", "num_leaves": 255,
+                     "verbosity": -1},
+                    lgb.Dataset(X, label=(X[:, 0] > 0) * 1.0))._gbdt
+    leaf_vals = jnp.zeros(255, jnp.float32)
+    leaf_id = jnp.zeros(g.n_pad, jnp.int32)
+    args = ((g.scores, 0, leaf_vals, 0.1, leaf_id, g.pad_mask)
+            if fn_name.endswith("shrink_fn")
+            else (g.scores, 0, leaf_vals, leaf_id, g.pad_mask))
+    text = _lowered_text(getattr(g, fn_name).lower(*args))
+    assert "GBDT.score_update" in text
+    assert ("stablehlo.gather" in text) is gathers
 
 
 def test_kernel_wrappers_scope_their_operand_building():

@@ -15,12 +15,20 @@ Checks (small shapes, seconds of chip time):
   3. int8 quantized kernel: exact int32 accumulation of grid-snapped
      gradients (dequantized result equals the fp32 kernel on grid values)
   4. single-leaf Pallas histogram == segment lowering
+  5. the score update's one-hot leaf-value lookup == `jnp.take`, bit for
+     bit, at 2 to 1,000 leaves with -0.0, a denormal, the largest float,
+     both infinities and a NaN among the values and ids outside the table
 
 `run_wide_checks()` (`python tools/kernel_checks.py --wide`; a minute of
 chip time, so not part of `run_checks`) holds the fused wave kernel to a
 plain float32 reference at the widest benchmark cell's own shape,
 400,384 x 2,000 at 63 bins and 1 / 8 / 128 slots: the feature-grouped
 path, which 28 features never take.
+
+`time_score_lookup()` (`python tools/kernel_checks.py --score-lookup`;
+two minutes) times the score update with each form of the lookup at
+2,625,536 rows and 255 to 16,383 leaves, and checks the bits there too:
+the reading behind `boosting/leaf_lookup.py ONE_HOT_MAX_LEAVES`.
 """
 import os
 import sys
@@ -141,7 +149,80 @@ def run_checks():
         traceback.print_exc()
         failures.append(f"rows_raised({type(e).__name__})")
 
+    # 5. the score update's one-hot lookup vs the gather, bit for bit
+    try:
+        for L in (2, 31, 255, 1000):
+            if not _lookup_bits_equal(65536, L):
+                failures.append(f"score_lookup_bits_{L}")
+    except Exception as e:    # noqa: BLE001 - named in the verdict
+        traceback.print_exc()
+        failures.append(f"score_lookup_raised({type(e).__name__})")
+
     return "ok" if not failures else "fail:" + ",".join(failures)
+
+
+def _lookup_operands(n, L, seed=31):
+    """A leaf-value table with every awkward float in it (as far as `L`
+    has room) and ids that reach 3 past both ends of it."""
+    import jax.numpy as jnp
+    rng = np.random.RandomState(seed)
+    vals = rng.randn(L).astype(np.float32)
+    awkward = np.array([-0.0, np.nan, np.inf, -np.inf, 1e-42,
+                        np.finfo(np.float32).max], np.float32)
+    vals[:min(L - 1, awkward.size)] = awkward[:L - 1]
+    ids = rng.randint(-3, L + 3, n).astype(np.int32)
+    return jnp.asarray(vals), jnp.asarray(ids)
+
+
+def _lookup_bits_equal(n, L):
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.boosting.leaf_lookup import lookup_onehot, lookup_take
+    vals, ids = _lookup_operands(n, L)
+    as_bits = lambda a: np.asarray(
+        jax.lax.bitcast_convert_type(a, jnp.int32))
+    return bool(np.array_equal(as_bits(jax.jit(lookup_onehot)(vals, ids)),
+                               as_bits(jax.jit(lookup_take)(vals, ids))))
+
+
+def time_score_lookup(n=2_625_536,
+                      leaves=(255, 1023, 2047, 4095, 8191, 16383),
+                      calls=20):
+    """One JSON line per table size: milliseconds a call of the score
+    update (`scores.at[k].add(lookup(vals * rate, ids) * pad_mask)`,
+    scores donated, as `boosting/gbdt.py _score_update_shrink`) with each
+    form of the lookup, the host's clock around `calls` calls, and
+    whether the two forms' rows agree in every bit."""
+    import json
+    import time
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.boosting.leaf_lookup import lookup_onehot, lookup_take
+
+    def update_with(form):
+        def update(scores, class_id, leaf_vals, rate, leaf_id, pad_mask):
+            delta = form(leaf_vals * rate, leaf_id)
+            return scores.at[class_id].add(delta * pad_mask)
+        return jax.jit(update, donate_argnums=(0,))
+
+    pad_mask = jnp.ones(n, jnp.float32)
+    for L in leaves:
+        vals, ids = _lookup_operands(n, L)
+        line = {"rows": n, "leaves": L,
+                "device": jax.devices()[0].device_kind,
+                "bits_equal": _lookup_bits_equal(n, L)}
+        for name, form in (("take", lookup_take), ("onehot", lookup_onehot)):
+            update = update_with(form)
+            scores = jnp.zeros((1, n), jnp.float32)
+            for _ in range(3):
+                scores = update(scores, 0, vals, 0.1, ids, pad_mask)
+            scores.block_until_ready()
+            t0 = time.perf_counter()
+            for _ in range(calls):
+                scores = update(scores, 0, vals, 0.1, ids, pad_mask)
+            scores.block_until_ready()
+            line[f"{name}_ms"] = (time.perf_counter() - t0) / calls * 1e3
+        print(json.dumps(line), flush=True)
 
 
 def _wide_reference(binned_blk, slot, gh, B, slots):
@@ -222,4 +303,8 @@ def run_wide_checks(n=400_384, F=2000, B=63, slot_counts=(1, 8, 128),
 
 
 if __name__ == "__main__":
-    print(run_wide_checks() if "--wide" in sys.argv[1:] else run_checks())
+    if "--score-lookup" in sys.argv[1:]:
+        time_score_lookup()
+    else:
+        print(run_wide_checks() if "--wide" in sys.argv[1:]
+              else run_checks())
