@@ -23,7 +23,7 @@ from ..io.binning import BIN_CATEGORICAL
 from ..io.dataset import Dataset
 from ..learner import (FeatureMeta, GrowParams, grow_tree,
                        grow_tree_donated, grow_tree_wave,
-                       grow_tree_wave_donated)
+                       grow_tree_wave_donated, plan_growth)
 from ..models.tree import Tree
 from ..objective import ObjectiveFunction
 from ..ops.split import SplitParams
@@ -504,14 +504,7 @@ class GBDT:
                             and config.num_grad_quant_bins <= 126
                             and self.n_pad * config.num_grad_quant_bins
                             < 2**31) else 0),
-            use_hist_stack=stack_bytes <= budget,
-            # Fused Pallas one-hot kernel on TPU (one-hot tiles live only in
-            # VMEM, like the CUDA shared-memory histogram kernels); XLA's
-            # scatter path wins on CPU.  Both accumulate fp32; gpu_use_dp
-            # selects the 3-pass high-precision matmul fallback instead
-            # (ref: gpu_tree_learner.h:79 single-precision default).
-            hist_method=(("onehot_hp" if config.gpu_use_dp else "pallas")
-                         if jax.default_backend() == "tpu" else "segment"))
+            use_hist_stack=stack_bytes <= budget)
         if (self.grow_params.monotone_intermediate
                 and not self.grow_params.use_hist_stack):
             log.warning("monotone intermediate mode needs the per-leaf "
@@ -522,10 +515,9 @@ class GBDT:
         if self.mesh is not None and self._mesh_axis == 1:
             # row sharding: masked engine (global-index row gathers would
             # all-gather the binned matrix).  The wave engine keeps its
-            # Pallas histogram and runs under explicit shard_map (the
-            # sharded-wave selection below); only the leaf-wise engine,
-            # which rides GSPMD annotations, downgrades to the XLA
-            # segment histogram (GSPMD cannot partition a pallas_call).
+            # Pallas histogram and runs under explicit shard_map; only the
+            # leaf-wise engine, which rides GSPMD annotations, downgrades
+            # to the XLA segment histogram (learner/select.py).
             from ..parallel import grow_params_for_mesh
             self.grow_params = grow_params_for_mesh(self.grow_params)
             if self._voting:
@@ -582,13 +574,9 @@ class GBDT:
             if not self.grow_params.use_hist_stack:
                 log.fatal("forced splits need the per-leaf histogram stack; "
                           "raise histogram_pool_size")
-        # growth engine: wave (level-batched; one MXU histogram sweep per
-        # round with leaf slots as the matmul's output columns) vs strict
-        # leaf-wise (partitioned segments; the reference-parity order)
-        from ..ops.histogram import wave_pallas_vmem_ok
-        strategy = config.tpu_growth_strategy
-        if strategy not in ("auto", "wave", "leafwise"):
-            log.fatal(f"Unknown tpu_growth_strategy {strategy!r}; "
+        if config.tpu_growth_strategy not in ("auto", "wave", "leafwise"):
+            log.fatal("Unknown tpu_growth_strategy "
+                      f"{config.tpu_growth_strategy!r}; "
                       "expected auto, wave, or leafwise")
         # interaction constraints (ref: config.h:585; col_sampler.hpp:91):
         # "[0,1,2],[2,3]" -> static inner-index sets
@@ -608,37 +596,22 @@ class GBDT:
                     sets.append(idxs)
             self.grow_params = self.grow_params._replace(
                 interaction_sets=tuple(sets))
-        if (self.grow_params.voting is not None
-                or self.grow_params.monotone_intermediate
-                or self.grow_params.split.has_cegb_lazy):
-            # interaction constraints and forced splits run on the wave
-            # engine (branch masks compose with waves; forced splits
-            # apply as a one-split-per-wave prologue, wave.py).  Voting
-            # elects per-leaf feature sets (children not derivable by
-            # subtraction), and intermediate monotone / lazy CEGB
-            # recompute global state after EVERY split — inherently
-            # sequential, so they keep the leaf-wise engine (measured
-            # 0.958 s/iter at bench scale vs the same-host oracle's
-            # 9.8 — see PERF_NOTES).
-            if strategy == "wave":
-                log.warning("voting / intermediate monotone / lazy CEGB "
-                            "use the leaf-wise engine")
-            strategy = "leafwise"
-        if strategy == "auto":
-            strategy = ("wave" if jax.default_backend() == "tpu"
-                        and config.num_leaves >= 8
-                        and self.grow_params.hist_method == "pallas"
-                        and wave_pallas_vmem_ok(len(nb), max_b,
-                                                config.num_leaves)
-                        else "leafwise")
-        elif (strategy == "wave" and jax.default_backend() == "tpu"
-              and not (self.grow_params.hist_method == "pallas"
-                       and wave_pallas_vmem_ok(len(nb), max_b,
-                                               config.num_leaves))):
-            log.warning("tpu_growth_strategy=wave without the fused Pallas "
-                        "histogram falls back to the XLA one-hot wave "
-                        "histogram, which materializes [F, n, B] — only "
-                        "viable for small datasets")
+        # growth engine and histogram method: one function of the
+        # configuration, the backend and the shape (learner/select.py)
+        plan = plan_growth(
+            backend=jax.default_backend(),
+            strategy=config.tpu_growth_strategy,
+            num_leaves=config.num_leaves, num_features=len(nb),
+            max_bin=max_b, gpu_use_dp=config.gpu_use_dp,
+            pinned_leafwise=(self.grow_params.voting is not None
+                             or self.grow_params.monotone_intermediate
+                             or self.grow_params.split.has_cegb_lazy),
+            row_mesh=self.mesh is not None and self._mesh_axis == 1,
+            voting=self.grow_params.voting is not None)
+        for msg in plan.warnings:
+            log.warning(msg)
+        self.grow_params = self.grow_params._replace(
+            hist_method=plan.hist_method)
         # grad/hess buffer donation into the grow program
         # (docs/Performance.md): the per-class slices die at the grow
         # call in every configuration except linear trees, whose leaf
@@ -655,27 +628,17 @@ class GBDT:
                         "disabled under a device mesh (sharded inputs "
                         "cannot alias the grow outputs)")
             donate_grow = False
-        if strategy == "wave" and (self.mesh is not None
-                                   and self._mesh_axis == 1
-                                   and self.grow_params.voting is None):
-            # data-parallel wave: the DEFAULT engine sharded over the row
-            # mesh via shard_map + histogram psum (the reference's
-            # ReduceScatter path, data_parallel_tree_learner.cpp:282)
+        if plan.sharded_wave:
             from ..parallel import make_sharded_wave_fn
             self._grow_fn = make_sharded_wave_fn(self.mesh,
                                                  donate=donate_grow)
-        elif strategy == "wave":
+        elif plan.strategy == "wave":
             self._grow_fn = (grow_tree_wave_donated if donate_grow
                              else grow_tree_wave)
         else:
-            if self.mesh is not None and self._mesh_axis == 1:
-                # leaf-wise under a row mesh rides GSPMD annotations,
-                # which cannot partition a pallas_call
-                self.grow_params = self.grow_params._replace(
-                    hist_method="segment")
             self._grow_fn = (grow_tree_donated if donate_grow
                              else grow_tree)
-        self.growth_strategy = strategy
+        self.growth_strategy = plan.strategy
         # recompile watchdog (docs/Observability.md): a mid-training
         # shape change on a jitted hot-path entry re-traces the whole
         # program — a multi-second stall with no other symptom.  The

@@ -47,9 +47,9 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..ops.histogram import (build_histogram_wave, build_histogram_wave_hl,
-                             hl_split_of, snap_to_operand_grid,
-                             wave_hl_profitable, wave_slot_pad)
+from ..ops.histogram import (plan_wave_kernel, snap_to_operand_grid,
+                             spike_true_slots, wave_histograms,
+                             wave_slot_pad)
 from ..ops.split import (K_MIN_SCORE, SplitResult, cat_bitset_words,
                          find_best_split)
 from .grow import (FeatureMeta, GrowParams, TreeArrays,
@@ -137,32 +137,22 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
             # tpulint: disable-next=collective-discipline -- the wave engine's single histogram/count reduction point; parallel/data_parallel.py wraps this engine in shard_map and owns the data_axis contract
             return jax.lax.psum(x, params.data_axis)
 
-    def _hl_fits(true_slots):
-        """VMEM gate for the decomposed kernel (no feature grouping)."""
-        F_, Rt, C_ = binned.shape[0], 512, 2
-        Bh, Bl = hl_split_of(hist_B, true_slots, C_)
-        Wd = F_ * Bl * C_ * true_slots
-        vmem = (F_ * Bh * Rt * 2 + Rt * Wd * 10 + F_ * Bh * Bl
-                * C_ * true_slots * 4)
-        return vmem <= (12 << 20)
-
-    def _hl_serves(true_slots):
-        """The decomposed kernel takes a wave of `true_slots` computed
-        slots: static, from shapes alone."""
-        return (use_pallas and not use_int8
-                and wave_hl_profitable(hist_B, true_slots)
-                and _hl_fits(true_slots))
-
     binned_rm = None
-    # both gates only close as the slots grow: where one slot is refused
-    # (2,000 features: 40 MB of VMEM) no wave of this tree can use the
-    # row-major copy, and it is not built (0.8 GB there)
-    if _hl_serves(1):
+    # both of the plan's `wave_hl` gates only close as the slots grow:
+    # where one slot is refused (2,000 features: its ungrouped blocks
+    # count 116 MB of VMEM) no wave of this tree can use the row-major
+    # copy, and it is not built (0.8 GB there)
+    if use_pallas and plan_wave_kernel(binned.shape[0], hist_B, 1, 1,
+                                       int8=use_int8).kernel == "wave_hl":
         # row-major copy for the decomposed small-S kernel's lo side
         # (transposed once per tree; bins are static so XLA keeps it
         # resident for all waves of the tree)
         with global_timer.device_scope("Tree::hist_operands"):
             binned_rm = binned.T
+    # quantized grid grads -> exact int32 accumulation through the MXU
+    # int8 path (ref: dense_bin.hpp:174 ConstructHistogramIntInner)
+    quant = (dict(quant_bins=params.quant_bins, quant_scales=quant_scales)
+             if use_int8 else {})
 
     def hists_of(kslot, ghm, num_slots, true_slots=None):
         """Group-space histograms for the COMPUTED (compact) slots only;
@@ -170,29 +160,12 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
         per-leaf set is completed by sibling subtraction at the cache.
         `true_slots` (<= num_slots) is the unpadded computed-slot bound:
         when it is small the decomposed hi/lo kernel streams far less
-        VMEM volume (ops/histogram.py _wave_kernel_hl)."""
+        VMEM volume (ops/histogram.py plan_wave_kernel picks)."""
         with global_timer.device_scope("Tree::histogram"):
             if use_pallas:
-                if use_int8:
-                    # quantized grid grads -> exact int32 accumulation
-                    # through the MXU int8 path (ref: dense_bin.hpp:174
-                    # ConstructHistogramIntInner)
-                    H, cnt = build_histogram_wave(
-                        binned, kslot, ghm, max_bin=hist_B,
-                        num_slots=num_slots, quant_bins=params.quant_bins,
-                        quant_scales=quant_scales)
-                elif (true_slots is not None and binned_rm is not None
-                        and _hl_serves(true_slots)):
-                    H, cnt = build_histogram_wave_hl(
-                        binned, binned_rm, kslot, ghm, max_bin=hist_B,
-                        num_slots=true_slots, out_slots=num_slots)
-                else:
-                    # Rt stays 512: 1024 is ~3% faster on small slot
-                    # counts but exceeds the 16 MB scoped-VMEM limit at
-                    # 128 slots
-                    H, cnt = build_histogram_wave(binned, kslot, ghm,
-                                                  max_bin=hist_B,
-                                                  num_slots=num_slots)
+                H, cnt = wave_histograms(
+                    binned, binned_rm, kslot, ghm, max_bin=hist_B,
+                    num_slots=num_slots, true_slots=true_slots, **quant)
             else:
                 H, cnt = _hist_wave_xla(binned, kslot, ghm, max_bin=hist_B,
                                         num_slots=num_slots)
@@ -888,7 +861,7 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
             state[0].num_leaves < Lg,
             functools.partial(wave_body, NLp=wave_slot_pad(Lg),
                               Kb=wave_slot_pad(KsS),
-                              Ks=(KsS if KsS <= 16 else None),
+                              Ks=spike_true_slots(KsS),
                               budget_cap=spike_k),
             lambda s: s, state)
 

@@ -2,9 +2,9 @@
 classification (docs/Observability.md).
 
 The MFU number earlier rounds tracked (`useful_mac_mfu`) was a
-single hand-derived analytic estimate in tools/bench_10m.py — a MAC
-guess divided by wall clock divided by a hardcoded peak.  It says the
-chip is idle but not WHERE, so the Pallas-histogram work has nothing to
+single hand-derived analytic estimate in a measuring script (deleted,
+PR 32) — a MAC guess divided by wall clock divided by a hardcoded
+peak.  It says the chip is idle but not WHERE, so the Pallas-histogram work has nothing to
 aim at.  This module asks the compiler instead: every hot jitted entry
 point is already wrapped in a `RecompileDetector` (grow/grow-wave,
 donated or not; the gradient program; DeviceEval's packed tick; every

@@ -30,6 +30,7 @@ data counts are derived downstream from hessian sums exactly as the reference do
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -225,8 +226,9 @@ def _wave_kernel(C: int, Fg: int, Bg: int, NLg: int):
         mxu_t = jnp.int8 if int8_mode else jnp.bfloat16
         acc_t = jnp.int32 if int8_mode else jnp.float32
         # offset the SMALL [Fg, Rt] rows instead of the big [Fg, Bg, Rt]
-        # iota: the one-hot construction is the per-wave VPU floor, so
-        # every elementwise pass over the big shape counts
+        # iota: the one-hot construction was the per-wave cost floor
+        # *(old chip)*; not re-measured, see ROADMAP S1 — every
+        # elementwise pass over the big shape counts
         rows = rows_ref[...].astype(jnp.int32) - bg * Bg  # [Fg, Rt]
         slot = slot_ref[...]                             # [1, Rt]
         Rt = rows.shape[1]
@@ -235,8 +237,9 @@ def _wave_kernel(C: int, Fg: int, Bg: int, NLg: int):
         oh2 = oh.reshape(Fg * Bg, Rt)
         lanes = (((1,), (1,)), ((), ()))     # contract both over rows
         S = out_ref.shape[-1] // (C * NLg)
-        for s in range(S):  # slot groups REUSE the bin one-hot (its VPU
-            # construction, not the MXU dot, is the per-wave cost floor)
+        for s in range(S):  # slot groups REUSE the bin one-hot (its
+            # construction, not the MXU dot, was the per-wave cost floor
+            # *(old chip)*; not re-measured, see ROADMAP S1)
             # rows stay on lanes: the slot one-hot [NLg, Rt] and the
             # slot-separated channel matrix [C*NLg, Rt] (c-major) are
             # built from sublane broadcasts of the [1, Rt] operand rows —
@@ -273,8 +276,9 @@ def _wave_kernel(C: int, Fg: int, Bg: int, NLg: int):
 def _wave_kernel_hl(C: int, Fg: int, Bh: int, Bl: int, S: int, P: int):
     """Decomposed (hi/lo outer-product) wave kernel for FEW computed slots.
 
-    The flat-floor cost of `_wave_kernel` is the F*B*Rt bin one-hot built
-    in VMEM every wave.  For waves whose computed-slot count S is small,
+    The flat cost of `_wave_kernel` is the F*B*Rt bin one-hot built in
+    VMEM every wave (its floor *(old chip)*; not re-measured, see ROADMAP
+    S1).  For waves whose computed-slot count S is small,
     the one-hot factors over a hi/lo split of the bin code
 
         onehot_B(bin) = onehot_Bh(bin >> log2(Bl)) (x) onehot_Bl(bin & Bl-1)
@@ -383,69 +387,6 @@ def hl_split_of(max_bin: int, num_slots: int, C: int):
     return best[1], best[2]
 
 
-def wave_hl_profitable(max_bin: int, num_slots: int, C: int = 2) -> bool:
-    """True when the decomposed kernel's materialized volume is
-    meaningfully below the full kernel's F*B (measured crossover ~0.6)."""
-    Bh, Bl = hl_split_of(max_bin, num_slots, C)
-    # Bh > 256 would overflow the feature-packed M dimension (and such
-    # giant max_bin configs gain nothing from decomposition anyway)
-    return Bh <= 256 and (Bh + Bl * C * num_slots) <= 0.6 * max_bin
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("max_bin", "num_slots", "out_slots",
-                                    "row_tile"))
-def build_histogram_wave_hl(binned_fm: jnp.ndarray, binned_rm: jnp.ndarray,
-                            slot: jnp.ndarray, gh: jnp.ndarray, *,
-                            max_bin: int, num_slots: int, out_slots: int,
-                            row_tile: int = 512):
-    """Decomposed-kernel variant of `build_histogram_wave` for waves with
-    few computed slots (see `_wave_kernel_hl`).  Same operands —
-    binned_fm [F, n], slot [n] int32, gh [C+1, n] with the count mask as
-    its last row — plus binned_rm [n, F], the row-major copy of the bins
-    for the kernel's lo side.  `num_slots` is the TRUE computed-slot
-    bound; the output is zero-padded to `out_slots` rows so callers keep
-    the padded-Kb contract.  Returns
-    (hist [out_slots, F, B, C] float32, counts [out_slots] float32)."""
-    F, n = binned_fm.shape
-    C = gh.shape[0] - 1
-    S = num_slots
-    Bh, Bl = hl_split_of(max_bin, S, C)
-    P = next((p for p in (4, 2, 1) if F % p == 0 and p * Bh <= 256), 1)
-    if n % row_tile != 0:
-        raise ValueError(f"n {n} not a multiple of row_tile {row_tile}")
-    with global_timer.device_scope("Tree::hist_operands"):
-        slot_row = slot.reshape(1, n)
-    _count_traced_call(1)       # no feature grouping: blocks are (F, Rt)
-    out, cnt = pl.pallas_call(
-        _wave_kernel_hl(C, F, Bh, Bl, S, P),
-        grid=(n // row_tile,),
-        in_specs=[
-            pl.BlockSpec((F, row_tile), lambda i: (0, i)),
-            pl.BlockSpec((row_tile, F), lambda i: (i, 0)),
-            pl.BlockSpec((1, row_tile), lambda i: (0, i)),
-            pl.BlockSpec((C + 1, row_tile), lambda i: (0, i))],
-        out_specs=[
-            pl.BlockSpec((F, Bh, Bl * C * S), lambda i: (0, 0, 0)),
-            pl.BlockSpec((8, S), lambda i: (0, 0))],
-        out_shape=[
-            jax.ShapeDtypeStruct((F, Bh, Bl * C * S), jnp.float32),
-            jax.ShapeDtypeStruct((8, S), jnp.float32)],
-        name="build_histogram_wave_hl",
-    )(binned_fm, binned_rm, slot_row, gh)
-    # [F, Bh, (bl, c, s)] -> [S, F, B, C], zero-padded to out_slots
-    h = out.reshape(F, Bh, Bl, C, S).transpose(4, 0, 1, 2, 3)
-    h = h.reshape(S, F, Bh * Bl, C)[:, :, :max_bin, :]
-    pad = out_slots - S
-    if pad > 0:
-        h = jnp.concatenate(
-            [h, jnp.zeros((pad,) + h.shape[1:], h.dtype)], axis=0)
-        cntv = jnp.concatenate([cnt[0], jnp.zeros(pad, cnt.dtype)])
-    else:
-        cntv = cnt[0]
-    return h, cntv
-
-
 # VMEM gates of `build_histogram_wave`.  A call's footprint is counted in
 # `_wave_unit_bytes` a feature of its block: the f32 accumulator
 # [Bg, S*C*NLg] plus the bf16 bin one-hot [Bg, Rt].  The gates were set on
@@ -499,13 +440,137 @@ def wave_slot_pad(num_slots: int) -> int:
     return (num_slots + 127) // 128 * 128
 
 
-def wave_pallas_vmem_ok(num_features: int, max_bin: int,
-                        num_slots: int) -> bool:
-    """True when the wave kernel fits the chip's scoped VMEM at its
-    smallest legal feature group (8): accumulator and one-hot of all
-    `num_slots` slot groups, as the compiler counts them (readings
-    above)."""
-    return 8 * _wave_unit_bytes(max_bin, num_slots) <= _SCOPED_VMEM
+# The decomposed kernel has no feature grouping (its blocks are (F, Rt)):
+# the hi one-hot [F, Bh, Rt] bf16, the [Rt, Wd] expander products (d and
+# wt in f32, sc in bf16) and the f32 accumulator must fit together
+_HL_VMEM = 12 << 20
+# measured crossover of the decomposed kernel's materialized volume
+# against the full kernel's F*B *(old chip)*; re-tuning it is ROADMAP S1 (2)
+_HL_CROSSOVER = 0.6
+# The spike waves (learner/wave.py) name their true slot count to the plan
+# only up to this many and take the full kernel past it, whatever the
+# crossover says: `_wave_kernel_hl`'s advantage was measured to vanish by
+# 16 slots.  The ladder's waves have no such cap (they differ from this
+# where 0.6 * max_bin admits more than 16 slots, max_bin > ~430):
+# ROADMAP S1 (2) settles both together.
+_HL_SPIKE_MAX_SLOTS = 16
+
+
+def spike_true_slots(true_slots: int) -> Optional[int]:
+    """The `true_slots` a spike wave hands `plan_wave_kernel`."""
+    return true_slots if true_slots <= _HL_SPIKE_MAX_SLOTS else None
+
+
+class WaveKernelPlan(NamedTuple):
+    """Which wave kernel a call takes and in what blocks: shapes in,
+    nothing of the data."""
+    kernel: str                 # "wave" | "wave_hl"
+    feature_pad: int            # full kernel: F as its grid sees it (Fp)
+    feature_group: int          # full kernel: features a block (Fg)
+    groups: int                 # full kernel: Fp // Fg passes over the rows
+    hl_split: Optional[Tuple[int, int]]   # decomposed kernel: (Bh, Bl)
+    vmem_bytes: int             # of `kernel`, as its gate counted them
+    fits: bool                  # the full kernel's smallest group compiles
+
+
+def plan_wave_kernel(num_features: int, max_bin: int, num_slots: int,
+                     true_slots: Optional[int] = None, *,
+                     int8: bool = False, C: int = 2,
+                     row_tile: int = 512) -> WaveKernelPlan:
+    """The one owner of "which histogram kernel does a wave run".
+
+    `num_slots` is the padded computed-slot bound (the output's), and
+    `true_slots` the unpadded one where the caller knows it: only then,
+    and never for int8 operands, can the decomposed kernel take the wave
+    — when its materialized volume is meaningfully below the full
+    kernel's F*B and its ungrouped blocks fit VMEM.  Otherwise the full
+    kernel runs, as one full-F block where that fits (no padding of F to
+    the 8-sublane granule: 12.5% of one-hot volume and MXU rows at 28
+    features, and fewer grid cells) and else in feature groups.  `fits`
+    is false where even the smallest legal group (8 features, all
+    `num_slots` slot groups) is past the compiler's scoped VMEM: such a
+    booster takes the leaf-wise engine (learner/select.py)."""
+    unit = _wave_unit_bytes(max_bin, num_slots, C, row_tile)
+    if num_features * unit <= _FULL_F_VMEM:
+        Fp = Fg = num_features
+    else:
+        # TPU block constraint: the binned block's second-to-last dim
+        # (Fg) must be a multiple of 8 OR the whole (unpadded) F
+        Fp = _round_up(num_features, 8)
+        # feature group bounded by the VMEM accumulator [Fg, Bg, S*C*NLg]
+        # plus the [Fg, Bg, Rt] bf16 one-hot
+        Fg = _pick_feature_group(Fp, unit, _GROUP_VMEM)
+    kernel, split, vmem = "wave", None, Fg * unit
+    if true_slots is not None:
+        Bh, Bl = split = hl_split_of(max_bin, true_slots, C)
+        CS = C * true_slots
+        Wd = num_features * Bl * CS
+        hl_vmem = (num_features * Bh * row_tile * 2 + row_tile * Wd * 10
+                   + num_features * Bh * Bl * CS * 4)
+        # Bh > 256 would overflow the feature-packed M dimension (and
+        # such giant max_bin configs gain nothing from decomposition
+        # anyway)
+        if (not int8 and Bh <= 256
+                and Bh + Bl * CS <= _HL_CROSSOVER * max_bin
+                and hl_vmem <= _HL_VMEM):
+            kernel, vmem = "wave_hl", hl_vmem
+    return WaveKernelPlan(kernel, Fp, Fg, Fp // Fg, split, vmem,
+                          8 * unit <= _SCOPED_VMEM)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("max_bin", "num_slots", "out_slots",
+                                    "row_tile"))
+def build_histogram_wave_hl(binned_fm: jnp.ndarray, binned_rm: jnp.ndarray,
+                            slot: jnp.ndarray, gh: jnp.ndarray, *,
+                            max_bin: int, num_slots: int, out_slots: int,
+                            row_tile: int = 512):
+    """Decomposed-kernel variant of `build_histogram_wave` for waves with
+    few computed slots (see `_wave_kernel_hl`).  Same operands —
+    binned_fm [F, n], slot [n] int32, gh [C+1, n] with the count mask as
+    its last row — plus binned_rm [n, F], the row-major copy of the bins
+    for the kernel's lo side.  `num_slots` is the TRUE computed-slot
+    bound; the output is zero-padded to `out_slots` rows so callers keep
+    the padded-Kb contract.  Returns
+    (hist [out_slots, F, B, C] float32, counts [out_slots] float32)."""
+    F, n = binned_fm.shape
+    C = gh.shape[0] - 1
+    S = num_slots
+    Bh, Bl = plan_wave_kernel(F, max_bin, out_slots, S, C=C,
+                              row_tile=row_tile).hl_split
+    P = next((p for p in (4, 2, 1) if F % p == 0 and p * Bh <= 256), 1)
+    if n % row_tile != 0:
+        raise ValueError(f"n {n} not a multiple of row_tile {row_tile}")
+    with global_timer.device_scope("Tree::hist_operands"):
+        slot_row = slot.reshape(1, n)
+    _count_traced_call(1)       # no feature grouping: blocks are (F, Rt)
+    out, cnt = pl.pallas_call(
+        _wave_kernel_hl(C, F, Bh, Bl, S, P),
+        grid=(n // row_tile,),
+        in_specs=[
+            pl.BlockSpec((F, row_tile), lambda i: (0, i)),
+            pl.BlockSpec((row_tile, F), lambda i: (i, 0)),
+            pl.BlockSpec((1, row_tile), lambda i: (0, i)),
+            pl.BlockSpec((C + 1, row_tile), lambda i: (0, i))],
+        out_specs=[
+            pl.BlockSpec((F, Bh, Bl * C * S), lambda i: (0, 0, 0)),
+            pl.BlockSpec((8, S), lambda i: (0, 0))],
+        out_shape=[
+            jax.ShapeDtypeStruct((F, Bh, Bl * C * S), jnp.float32),
+            jax.ShapeDtypeStruct((8, S), jnp.float32)],
+        name="build_histogram_wave_hl",
+    )(binned_fm, binned_rm, slot_row, gh)
+    # [F, Bh, (bl, c, s)] -> [S, F, B, C], zero-padded to out_slots
+    h = out.reshape(F, Bh, Bl, C, S).transpose(4, 0, 1, 2, 3)
+    h = h.reshape(S, F, Bh * Bl, C)[:, :, :max_bin, :]
+    pad = out_slots - S
+    if pad > 0:
+        h = jnp.concatenate(
+            [h, jnp.zeros((pad,) + h.shape[1:], h.dtype)], axis=0)
+        cntv = jnp.concatenate([cnt[0], jnp.zeros(pad, cnt.dtype)])
+    else:
+        cntv = cnt[0]
+    return h, cntv
 
 
 @functools.partial(jax.jit,
@@ -522,9 +587,10 @@ def build_histogram_wave(binned_fm: jnp.ndarray, slot: jnp.ndarray,
     slot group whose output columns are (channel, slot) pairs.  The leaf-
     slot axis fills the MXU's 128-wide output dimension — a plain per-leaf
     histogram dot has C=2 output columns and idles most of the systolic
-    array.  The one-hot's VPU construction is the cost floor, so its
-    volume (F*B*n per wave) is built exactly once regardless of slot
-    count.  Exact per-slot row counts ride along as a second output — the
+    array.  The one-hot's construction was the cost floor *(old chip)*;
+    not re-measured, see ROADMAP S1 — so its volume (F*B*n per wave) is
+    built exactly once regardless of slot count.
+    Exact per-slot row counts ride along as a second output — the
     mask column against the slot one-hot.  (TPU replacement for the CUDA
     per-leaf shared-memory kernels, cuda_histogram_constructor.cu:18.)
 
@@ -569,22 +635,11 @@ def build_histogram_wave(binned_fm: jnp.ndarray, slot: jnp.ndarray,
     if n % row_tile != 0:
         raise ValueError(f"n {n} not a multiple of row_tile {row_tile}")
     S = NLp // NLg
-    # TPU block constraint: the binned block's second-to-last dim (Fg) must
-    # be a multiple of 8 OR the whole (unpadded) F.  Prefer the single
-    # full-F group when its VMEM footprint fits — it avoids padding F up
-    # to a multiple of 8 (12.5% wasted one-hot volume and MXU rows at the
-    # bench's 28 features) and cuts grid-cell overheads.
-    unit = _wave_unit_bytes(max_bin, num_slots, C, row_tile)
-    if F * unit <= _FULL_F_VMEM:
-        Fp = Fg = F
-    else:
-        Fp = (F + 7) // 8 * 8
-        if Fp != F:
-            with global_timer.device_scope("Tree::hist_operands"):
-                binned_fm = jnp.pad(binned_fm, ((0, Fp - F), (0, 0)))
-        # feature group bounded by the VMEM accumulator [Fg, Bg, S*C*NLg]
-        # plus the [Fg, Bg, Rt] bf16 one-hot
-        Fg = _pick_feature_group(Fp, unit, _GROUP_VMEM)
+    plan = plan_wave_kernel(F, max_bin, num_slots, C=C, row_tile=row_tile)
+    Fp, Fg = plan.feature_pad, plan.feature_group
+    if Fp != F:
+        with global_timer.device_scope("Tree::hist_operands"):
+            binned_fm = jnp.pad(binned_fm, ((0, Fp - F), (0, 0)))
     acc_t = jnp.int32 if use_int8 else jnp.float32
     with global_timer.device_scope("Tree::hist_operands"):
         slot_row = slot.reshape(1, n)
@@ -613,6 +668,32 @@ def build_histogram_wave(binned_fm: jnp.ndarray, slot: jnp.ndarray,
         hist = hist.astype(jnp.float32) * quant_scales[None, None, None, :]
         return hist, cnt[0, :num_slots].astype(jnp.float32)
     return hist, cnt[0, :num_slots]
+
+
+def wave_histograms(binned_fm: jnp.ndarray, binned_rm: jnp.ndarray,
+                    slot: jnp.ndarray, gh: jnp.ndarray, *, max_bin: int,
+                    num_slots: int, true_slots: Optional[int] = None,
+                    quant_bins: int = 0, quant_scales: jnp.ndarray = None):
+    """Run the kernel `plan_wave_kernel` names for this wave (plain Python:
+    the kernels are the jitted entries and carry the scopes).  Operands as
+    `build_histogram_wave`'s, plus `binned_rm` [n, F] — built by the
+    caller where the plan gives `wave_hl` at one slot, which every
+    `wave_hl` wave of the same shape implies (both gates only close as
+    the slots grow) — and the wave's `true_slots` where it knows them.
+    `quant_scales` selects the full kernel's int8 operands.
+    Returns (hist [num_slots, F, B, C] float32, counts [num_slots])."""
+    plan = plan_wave_kernel(binned_fm.shape[0], max_bin, num_slots,
+                            true_slots, int8=quant_scales is not None,
+                            C=gh.shape[0] - 1)
+    if plan.kernel == "wave_hl":
+        return build_histogram_wave_hl(
+            binned_fm, binned_rm, slot, gh, max_bin=max_bin,
+            num_slots=true_slots, out_slots=num_slots)
+    # Rt stays 512: 1024 is ~3% faster on small slot counts but exceeds
+    # the 16 MB scoped-VMEM limit at 128 slots
+    return build_histogram_wave(
+        binned_fm, slot, gh, max_bin=max_bin, num_slots=num_slots,
+        quant_bins=quant_bins, quant_scales=quant_scales)
 
 
 @functools.partial(jax.jit, static_argnames=("max_bin", "method", "row_chunk"))

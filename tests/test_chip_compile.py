@@ -94,10 +94,10 @@ def test_wave_kernel_compiles(one_chip, num_slots):
 @pytest.mark.parametrize("num_slots", [1, 8, 128])
 def test_wave_kernel_compiles_in_feature_groups(one_chip, num_slots):
     """2,000 features: `F * unit` is 139-262 MB against the 16 MB gate,
-    so the kernel runs in groups of `_pick_feature_group(.., 6 MB)`
-    features (80 / 80 / 40 here).  Mosaic takes each (the gates date
-    from an older runtime and no shape wider than 28 had tried them);
-    the chip runs them in `tools/kernel_checks.py --wide`."""
+    so the kernel runs in the groups `plan_wave_kernel` gives (80 / 80
+    / 40 features here, under 6 MB each).  Mosaic takes each (the gates
+    date from an older runtime and no shape wider than 28 had tried
+    them); the chip runs them in `tools/kernel_checks.py --wide`."""
     from lightgbm_tpu.ops.histogram import build_histogram_wave
     compiled = build_histogram_wave.lower(
         *_kernel_args(one_chip, WIDE_F, WIDE_N), max_bin=WIDE_B,
@@ -107,13 +107,13 @@ def test_wave_kernel_compiles_in_feature_groups(one_chip, num_slots):
 
 @pytest.mark.parametrize("num_slots,fits", [(895, True), (1023, False)])
 def test_smallest_group_gate_is_the_compilers(one_chip, num_slots, fits):
-    """`wave_pallas_vmem_ok` (the booster takes the leaf-wise engine
-    where it is false) against the compiler at 255 bins: 8 features of
-    895 slots count 16.0 MB and compile, of 1,023 slots 18.0 MB and are
-    refused for scoped VMEM."""
+    """`plan_wave_kernel(...).fits` (the booster takes the leaf-wise
+    engine where it is false) against the compiler at 255 bins: 8
+    features of 895 slots count 16.0 MB and compile, of 1,023 slots 18.0
+    MB and are refused for scoped VMEM."""
     from lightgbm_tpu.ops.histogram import (build_histogram_wave,
-                                            wave_pallas_vmem_ok)
-    assert wave_pallas_vmem_ok(F, B, num_slots) is fits
+                                            plan_wave_kernel)
+    assert plan_wave_kernel(F, B, num_slots).fits is fits
     lowered = build_histogram_wave.lower(
         *_kernel_args(one_chip, F, 1 << 16), max_bin=B,
         num_slots=num_slots)

@@ -64,15 +64,53 @@ def test_fast_cache_invalidated_by_growth():
     assert not np.allclose(p1, p2)                   # new tree changed it
 
 
-def test_fast_direct_api_latency_is_micro_scale():
+def test_fast_direct_api_latency_is_micro_scale(monkeypatch):
+    """What makes the direct path microseconds a row, as counts (a wall
+    clock on a shared CPU under six xdist workers says nothing): per call
+    one native call through the handle and argument tuple bound at
+    construction, no repack of the model, no new library handle and no
+    new buffer — and the answers are `Booster.predict`'s."""
+    import ctypes
+    from lightgbm_tpu import native
     X, b = _fit("binary", lambda X: (X[:, 0] + X[:, 1] > 1).astype(float))
     sp = b._gbdt.make_single_row_fast(X.shape[1])
     assert sp is not None and sp.ok
-    import time
     rows = [np.ascontiguousarray(X[i % 2000]) for i in range(3000)]
-    sp.predict(rows[0])
-    t0 = time.time()
-    for r in rows:
-        sp.predict(r)
-    per_row = (time.time() - t0) / len(rows)
-    assert per_row < 500e-6, f"{per_row * 1e6:.0f} us/row"
+    first = sp.predict(rows[0])
+
+    counts = {"native": 0, "pack": 0, "bind": 0}
+    fn, cargs, pack = sp._fn, sp._cargs, sp._packed
+
+    def addresses():
+        return [a.ctypes.data for a in (
+            sp._X, sp._out, pack.sf, pack.th, pack.dt, pack.lc, pack.rc,
+            pack.lv, pack.cw, pack.cb, pack.node_off, pack.leaf_off,
+            pack.cw_off, pack.cb_off)]
+    buffers = addresses()
+
+    def counted_fn(*args):
+        counts["native"] += 1
+        assert all(x is y for x, y in zip(args, cargs, strict=True))
+        return fn(*args)
+
+    def counted(name, real):
+        def wrapper(*a, **kw):
+            counts[name] += 1
+            return real(*a, **kw)
+        return wrapper
+
+    sp._fn = counted_fn
+    monkeypatch.setattr(native.PackedPredictor, "__init__", counted(
+        "pack", native.PackedPredictor.__init__))
+    monkeypatch.setattr(ctypes, "CDLL", counted("bind", ctypes.CDLL))
+    got = np.stack([sp.predict(r) for r in rows])
+
+    assert counts == {"native": len(rows), "pack": 0, "bind": 0}
+    assert sp._cargs is cargs and sp._packed is pack
+    assert b._gbdt._packed_pred[1] is pack
+    assert addresses() == buffers
+    np.testing.assert_allclose(got[0], first, rtol=0, atol=0)
+    want = b.predict(X)
+    np.testing.assert_allclose(got[:2000, 0], want, rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(got[2000:, 0], want[:1000], rtol=1e-5,
+                               atol=1e-7)

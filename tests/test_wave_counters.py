@@ -15,20 +15,21 @@ from jax.experimental import pallas as pl
 import lightgbm_tpu as lgb
 from lightgbm_tpu.learner import FeatureMeta, GrowParams, wave
 from lightgbm_tpu.observability import global_registry
+from lightgbm_tpu.ops import histogram
 from lightgbm_tpu.ops.split import MISSING_NONE, SplitParams
 
 
-def _counting(monkeypatch, names):
-    """Replace `wave.<name>` by a twin that reports each EXECUTION (a
+def _counting(monkeypatch, names, module=wave):
+    """Replace `module.<name>` by a twin that reports each EXECUTION (a
     wave skipped by its lax.cond reports nothing)."""
     calls = []
     for name in names:
-        real = getattr(wave, name)
+        real = getattr(module, name)
 
         def twin(*args, _real=real, _name=name, **kw):
             jax.debug.callback(lambda _n=_name: calls.append(_n))
             return _real(*args, **kw)
-        monkeypatch.setattr(wave, name, twin)
+        monkeypatch.setattr(module, name, twin)
     return calls
 
 
@@ -59,8 +60,9 @@ def test_a_tiny_train_counts_its_waves(monkeypatch):
 def test_waves_equal_the_kernel_calls_in_interpret_mode(monkeypatch):
     monkeypatch.setattr(pl, "pallas_call",
                         functools.partial(pl.pallas_call, interpret=True))
+    # the kernels `ops/histogram.py wave_histograms` dispatches to
     calls = _counting(monkeypatch, ["build_histogram_wave",
-                                    "build_histogram_wave_hl"])
+                                    "build_histogram_wave_hl"], histogram)
     n, F, B, L = 4096, 6, 32, 21                   # shapes of no other test
     rng = np.random.RandomState(5)
     Xu = rng.rand(n, F)

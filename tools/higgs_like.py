@@ -1,7 +1,7 @@
 """Seeded Higgs-shaped binary-classification data and the AUC it is
-judged by — shared by bench.py, chip_smoke.py and the tools/bench_*
-scripts, so every one of them trains on the same generator (the real
-Higgs file cannot be downloaded where these run)."""
+judged by — shared by bench.py and chip_smoke.py, so both train on the
+same generator (the real Higgs file cannot be downloaded where these
+run)."""
 
 import numpy as np
 

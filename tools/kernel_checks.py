@@ -256,6 +256,7 @@ def run_wide_checks(n=400_384, F=2000, B=63, slot_counts=(1, 8, 128),
     import jax
     import jax.numpy as jnp
     from lightgbm_tpu.ops.histogram import (build_histogram_wave,
+                                            plan_wave_kernel,
                                             snap_to_operand_grid,
                                             wave_slot_pad)
     kb, ks, kw, kg, kh, km = jax.random.split(jax.random.PRNGKey(30), 6)
@@ -275,6 +276,9 @@ def run_wide_checks(n=400_384, F=2000, B=63, slot_counts=(1, 8, 128),
                          jax.random.randint(ks, (n,), 0, slots),
                          wave_slot_pad(255)).astype(jnp.int32)
         try:
+            plan = plan_wave_kernel(F, B, slots)
+            if not plan.fits:
+                failures.append(f"wide_plan_does_not_fit_{slots}")
             hist, cnt = build_histogram_wave(binned, slot, gh, max_bin=B,
                                              num_slots=slots)
             err = jnp.zeros(2)
@@ -290,8 +294,10 @@ def run_wide_checks(n=400_384, F=2000, B=63, slot_counts=(1, 8, 128),
             want_cnt = np.bincount(s_np[inb], weights=m_np[inb],
                                    minlength=slots)
             counts_exact = bool(np.array_equal(np.asarray(cnt), want_cnt))
-            print(f"wide {n}x{F} B={B} slots={slots}: rel_err={rel.tolist()}"
-                  f" counts_exact={counts_exact}", file=sys.stderr)
+            print(f"wide {n}x{F} B={B} slots={slots}: "
+                  f"groups={plan.groups}x{plan.feature_group} "
+                  f"rel_err={rel.tolist()} counts_exact={counts_exact}",
+                  file=sys.stderr)
             if not (rel <= rel_tol).all():
                 failures.append(f"wide_sums_{slots}")
             if not counts_exact:
