@@ -468,6 +468,7 @@ class GBDT:
                 max_delta_step=config.max_delta_step,
                 path_smooth=config.path_smooth,
                 has_categorical=bool(self.f_is_cat.any()),
+                has_missing=bool((self.f_missing_type != 0).any()),
                 cat_features=tuple(np.nonzero(self.f_is_cat)[0].tolist()),
                 max_cat_to_onehot=config.max_cat_to_onehot,
                 max_cat_threshold=config.max_cat_threshold,

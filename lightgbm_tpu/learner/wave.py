@@ -51,7 +51,7 @@ from ..ops.histogram import (plan_wave_kernel, snap_to_operand_grid,
                              spike_true_slots, wave_histograms,
                              wave_slot_pad)
 from ..ops.split import (K_MIN_SCORE, SplitResult, cat_bitset_words,
-                         find_best_split)
+                         find_best_split, find_best_split_dense)
 from .grow import (FeatureMeta, GrowParams, TreeArrays,
                    bundle_hist_to_features, gather_forced_split)
 from ..utils.timer import global_timer
@@ -101,6 +101,14 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
     use_pallas = params.hist_method == "pallas"
     use_int8 = (use_pallas and params.quant_bins > 0
                 and quant_scales is not None)
+    # Which form the gain scan takes, by what the configuration shows:
+    # plain numerical features are scanned for all leaves of a wave at
+    # once, straight from the cache's rows — `find_best_split_dense`.
+    # EFB's per-feature gather, the categorical scan's sub-array and the
+    # monotone constraint surfaces take a leaf's [F, B, 2], so with any
+    # of them each leaf goes through `find_best_split` under a vmap.
+    dense_scan = not (params.has_bundles or sp.has_categorical
+                      or sp.has_monotone)
 
     with global_timer.device_scope("Tree::hist_operands"):
         row_mask = row_mask.astype(f32)
@@ -270,6 +278,21 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
                                 0 if (use_bynode or use_interaction)
                                 else None))
 
+    def best_of(rows, sg, sh, c, po, cmin, cmax, dep, rb, rcu, used, bym):
+        """Best split of each leaf whose cache row is in `rows` [N, Dh]."""
+        if not dense_scan:
+            return best_vm(rows.reshape(-1, Fh, hist_B, 2), sg, sh, c, po,
+                           cmin, cmax, dep, rb, rcu, used, bym)
+        kw = {}
+        if sp.extra_trees:
+            kw["rand_bin"] = rb
+        if sp.has_cegb:
+            kw.update(cegb_coupled=meta.cegb_coupled, cegb_used=used)
+        return find_best_split_dense(
+            rows, meta.num_bin, meta.missing_type, meta.default_bin,
+            meta.penalty, col_mask if bym is None else (col_mask & bym),
+            sg, sh, c, po, sp, max_bin=B, **kw)
+
     # incremental gain scan: a leaf's best split depends only on its own
     # histogram/sums, which change ONLY when the leaf is created — so in
     # the plain mode the per-wave scan touches just the <= 2*Kb leaves
@@ -347,7 +370,8 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
     leaf_cmax0 = jnp.full(cm_n, jnp.inf, f32)
 
     # per-leaf histogram cache (flat [Lp, F'*B'*2] for MXU-friendly
-    # selection matmuls) + exact count cache, carried across waves (the
+    # selection matmuls; a row is a leaf's histogram in (feature, bin,
+    # channel) order) + exact count cache, carried across waves (the
     # HistogramPool analogue, feature_histogram.hpp:1367); completed by
     # sibling subtraction
     Fh = binned.shape[0]
@@ -485,12 +509,14 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
         # when that bound is a small fraction of NLp.  In practice that
         # is the spike waves after the first (Kb=8 vs NLp=pad(Lg));
         # ladder waves, the chain-tail while loop (Kb=pad(Lg/2)), and
-        # short forced prologues all keep the full scan
+        # short forced prologues all keep the full scan: a doubling
+        # ladder scans 528 leaves a tree where 511 are new, so the
+        # rescans are 3% of it
         use_inc = incremental_scan and not first and 4 * Kb <= NLp
         if not use_inc:
-            hists = cache_h[:NLp].reshape(NLp, Fh, hist_B, 2)
+            rows = cache_h[:NLp]
             with global_timer.device_scope("Tree::split_find"):
-                best = best_vm(hists, leaf_sum_g[:NLp], leaf_sum_h[:NLp],
+                best = best_of(rows, leaf_sum_g[:NLp], leaf_sum_h[:NLp],
                                counts, leaf_out[:NLp], *mono_args, rb,
                                rcu, used_vec, bym)
             if incremental_scan:
@@ -506,10 +532,9 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
             news = jnp.where(valid_p, jnp.take(pend_new, psl), Lp)
             changed = jnp.concatenate([parents, news])       # [2*Kb]
             ch = jnp.clip(changed, 0, Lp - 1)
-            h_ch = jnp.take(cache_h, ch, axis=0).reshape(
-                2 * Kb, Fh, hist_B, 2)
+            h_ch = jnp.take(cache_h, ch, axis=0)
             with global_timer.device_scope("Tree::split_find"):
-                best_ch = best_vm(h_ch, jnp.take(leaf_sum_g, ch),
+                best_ch = best_of(h_ch, jnp.take(leaf_sum_g, ch),
                                   jnp.take(leaf_sum_h, ch),
                                   jnp.round(jnp.take(cache_c, ch))
                                   .astype(i32),

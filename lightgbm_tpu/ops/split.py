@@ -1,12 +1,23 @@
-"""Best-split finding: the reference's sequential per-bin gain scan, vectorized.
+"""Best-split finding: the reference's sequential per-bin gain scan, a slab a step.
 
 TPU-native replacement for FeatureHistogram::FindBestThresholdSequentially
 (ref: src/treelearner/feature_histogram.hpp:831-1057) and the CUDA kernels
 FindBestSplitsForLeafKernel / SyncBestSplitForLeafKernel
-(ref: src/treelearner/cuda/cuda_best_split_finder.cu:772,1920): instead of a
-serial loop per feature, both scan directions are evaluated for ALL features
-and ALL candidate thresholds at once via masked prefix/suffix cumsums, then a
-single argmax picks the winner — the shape XLA tiles well.
+(ref: src/treelearner/cuda/cuda_best_split_finder.cu:772,1920): the scan
+runs bin by bin, as the reference's does, but every step handles ALL its
+columns — (leaf, feature) pairs — as one dense slab, and carries each
+column's running sums and running best along (`_scan_thresholds`).  There
+is no prefix array, no argmax and no gather: on a TPU nine
+`take_along_axis` over `[256, 2000, 63]` were 147 of the old scan's 164 ms
+(PERF.md, PR 33).  The candidate evaluation, the gates and the tie-break
+rules exist once, written against the leading bin axis and no layout; two
+entries feed them:
+
+  * `find_best_split`: one leaf's `[F, B, 2]` (the leaf-wise engine, the
+    parallel learners, EFB bundles, categorical features, monotone
+    constraint surfaces);
+  * `find_best_split_dense`: all the leaves of a wave at once, from the
+    wave engine's cache rows.
 
 Behavioral parity notes (each mirrors a reference line):
   * counts are derived from hessians: cnt(bin) = RoundInt(hess * cnt_factor),
@@ -21,7 +32,8 @@ Behavioral parity notes (each mirrors a reference line):
     threshold, so masking is exactly equivalent to breaking.
   * within a scan, ties keep the first-visited threshold: largest for REVERSE,
     smallest for forward; the forward result replaces the reverse one only on
-    strictly larger gain (hpp:1031).
+    strictly larger gain (hpp:1031).  Thresholds separated only by empty bins
+    tie bit-exactly, here as there, because the sums run bin by bin.
   * across features, gain ties pick the smaller feature index
     (split_info.hpp:138-163 operator>).
 
@@ -36,6 +48,8 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+from ..observability import global_registry
 
 K_EPSILON = 1e-15  # ref: include/LightGBM/meta.h:54
 K_MIN_SCORE = -jnp.inf
@@ -58,6 +72,11 @@ class SplitParams(NamedTuple):
     # FindBestThresholdCategoricalInner); has_categorical=False skips the
     # whole categorical branch at trace time
     has_categorical: bool = False
+    # whether any feature of the dataset has a missing type (a fact of the
+    # Dataset, set where the booster is built): only such features have
+    # forward-scan candidates (hpp:1031), so False skips that scan at
+    # trace time
+    has_missing: bool = True
     # static inner-feature indices of the categorical features: the scan
     # (argsort + two sequential prefix scans) runs only over these rows,
     # not all F features; () falls back to scanning every feature
@@ -300,6 +319,325 @@ def _cat_best_split(grad, hess, cnt_factor, num_bin, sum_g, sum_h, num_data,
             so_i, used_bin, order)
 
 
+class _Columns(NamedTuple):
+    """What the threshold scan knows of each of its columns — one (leaf,
+    feature) pair each.  Every field broadcasts against the shape of a
+    slab: per-feature fields lie along the feature axis, per-leaf fields
+    along the leaf axis, and the scan never asks which is which."""
+    num_bin: jnp.ndarray          # int32
+    missing_type: jnp.ndarray     # int32
+    default_bin: jnp.ndarray      # int32
+    sum_g: jnp.ndarray            # leaf totals; sum_h carries the +2*kEpsilon
+    sum_h: jnp.ndarray
+    num_data: jnp.ndarray         # int32
+    parent_output: jnp.ndarray
+
+
+def _leaf_totals(sum_gradient, sum_hessian):
+    """(sum_g, sum_h + 2*kEpsilon) as the scan uses them
+    (ref: feature_histogram.hpp:169 FindBestThreshold)."""
+    f32 = jnp.float32
+    return sum_gradient.astype(f32), sum_hessian.astype(f32) + 2 * K_EPSILON
+
+
+def _min_gain_shift(sum_g, sum_h, num_data, parent_output, params):
+    """The gain a split has to beat: the unsplit leaf's, plus
+    min_gain_to_split (hpp:172-175)."""
+    return (leaf_gain(sum_g, sum_h, num_data.astype(jnp.float32),
+                      parent_output, params) + params.min_gain_to_split)
+
+
+def _scan_thresholds(slab, num_bins: int, shape, col: _Columns,
+                     params: SplitParams, rand_bin=None, mono=None):
+    """Best numerical threshold of every column, by the reference's own
+    two sequential scans (feature_histogram.hpp:831
+    FindBestThresholdSequentially): a running sum over the bins, one dense
+    slab of columns a step, with the running best carried along — no
+    prefix array, no argmax, no gather.
+
+    `slab(t)` gives (gradient, hessian) sums of bin `t` (a traced int32)
+    for all columns, in `shape`; the scan is written against that leading
+    bin axis and knows no layout.  `rand_bin` (extra-trees) and the
+    members of `mono` broadcast against `shape` like `col`'s fields;
+    `mono` is (monotone, min_left, max_left, min_right, max_right): each
+    bound has a leading axis that is read at the candidate threshold —
+    of `num_bins` for a constraint surface, of 1 for the leaf's scalar.
+
+    Because the sums run bin by bin, two candidates separated only by
+    empty bins (an exact 0.0 added, a count of 0) have bit-identical sums
+    and gains, as in the reference, and the strict `>` below keeps the
+    first visited: the largest threshold in the REVERSE scan, the smallest
+    in the forward one.
+
+    Returns (gain, threshold, default_left, left_g, left_h_raw, left_c)
+    per column; gain is K_MIN_SCORE where no candidate passed the gates.
+    """
+    f32, i32 = jnp.float32, jnp.int32
+    nb, mt, db = col.num_bin, col.missing_type, col.default_bin
+    sum_g, sum_h, num_data = col.sum_g, col.sum_h, col.num_data
+    parent_output = col.parent_output
+    cnt_factor = num_data.astype(f32) / sum_h
+    is_nan = mt == MISSING_NAN
+    is_zero = mt == MISSING_ZERO
+    min_gain_shift = _min_gain_shift(sum_g, sum_h, num_data, parent_output,
+                                     params)
+
+    def bin_sums(t):
+        """Bin t's (gradient, hessian, count) where it accumulates: not
+        past the feature's bins, not its NaN bin, not a skipped zero bin
+        (hpp:859-869)."""
+        g, h = slab(t)
+        acc = (t < nb) & ~(is_nan & (t == nb - 1)) & ~(is_zero & (t == db))
+        return (jnp.where(acc, g, 0.0), jnp.where(acc, h, 0.0),
+                jnp.where(acc, _round_int(h * cnt_factor), 0))
+
+    def candidate_gain(left_g, left_h_raw, left_c, tau_ok, tau):
+        """Gain where the left side is (left_g, left_h_raw + eps, left_c),
+        K_MIN_SCORE where a gate refuses the candidate."""
+        left_h = left_h_raw + K_EPSILON
+        right_g = sum_g - left_g
+        right_h = sum_h - left_h
+        right_c = num_data - left_c
+        ok = (tau_ok
+              & (left_c >= params.min_data_in_leaf)
+              & (left_h >= params.min_sum_hessian_in_leaf)
+              & (right_c >= params.min_data_in_leaf)
+              & (right_h >= params.min_sum_hessian_in_leaf))
+        gain = (leaf_gain(left_g, left_h, left_c.astype(f32), parent_output,
+                          params)
+                + leaf_gain(right_g, right_h, right_c.astype(f32),
+                            parent_output, params))
+        if params.has_monotone:
+            # constrained gain for monotone features: outputs clamped to
+            # the leaf's [min, max]; ordering violations score 0
+            # (feature_histogram.hpp:758-797 GetSplitGains USE_MC branch).
+            # Advanced mode (monotone_constraints.hpp:858
+            # AdvancedLeafConstraints) passes PER-CHILD, PER-THRESHOLD
+            # constraint surfaces instead of the leaf scalar.
+            mc, cmin_l, cmax_l, cmin_r, cmax_r = mono
+            at = lambda a: a[jnp.minimum(tau, a.shape[0] - 1)]
+            lout = jnp.clip(leaf_output(left_g, left_h, left_c.astype(f32),
+                                        parent_output, params),
+                            at(cmin_l), at(cmax_l))
+            rout = jnp.clip(leaf_output(right_g, right_h,
+                                        right_c.astype(f32),
+                                        parent_output, params),
+                            at(cmin_r), at(cmax_r))
+            bad = (((mc > 0) & (lout > rout)) | ((mc < 0) & (lout < rout)))
+            # clamping applies to EVERY feature once the leaf is
+            # constrained (USE_MC templates the whole learner); the
+            # ordering rejection only to monotone features
+            gain_mc = (leaf_gain_given_output(left_g, left_h, lout, params)
+                       + leaf_gain_given_output(right_g, right_h, rout,
+                                                params))
+            gain = jnp.where(bad & (mc != 0), 0.0, gain_mc)
+        ok = ok & (gain > min_gain_shift)
+        return jnp.where(ok, gain, K_MIN_SCORE)
+
+    def keep_better(best, new):
+        better = new[0] > best[0]           # strict: first visited wins ties
+        return tuple(jnp.where(better, n, b) for n, b in zip(new, best))
+
+    zf, zi = jnp.zeros(shape, f32), jnp.zeros(shape, i32)
+    no_best = (jnp.full(shape, K_MIN_SCORE, f32), zi, zf, zf, zi)
+    if params.extra_trees:
+        # only the leaf's random threshold is a candidate (USE_RAND:
+        # hpp:899 `t - 1 + offset != rand_threshold -> continue`)
+        is_drawn = lambda tau: tau == rand_bin
+    else:
+        is_drawn = lambda tau: True
+
+    # ---- REVERSE scan: the right side accumulates bins > tau from the top
+    # (ref: hpp:856-930); missing (the NaN bin, a skipped zero bin) stays
+    # out of it and so joins the left: default_left.  right_h = kEps +
+    # suffix and left_h = sum_h - right_h; candidate_gain re-adds its own
+    # eps to the raw left, so raw subtracts both.
+    def rev_step(i, carry):
+        right_g, right_h, right_c, best = carry
+        tau = num_bins - 2 - i
+        g, h, c = bin_sums(tau + 1)
+        right_g, right_h, right_c = right_g + g, right_h + h, right_c + c
+        tau_ok = ((tau <= nb - 2 - is_nan.astype(i32))
+                  & ~(is_zero & (tau == db - 1))        # skipped iteration
+                  & is_drawn(tau))
+        left = (sum_g - right_g, sum_h - right_h - 2 * K_EPSILON,
+                num_data - right_c)
+        gain = candidate_gain(*left, tau_ok, tau)
+        return right_g, right_h, right_c, keep_better(best, (gain, tau) + left)
+
+    steps = max(num_bins - 1, 0)
+    rev = jax.lax.fori_loop(0, steps, rev_step, (zf, zf, zi, no_best))[3]
+    if not params.has_missing:
+        # every forward candidate needs a missing type (hpp:1031 runs the
+        # second scan only for such features): none in this dataset
+        gain, thr, lg, lh_raw, lc = rev
+        return gain, thr, jnp.ones(shape, bool), lg, lh_raw, lc
+
+    # ---- FORWARD scan: left = inclusive prefix at tau; missing goes right
+    def fwd_step(tau, carry):
+        left_g, left_h, left_c, best = carry
+        g, h, c = bin_sums(tau)
+        left = (left_g + g, left_h + h, left_c + c)
+        tau_ok = ((tau <= nb - 2) & (mt != MISSING_NONE)
+                  & ~(is_zero & (tau == db))            # skipped iteration
+                  & is_drawn(tau))
+        gain = candidate_gain(*left, tau_ok, tau)
+        return left + (keep_better(best, (gain, tau) + left),)
+
+    fwd = jax.lax.fori_loop(0, steps, fwd_step, (zf, zf, zi, no_best))[3]
+    # forward replaces reverse only on strictly larger gain (ref: hpp:1031)
+    use_fwd = fwd[0] > rev[0]
+    gain, thr, lg, lh_raw, lc = (jnp.where(use_fwd, f, r)
+                                 for f, r in zip(fwd, rev))
+    return gain, thr, ~use_fwd, lg, lh_raw, lc
+
+
+def _take_one(a, idx):
+    """a[idx] along the last axis as a masked reduce: an XLA gather walks
+    its indices on a TPU (~1 GB/s), a one-hot select-sum does not, and with
+    one selected element it is exact."""
+    hit = jnp.arange(a.shape[-1], dtype=jnp.int32) == idx
+    if a.dtype == jnp.bool_:
+        return jnp.any(hit & a, axis=-1)
+    return jnp.sum(jnp.where(hit, a, jnp.zeros((), a.dtype)), axis=-1)
+
+
+def _choose_feature(per_feature, feature_penalty, col_mask, sum_g, sum_h,
+                    num_data, parent_output, params: SplitParams, max_bin,
+                    cat=None, cegb_coupled=None, cegb_used=None,
+                    monotone=None, mono_penalty=None, cegb_lazy_cost=None,
+                    clamp_winner=None, return_feature_gains=False):
+    """One leaf's best feature from its per-feature bests ([F] arrays, as
+    `_scan_thresholds` returns them; `sum_h` with its +2*kEpsilon): feature
+    penalty, column sampling, CEGB and the monotone penalty, then the
+    argmax (gain tie -> smaller index, SplitInfo::operator>) and the
+    winner's `SplitResult`.  `cat` is `_cat_best_split`'s result for the
+    categorical features (with their indices and mask), whose numerical
+    results it replaces; `clamp_winner(feature, threshold, is_cat)` gives
+    the (min_l, max_l, min_r, max_r) that clamp the winner's outputs."""
+    f32 = jnp.float32
+    best_gain_f, best_thr_f, default_left_f, lg, lh_raw, lc = per_feature
+    num_features = best_gain_f.shape[0]
+    min_gain_shift = _min_gain_shift(sum_g, sum_h, num_data, parent_output,
+                                     params)
+    if cat is not None:
+        # categorical features replace their numerical scan results;
+        # double-guard with is_cat_f (a numerical feature listed in
+        # cat_features must keep its numerical result)
+        (ci, is_cat_f, cgain, clg, clh, clc, c_onehot, c_ohbin, c_fwd,
+         c_plen, c_ub, c_order) = cat
+        catset = jnp.zeros(num_features, bool).at[ci].set(True) & is_cat_f
+        best_gain_f = jnp.where(catset, best_gain_f.at[ci].set(cgain),
+                                best_gain_f)
+        lg = jnp.where(catset, lg.at[ci].set(clg), lg)
+        lh_raw = jnp.where(catset, lh_raw.at[ci].set(clh - K_EPSILON),
+                           lh_raw)
+        lc = jnp.where(catset, lc.at[ci].set(clc), lc)
+        default_left_f = jnp.where(catset, False, default_left_f)
+        # map a winning full-F index back to its compact cat row
+        pos_of_f = jnp.zeros(num_features, jnp.int32).at[ci].set(
+            jnp.arange(ci.shape[0], dtype=jnp.int32))
+
+    shifted = (best_gain_f - min_gain_shift) * feature_penalty
+    if params.has_cegb:
+        # ref: serial_tree_learner.cpp:983 new_split.gain -= DeltaGain(...)
+        delta = params.cegb_tradeoff * (
+            params.cegb_penalty_split * num_data.astype(f32))
+        if cegb_coupled is not None:
+            delta = delta + params.cegb_tradeoff * jnp.where(
+                cegb_used, 0.0, cegb_coupled)
+        if params.has_cegb_lazy and cegb_lazy_cost is not None:
+            # ref: cost_effective_gradient_boosting.hpp:91 DeltaGain's
+            # CalculateOndemandCosts term
+            delta = delta + params.cegb_tradeoff * cegb_lazy_cost
+        shifted = shifted - delta
+    if params.has_monotone and params.monotone_penalty > 0:
+        # depth-based penalty on monotone features' gains
+        # (serial_tree_learner.cpp:987-991)
+        shifted = jnp.where(monotone != 0, shifted * mono_penalty, shifted)
+    shifted = jnp.where(col_mask & (best_gain_f > K_MIN_SCORE), shifted,
+                        K_MIN_SCORE)
+    if return_feature_gains:
+        # per-feature shifted best gains, for the voting-parallel learner's
+        # local vote (ref: voting_parallel_tree_learner.cpp:151 GlobalVoting
+        # ranks features by their local best split gains)
+        return shifted
+    best_f = jnp.argmax(shifted, axis=0).astype(jnp.int32)
+
+    g_ = jnp.max(shifted, axis=0)
+    lg_, lc_ = _take_one(lg, best_f), _take_one(lc, best_f)
+    lh_ = _take_one(lh_raw, best_f) + K_EPSILON
+    rg_, rc_ = sum_g - lg_, num_data - lc_
+    rh_ = sum_h - lh_
+    thr_ = _take_one(best_thr_f, best_f)
+
+    W = cat_bitset_words(max_bin)
+    if cat is not None:
+        won_cat = catset[best_f]
+        cpos = pos_of_f[best_f]          # winner's compact cat row
+        # leaf outputs use lambda_l2 + cat_l2 only for sorted-subset
+        # categorical winners, not one-hot (feature_histogram.cpp:250)
+        pcat = params._replace(lambda_l2=params.lambda_l2 + params.cat_l2)
+        won_subset = won_cat & ~c_onehot[cpos]
+        left_out = jnp.where(
+            won_subset,
+            leaf_output(lg_, lh_, lc_.astype(f32), parent_output, pcat),
+            leaf_output(lg_, lh_, lc_.astype(f32), parent_output, params))
+        right_out = jnp.where(
+            won_subset,
+            leaf_output(rg_, rh_, rc_.astype(f32), parent_output, pcat),
+            leaf_output(rg_, rh_, rc_.astype(f32), parent_output, params))
+        # winning left-category set as a bin bitset (ref: split_info.hpp
+        # cat_threshold; bins, not raw category values, on device)
+        bins_b = jnp.arange(max_bin, dtype=jnp.int32)
+        sorted_w = c_order[cpos]                         # [B] sorted bins
+        ub = c_ub[cpos]
+        plen = c_plen[cpos] + 1
+        in_set_sorted = jnp.where(
+            c_fwd[cpos], bins_b < plen, (bins_b >= ub - plen) & (bins_b < ub))
+        member = jnp.zeros(max_bin, bool).at[sorted_w].set(
+            in_set_sorted, mode="drop")
+        member = jnp.where(c_onehot[cpos],
+                           bins_b == c_ohbin[cpos], member)
+        member = member & won_cat
+        bit = (member.astype(jnp.int32) << (bins_b % 32))
+        cat_bitset = jnp.zeros(W, jnp.int32).at[bins_b // 32].add(bit)
+        is_cat_out = won_cat
+        thr_out = jnp.where(won_cat, 0, thr_)
+    else:
+        left_out = leaf_output(lg_, lh_, lc_.astype(f32), parent_output,
+                               params)
+        right_out = leaf_output(rg_, rh_, rc_.astype(f32), parent_output,
+                                params)
+        cat_bitset = jnp.zeros(W, jnp.int32)
+        is_cat_out = jnp.asarray(False)
+        thr_out = thr_
+
+    if clamp_winner is not None:
+        # the leaf's [min, max] clamps the winner's stored outputs too
+        # (CalculateSplittedLeafOutput USE_MC, feature_histogram.hpp:740)
+        lmin_w, lmax_w, rmin_w, rmax_w = clamp_winner(best_f, thr_,
+                                                      is_cat_out)
+        left_out = jnp.clip(left_out, lmin_w, lmax_w)
+        right_out = jnp.clip(right_out, rmin_w, rmax_w)
+
+    return SplitResult(
+        gain=g_, feature=best_f, threshold=thr_out,
+        default_left=_take_one(default_left_f, best_f),
+        left_sum_gradient=lg_, left_sum_hessian=lh_ - K_EPSILON,
+        left_count=lc_, left_output=left_out,
+        right_sum_gradient=rg_, right_sum_hessian=rh_ - K_EPSILON,
+        right_count=rc_, right_output=right_out,
+        is_cat=is_cat_out, cat_bitset=cat_bitset)
+
+
+def _count_traced_scan(form: str):
+    """A gain scan of this form is being TRACED: `GET /metrics` and a
+    run's snapshot then say which form each program took
+    (`split_scan_dense_traces`, `split_scan_generic_traces`)."""
+    global_registry.inc(f"split_scan_{form}_traces")
+
+
 @functools.partial(jax.jit,
                    static_argnames=("params", "return_feature_gains"))
 def find_best_split(hist: jnp.ndarray, num_bin: jnp.ndarray,
@@ -325,6 +663,12 @@ def find_best_split(hist: jnp.ndarray, num_bin: jnp.ndarray,
                     return_feature_gains: bool = False) -> SplitResult:
     """Scan all (feature, threshold, direction) candidates; return the leaf's best.
 
+    The per-leaf entry: one leaf's histogram in `[F, B, 2]`, as the
+    leaf-wise engine, the parallel learners, EFB's `bundle_hist_to_features`
+    and the categorical scan's sub-array hand it over.  The wave engine's
+    plain numerical mode scans all its leaves at once from its cache's
+    rows: `find_best_split_dense`.
+
     Args:
       hist: [F, B, 2] (sum_gradient, sum_hessian) per bin.
       num_bin/missing_type/default_bin: [F] int32 per-feature bin metadata.
@@ -335,287 +679,113 @@ def find_best_split(hist: jnp.ndarray, num_bin: jnp.ndarray,
       num_data: actual row count in leaf (int32).
       parent_output: leaf's current output (for path smoothing).
     """
+    _count_traced_scan("generic")
     num_features, max_bin, _ = hist.shape
-    f32 = jnp.float32
-    sum_g = sum_gradient.astype(f32)
-    sum_h = sum_hessian.astype(f32) + 2 * K_EPSILON
-    n_leaf = num_data.astype(f32)
-    cnt_factor = n_leaf / sum_h
+    sum_g, sum_h = _leaf_totals(sum_gradient, sum_hessian)
+    # bins leading: a step of the scan reads one [F] row of each plane
+    grad, hess = hist[:, :, 0].T, hist[:, :, 1].T
+    mono = None
+    if params.has_monotone:
+        if constraint_min_left is not None:     # [F, B] surfaces
+            bounds = tuple(b.T for b in (
+                constraint_min_left, constraint_max_left,
+                constraint_min_right, constraint_max_right))
+        else:
+            bounds = (constraint_min[None], constraint_max[None]) * 2
+        mono = (monotone,) + bounds
+    per_feature = _scan_thresholds(
+        lambda t: (grad[t], hess[t]), max_bin, (num_features,),
+        _Columns(num_bin, missing_type, default_bin, sum_g, sum_h,
+                 num_data, parent_output),
+        params, rand_bin=rand_bin, mono=mono)
 
-    bins = jnp.arange(max_bin, dtype=jnp.int32)[None, :]           # [1, B]
-    nb = num_bin[:, None]
-    mt = missing_type[:, None]
-    db = default_bin[:, None]
-    na_extra = (mt == MISSING_NAN).astype(jnp.int32)               # [F, 1]
-
-    in_range = bins < nb
-    is_na_bin = (mt == MISSING_NAN) & (bins == nb - 1)
-    is_def_bin = (mt == MISSING_ZERO) & (bins == db)
-    acc = in_range & ~is_na_bin & ~is_def_bin
-    grad = jnp.where(acc, hist[:, :, 0], 0.0)
-    hess = jnp.where(acc, hist[:, :, 1], 0.0)
-    cnt = jnp.where(acc, _round_int(hist[:, :, 1] * cnt_factor), 0)
-
-    pg = jnp.cumsum(grad, axis=1)
-    ph = jnp.cumsum(hess, axis=1)
-    pc = jnp.cumsum(cnt, axis=1)
-    tg, th, tc = pg[:, -1:], ph[:, -1:], pc[:, -1:]
-
-    min_gain_shift = (leaf_gain(sum_g, sum_h, n_leaf, parent_output, params)
-                      + params.min_gain_to_split)
-
-    def eval_candidates(left_g, left_h_raw, left_c, tau_ok):
-        """Gain for candidates where left side = (left_g, left_h_raw+eps, left_c)."""
-        left_h = left_h_raw + K_EPSILON
-        right_g = sum_g - left_g
-        right_h = sum_h - left_h
-        right_c = num_data - left_c
-        ok = (tau_ok
-              & (left_c >= params.min_data_in_leaf)
-              & (left_h >= params.min_sum_hessian_in_leaf)
-              & (right_c >= params.min_data_in_leaf)
-              & (right_h >= params.min_sum_hessian_in_leaf))
-        gain = (leaf_gain(left_g, left_h, left_c.astype(f32), parent_output, params)
-                + leaf_gain(right_g, right_h, right_c.astype(f32), parent_output,
-                            params))
-        if params.has_monotone:
-            # constrained gain for monotone features: outputs clamped to
-            # the leaf's [min, max]; ordering violations score 0
-            # (feature_histogram.hpp:758-797 GetSplitGains USE_MC branch).
-            # Advanced mode (monotone_constraints.hpp:858
-            # AdvancedLeafConstraints) passes PER-CHILD, PER-THRESHOLD
-            # [F, B] constraint surfaces instead of the leaf scalar.
-            mc = monotone[:, None]
-            cmin_l = (constraint_min_left if constraint_min_left is not None
-                      else constraint_min)
-            cmax_l = (constraint_max_left if constraint_max_left is not None
-                      else constraint_max)
-            cmin_r = (constraint_min_right
-                      if constraint_min_right is not None
-                      else constraint_min)
-            cmax_r = (constraint_max_right
-                      if constraint_max_right is not None
-                      else constraint_max)
-            lout = jnp.clip(leaf_output(left_g, left_h, left_c.astype(f32),
-                                        parent_output, params),
-                            cmin_l, cmax_l)
-            rout = jnp.clip(leaf_output(right_g, right_h,
-                                        right_c.astype(f32),
-                                        parent_output, params),
-                            cmin_r, cmax_r)
-            bad = (((mc > 0) & (lout > rout)) | ((mc < 0) & (lout < rout)))
-            # clamping applies to EVERY feature once the leaf is
-            # constrained (USE_MC templates the whole learner); the
-            # ordering rejection only to monotone features
-            gain_mc = (leaf_gain_given_output(left_g, left_h, lout, params)
-                       + leaf_gain_given_output(right_g, right_h, rout,
-                                                params))
-            gain = jnp.where(bad & (mc != 0), 0.0, gain_mc)
-        ok = ok & (gain > min_gain_shift)
-        return jnp.where(ok, gain, K_MIN_SCORE)
-
-    # ---- canonical tie-break across empty-bin runs -------------------------
-    # Candidate thresholds separated only by EMPTY bins (zero accumulated
-    # grad/hess/count between them) induce the identical row partition; in
-    # the reference's sequential scan their left sums tie bit-exactly, so
-    # its strict `>` keeps the first-visited candidate (largest tau for
-    # REVERSE, smallest for forward).  jnp.cumsum is a TREE scan: the
-    # prefix sums at two such candidates can disagree in the last ulp, and
-    # which side the noise lands on depends on the summands — a serial and
-    # a psum'd (data-parallel) histogram can therefore flip the argmax
-    # between truly-tied thresholds (the test_parallel threshold
-    # "off-by-two").  Snap the winner to its run's canonical end; the
-    # partition is unchanged by construction, so only the float payload
-    # moves (by ulps).
-    nonempty = (grad != 0.0) | (hess != 0.0) | (cnt != 0)
-    last_ne = jax.lax.cummax(jnp.where(nonempty, bins, -1), axis=1)
-
-    def snap_over_empty(best_idx, gain_2d, up):
-        t0 = best_idx[:, None]
-        valid = gain_2d > K_MIN_SCORE  # candidate passed every gate
-        if up:
-            run = valid & (bins >= t0) & (last_ne <= t0)
-            return jnp.max(jnp.where(run, bins, t0), axis=1)
-        lo = jnp.take_along_axis(last_ne, t0, 1)  # last non-empty <= t0
-        run = valid & (bins <= t0) & (bins >= lo)
-        return jnp.min(jnp.where(run, bins, t0), axis=1)
-
-    # ---- REVERSE scan: left = bins <= tau (+NaN, +zero-bin when default_left) ----
-    # right side accumulates bins > tau; candidate at threshold tau = t-1
-    # (ref: hpp:856-930), so left sums are the inclusive prefix at tau.
-    rev_tau_ok = (bins <= nb - 2 - na_extra) & in_range
-    rev_tau_ok &= ~((mt == MISSING_ZERO) & (bins == db - 1))  # skipped iteration
-    if params.extra_trees:
-        # only the leaf's random threshold is a candidate (USE_RAND:
-        # hpp:899 `t - 1 + offset != rand_threshold -> continue`)
-        rev_tau_ok &= bins == rand_bin[:, None]
-    # REVERSE accumulates right_h = kEps + suffix; left_h = sum_h - right_h.
-    # eval_candidates re-adds its own eps to the raw left, so raw subtracts both.
-    rev_left_g = sum_g - (tg - pg)
-    rev_left_h_raw = sum_h - (th - ph) - 2 * K_EPSILON
-    rev_left_c = num_data - (tc - pc)
-    rev_gain = eval_candidates(rev_left_g, rev_left_h_raw, rev_left_c, rev_tau_ok)
-    # tie-break: largest tau wins (scan visits from the right)
-    rev_best_idx = (max_bin - 1
-                    - jnp.argmax(rev_gain[:, ::-1], axis=1)).astype(jnp.int32)
-    rev_best_idx = snap_over_empty(rev_best_idx, rev_gain, up=True)
-    rev_best_gain = jnp.take_along_axis(rev_gain, rev_best_idx[:, None], 1)[:, 0]
-
-    # ---- FORWARD scan: left = inclusive prefix at tau; missing goes right ----
-    fwd_tau_ok = (bins <= nb - 2) & in_range & (mt != MISSING_NONE)
-    fwd_tau_ok &= ~((mt == MISSING_ZERO) & (bins == db))      # skipped iteration
-    if params.extra_trees:
-        fwd_tau_ok &= bins == rand_bin[:, None]
-    fwd_gain = eval_candidates(pg, ph, pc, fwd_tau_ok)
-    fwd_best_idx = jnp.argmax(fwd_gain, axis=1).astype(jnp.int32)
-    fwd_best_idx = snap_over_empty(fwd_best_idx, fwd_gain, up=False)
-    fwd_best_gain = jnp.take_along_axis(fwd_gain, fwd_best_idx[:, None], 1)[:, 0]
-
-    # forward replaces reverse only on strictly larger gain (ref: hpp:1031)
-    use_fwd = fwd_best_gain > rev_best_gain
-    best_gain_f = jnp.where(use_fwd, fwd_best_gain, rev_best_gain)
-    best_thr_f = jnp.where(use_fwd, fwd_best_idx, rev_best_idx)
-    # per-feature left sums at the winning threshold
-    take = lambda a, idx: jnp.take_along_axis(a, idx[:, None], 1)[:, 0]
-    lg = jnp.where(use_fwd, take(pg, fwd_best_idx), take(rev_left_g, rev_best_idx))
-    lh_raw = jnp.where(use_fwd, take(ph, fwd_best_idx),
-                       take(rev_left_h_raw, rev_best_idx))
-    lc = jnp.where(use_fwd, take(pc, fwd_best_idx), take(rev_left_c, rev_best_idx))
-    default_left_f = ~use_fwd
-
-    W = cat_bitset_words(max_bin)
+    cat = None
     if params.has_categorical:
         # the expensive scan (argsort + two sequential prefix scans) runs
         # only over the categorical rows, gathered into a static
         # F_cat-sized subarray; results scatter back into the [F] arrays
-        is_cat_f = is_cat_feature
         cat_idx = (params.cat_features if params.cat_features
                    else tuple(range(num_features)))
         ci = jnp.asarray(cat_idx, jnp.int32)
-        (cgain, clg, clh, clc, c_onehot, c_ohbin, c_fwd, c_plen, c_ub,
-         c_order) = _cat_best_split(
-            hist[ci, :, 0], hist[ci, :, 1], cnt_factor,
+        cat = (ci, is_cat_feature) + _cat_best_split(
+            hist[ci, :, 0], hist[ci, :, 1],
+            num_data.astype(jnp.float32) / sum_h,
             num_bin[ci], sum_g, sum_h, num_data, parent_output,
-            min_gain_shift, params,
+            _min_gain_shift(sum_g, sum_h, num_data, parent_output, params),
+            params,
             rand_u=None if rand_cat_u is None else rand_cat_u[ci])
-        # categorical features replace their numerical scan results;
-        # double-guard with is_cat_f (a numerical feature listed in
-        # cat_features must keep its numerical result)
-        catset = jnp.zeros(num_features, bool).at[ci].set(True) & is_cat_f
-        best_gain_f = jnp.where(catset, best_gain_f.at[ci].set(cgain),
-                                best_gain_f)
-        lg = jnp.where(catset, lg.at[ci].set(clg), lg)
-        lh_raw = jnp.where(catset, lh_raw.at[ci].set(clh - K_EPSILON),
-                           lh_raw)
-        lc = jnp.where(catset, lc.at[ci].set(clc), lc)
-        default_left_f = jnp.where(catset, False, default_left_f)
-        # map a winning full-F index back to its compact cat row
-        pos_of_f = jnp.zeros(num_features, jnp.int32).at[ci].set(
-            jnp.arange(len(cat_idx), dtype=jnp.int32))
 
-    # feature penalty + column sampling, then pick the best feature
-    # (gain tie -> smaller index, matching SplitInfo::operator>)
-    shifted = (best_gain_f - min_gain_shift) * feature_penalty
-    if params.has_cegb:
-        # ref: serial_tree_learner.cpp:983 new_split.gain -= DeltaGain(...)
-        delta = params.cegb_tradeoff * (
-            params.cegb_penalty_split * num_data.astype(f32))
-        if cegb_coupled is not None:
-            delta = delta + params.cegb_tradeoff * jnp.where(
-                cegb_used, 0.0, cegb_coupled)
-        if params.has_cegb_lazy and cegb_lazy_cost is not None:
-            # ref: cost_effective_gradient_boosting.hpp:91 DeltaGain's
-            # CalculateOndemandCosts term
-            delta = delta + params.cegb_tradeoff * cegb_lazy_cost
-        shifted = shifted - delta
-    if params.has_monotone and params.monotone_penalty > 0:
-        # depth-based penalty on monotone features' gains
-        # (serial_tree_learner.cpp:987-991)
-        shifted = jnp.where(monotone != 0, shifted * mono_penalty, shifted)
-    shifted = jnp.where(col_mask & (best_gain_f > K_MIN_SCORE), shifted, K_MIN_SCORE)
-    if return_feature_gains:
-        # per-feature shifted best gains, for the voting-parallel learner's
-        # local vote (ref: voting_parallel_tree_learner.cpp:151 GlobalVoting
-        # ranks features by their local best split gains)
-        return shifted
-    best_f = jnp.argmax(shifted, axis=0).astype(jnp.int32)
-
-    g_ = shifted[best_f]
-    lg_, lc_ = lg[best_f], lc[best_f]
-    lh_ = lh_raw[best_f] + K_EPSILON
-    rg_, rc_ = sum_g - lg_, num_data - lc_
-    rh_ = sum_h - lh_
-
-    if params.has_categorical:
-        won_cat = catset[best_f]
-        cpos = pos_of_f[best_f]          # winner's compact cat row
-        # leaf outputs use lambda_l2 + cat_l2 only for sorted-subset
-        # categorical winners, not one-hot (feature_histogram.cpp:250)
-        pcat = params._replace(lambda_l2=params.lambda_l2 + params.cat_l2)
-        won_subset = won_cat & ~c_onehot[cpos]
-        left_out = jnp.where(
-            won_subset,
-            leaf_output(lg_, lh_, lc_.astype(f32), parent_output, pcat),
-            leaf_output(lg_, lh_, lc_.astype(f32), parent_output, params))
-        right_out = jnp.where(
-            won_subset,
-            leaf_output(rg_, rh_, rc_.astype(f32), parent_output, pcat),
-            leaf_output(rg_, rh_, rc_.astype(f32), parent_output, params))
-        # winning left-category set as a bin bitset (ref: split_info.hpp
-        # cat_threshold; bins, not raw category values, on device)
-        bins_b = jnp.arange(max_bin, dtype=jnp.int32)
-        sorted_w = c_order[cpos]                         # [B] sorted bins
-        ub = c_ub[cpos]
-        plen = c_plen[cpos] + 1
-        pos = jnp.arange(max_bin, dtype=jnp.int32)
-        in_set_sorted = jnp.where(
-            c_fwd[cpos], pos < plen, (pos >= ub - plen) & (pos < ub))
-        member = jnp.zeros(max_bin, bool).at[sorted_w].set(
-            in_set_sorted, mode="drop")
-        member = jnp.where(c_onehot[cpos],
-                           bins_b == c_ohbin[cpos], member)
-        member = member & won_cat
-        word_idx = bins_b // 32
-        bit = (member.astype(jnp.int32) << (bins_b % 32))
-        cat_bitset = jnp.zeros(W, jnp.int32).at[word_idx].add(bit)
-        is_cat_out = won_cat
-        thr_out = jnp.where(won_cat, 0, best_thr_f[best_f])
-    else:
-        left_out = leaf_output(lg_, lh_, lc_.astype(f32), parent_output,
-                               params)
-        right_out = leaf_output(rg_, rh_, rc_.astype(f32), parent_output,
-                                params)
-        cat_bitset = jnp.zeros(W, jnp.int32)
-        is_cat_out = jnp.asarray(False)
-        thr_out = best_thr_f[best_f]
-
+    clamp_winner = None
     if params.has_monotone:
-        # the leaf's [min, max] clamps the winner's stored outputs too
-        # (CalculateSplittedLeafOutput USE_MC, feature_histogram.hpp:740).
-        # Advanced mode clamps with the constraint surface AT the winning
-        # (feature, threshold); categorical winners keep the conservative
-        # whole-leaf scalar (their surfaces are threshold-indexed).
-        if constraint_min_left is not None:
-            thr_n = best_thr_f[best_f]
-            lmin_w = jnp.where(is_cat_out, constraint_min,
-                               constraint_min_left[best_f, thr_n])
-            lmax_w = jnp.where(is_cat_out, constraint_max,
-                               constraint_max_left[best_f, thr_n])
-            rmin_w = jnp.where(is_cat_out, constraint_min,
-                               constraint_min_right[best_f, thr_n])
-            rmax_w = jnp.where(is_cat_out, constraint_max,
-                               constraint_max_right[best_f, thr_n])
-            left_out = jnp.clip(left_out, lmin_w, lmax_w)
-            right_out = jnp.clip(right_out, rmin_w, rmax_w)
-        else:
-            left_out = jnp.clip(left_out, constraint_min, constraint_max)
-            right_out = jnp.clip(right_out, constraint_min, constraint_max)
+        def clamp_winner(best_f, thr, is_cat):
+            # Advanced mode clamps with the constraint surface AT the
+            # winning (feature, threshold); categorical winners keep the
+            # conservative whole-leaf scalar (their surfaces are
+            # threshold-indexed).
+            if constraint_min_left is None:
+                return (constraint_min, constraint_max) * 2
+            return tuple(jnp.where(is_cat, whole, surface[best_f, thr])
+                         for whole, surface in (
+                             (constraint_min, constraint_min_left),
+                             (constraint_max, constraint_max_left),
+                             (constraint_min, constraint_min_right),
+                             (constraint_max, constraint_max_right)))
+    return _choose_feature(
+        per_feature, feature_penalty, col_mask, sum_g, sum_h, num_data,
+        parent_output, params, max_bin, cat=cat, cegb_coupled=cegb_coupled,
+        cegb_used=cegb_used, monotone=monotone, mono_penalty=mono_penalty,
+        cegb_lazy_cost=cegb_lazy_cost, clamp_winner=clamp_winner,
+        return_feature_gains=return_feature_gains)
 
-    return SplitResult(
-        gain=g_, feature=best_f, threshold=thr_out,
-        default_left=default_left_f[best_f],
-        left_sum_gradient=lg_, left_sum_hessian=lh_ - K_EPSILON,
-        left_count=lc_, left_output=left_out,
-        right_sum_gradient=rg_, right_sum_hessian=rh_ - K_EPSILON,
-        right_count=rc_, right_output=right_out,
-        is_cat=is_cat_out, cat_bitset=cat_bitset)
+
+@functools.partial(jax.jit, static_argnames=("params", "max_bin"))
+def find_best_split_dense(rows: jnp.ndarray, num_bin: jnp.ndarray,
+                          missing_type: jnp.ndarray,
+                          default_bin: jnp.ndarray,
+                          feature_penalty: jnp.ndarray,
+                          col_mask: jnp.ndarray,
+                          sum_gradient: jnp.ndarray,
+                          sum_hessian: jnp.ndarray, num_data: jnp.ndarray,
+                          parent_output: jnp.ndarray, params: SplitParams,
+                          max_bin: int, rand_bin: jnp.ndarray = None,
+                          cegb_coupled: jnp.ndarray = None,
+                          cegb_used: jnp.ndarray = None) -> SplitResult:
+    """`find_best_split` for N leaves at once, from the wave engine's
+    cache rows: every leaf's histogram is read once, in a layout that is
+    dense on the chip.
+
+    Args:
+      rows: [N, F * B * 2] float32, a leaf a row in the cache's
+        (feature, bin, channel) order.
+      col_mask: [F] bool, or [N, F] for per-leaf masks (by-node sampling,
+        interaction constraints).
+      sum_gradient / sum_hessian / num_data / parent_output: [N].
+      rand_bin: [N, F] (extra-trees).
+    Plain numerical features only: categorical scans, monotone constraint
+    surfaces and EFB's per-feature gather arrive per leaf in `[F, B, 2]`
+    and take `find_best_split`.  Returns a `SplitResult` of [N] fields.
+    """
+    assert not (params.has_categorical or params.has_monotone)
+    _count_traced_scan("dense")
+    N = rows.shape[0]
+    F = num_bin.shape[0]
+    num_data = num_data.astype(jnp.int32)
+    sum_g, sum_h = _leaf_totals(sum_gradient, sum_hessian)
+    # [2, B, N, F]: gradient and hessian as separate planes, the bins
+    # leading; a step of the scan reads one [N, F] slab of each, leaves on
+    # sublanes and features on lanes
+    planes = rows.reshape(N, F, max_bin, 2).transpose(3, 2, 0, 1)
+    col = _Columns(num_bin[None, :], missing_type[None, :],
+                   default_bin[None, :], sum_g[:, None], sum_h[:, None],
+                   num_data[:, None], parent_output[:, None])
+    per_feature = _scan_thresholds(
+        lambda t: (planes[0, t], planes[1, t]), max_bin, (N, F), col, params,
+        rand_bin=rand_bin)
+    choose = functools.partial(
+        _choose_feature, params=params, max_bin=max_bin,
+        cegb_coupled=cegb_coupled, cegb_used=cegb_used)
+    return jax.vmap(
+        lambda pf, cm, sg, sh, n, po: choose(pf, feature_penalty, cm, sg,
+                                             sh, n, po),
+        in_axes=(0, 0 if col_mask.ndim == 2 else None, 0, 0, 0, 0))(
+            per_feature, col_mask, sum_g, sum_h, num_data, parent_output)

@@ -19,6 +19,14 @@ Checks (small shapes, seconds of chip time):
      bit, at 2 to 1,000 leaves with -0.0, a denormal, the largest float,
      both infinities and a NaN among the values and ids outside the table
 
+  6. the wave engine's gain scan (`ops/split.py find_best_split_dense`)
+     == a bin-by-bin float32 scan on the host, at the benchmark's own
+     `[256, 2000, 63]` and `[256, 28, 255]`, all three missing types and
+     runs of empty bins among the features: feature, threshold,
+     `default_left` and counts exactly, sums to float32; and at
+     `[256, 2000, 63]` the program the cells run, no missing type in the
+     data and `has_missing=False` (the REVERSE scan alone)
+
 `run_wide_checks()` (`python tools/kernel_checks.py --wide`; a minute of
 chip time, so not part of `run_checks`) holds the fused wave kernel to a
 plain float32 reference at the widest benchmark cell's own shape,
@@ -158,7 +166,146 @@ def run_checks():
         traceback.print_exc()
         failures.append(f"score_lookup_raised({type(e).__name__})")
 
+    # 6. the dense gain scan vs a sequential host scan, at the cells' shapes
+    try:
+        for N, F, B, has_missing in ((256, 2000, 63, True),
+                                     (256, 28, 255, True),
+                                     (256, 2000, 63, False)):
+            failures.extend(_scan_mismatches(N, F, B, has_missing))
+    except Exception as e:    # noqa: BLE001 - named in the verdict
+        traceback.print_exc()
+        failures.append(f"split_scan_raised({type(e).__name__})")
+
     return "ok" if not failures else "fail:" + ",".join(failures)
+
+
+def _scan_operands(N, F, B, has_missing, seed=41, rows_a_leaf=4000):
+    """N leaves' histograms [N, F, B, 2] (hessian = rows a bin, so counts
+    are exact), every feature with its own num_bin, missing type (none
+    anywhere without `has_missing`), default bin and a run of empty
+    bins."""
+    rng = np.random.RandomState(seed)
+    nb = rng.randint(max(B // 2, 2), B + 1, F).astype(np.int32)
+    mt = rng.randint(0, 3, F).astype(np.int32) * int(has_missing)
+    db = (rng.randint(0, B, F) % nb).astype(np.int32)
+    bins = np.arange(B)[None, :]
+    lo = rng.randint(0, B, F)[:, None]
+    open_bin = (bins < nb[:, None]) & ~((bins >= lo) & (bins < lo + B // 8))
+    open_bin[:, 0] = True
+    p = open_bin / open_bin.sum(1, keepdims=True)
+    # rows of a (leaf, feature) over its open bins, the remainder of the
+    # flooring into bin 0
+    w = rng.gamma(4.0, 0.25, (N, F, B)) * p[None]
+    cnt = np.floor(w / w.sum(2, keepdims=True) * rows_a_leaf).astype(np.int64)
+    cnt[:, :, 0] += rows_a_leaf - cnt.sum(2)
+    grad = (rng.randn(N, F, B) * np.sqrt(cnt)
+            + 0.05 * cnt * np.sign(bins - B / 3)[None]).astype(np.float32)
+    hist = np.stack([grad, cnt.astype(np.float32)], -1)
+    sum_g = grad[:, 0, :].sum(1, dtype=np.float64).astype(np.float32)
+    return hist, nb, mt, db, sum_g, rows_a_leaf
+
+
+def _host_scan(hist, nb, mt, db, sum_g, n, min_data, min_hess, l2):
+    """The reference's two sequential scans (feature_histogram.hpp:831),
+    bin by bin in float32, for every (leaf, feature) at once; then each
+    leaf's best feature, ties to the smaller index."""
+    f32 = np.float32
+    N, F, B, _ = hist.shape
+    eps = f32(1e-15)
+    sum_g = sum_g[:, None]
+    sum_h = f32(n) + f32(2) * eps
+    cnt_factor = f32(n) / sum_h
+    gain_of = lambda g, h: (g * g) / (h + f32(l2))
+    shift = gain_of(sum_g, sum_h)
+    is_nan, is_zero = (mt == 2)[None], (mt == 1)[None]
+    nbr, dbr = nb[None], db[None]
+
+    def bin_sums(t):
+        acc = (t < nbr) & ~(is_nan & (t == nbr - 1)) & ~(is_zero & (t == dbr))
+        g, h = hist[:, :, t, 0], hist[:, :, t, 1]
+        c = np.floor(h * cnt_factor + f32(0.5)).astype(np.int32)
+        return np.where(acc, g, f32(0)), np.where(acc, h, f32(0)), \
+            np.where(acc, c, 0)
+
+    def gains(lg, lh_raw, lc, ok):
+        lh = lh_raw + eps
+        rg, rh, rc = sum_g - lg, sum_h - lh, n - lc
+        ok = (ok & (lc >= min_data) & (lh >= f32(min_hess))
+              & (rc >= min_data) & (rh >= f32(min_hess)))
+        with np.errstate(all="ignore"):
+            gain = gain_of(lg, lh) + gain_of(rg, rh)
+        return np.where(ok & (gain > shift), gain, -np.inf).astype(f32)
+
+    def scan(forward):
+        run = [np.zeros((N, F), f32), np.zeros((N, F), f32),
+               np.zeros((N, F), np.int32)]
+        best = [np.full((N, F), -np.inf, f32), np.zeros((N, F), np.int32),
+                np.zeros((N, F), f32), np.zeros((N, F), f32),
+                np.zeros((N, F), np.int32)]
+        for i in range(B - 1):
+            tau = i if forward else B - 2 - i
+            run = [a + b for a, b in zip(run, bin_sums(tau if forward
+                                                       else tau + 1))]
+            if forward:
+                left = run
+                ok = ((tau <= nbr - 2) & (mt != 0)[None]
+                      & ~(is_zero & (tau == dbr)))
+            else:
+                left = [sum_g - run[0], sum_h - run[1] - f32(2) * eps,
+                        n - run[2]]
+                ok = ((tau <= nbr - 2 - is_nan)
+                      & ~(is_zero & (tau == dbr - 1)))
+            new = [gains(*left, ok), np.full((N, F), tau, np.int32)] + left
+            better = new[0] > best[0]
+            best = [np.where(better, a, b) for a, b in zip(new, best)]
+        return best
+
+    rev, fwd = scan(False), scan(True)
+    use_fwd = fwd[0] > rev[0]
+    gain, thr, lg, lh_raw, lc = (np.where(use_fwd, a, b)
+                                 for a, b in zip(fwd, rev))
+    f = np.argmax(gain, axis=1)
+    at = lambda a: a[np.arange(N), f]
+    return dict(feature=f, threshold=at(thr), default_left=~at(use_fwd),
+                left_count=at(lc), left_sum_gradient=at(lg),
+                left_sum_hessian=at(lh_raw), gain=at(gain) - shift[:, 0])
+
+
+def _scan_mismatches(N, F, B, has_missing):
+    """Names of the fields in which the chip's dense scan and the host's
+    differ at [N, F, B]; [] when they agree."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.split import SplitParams, find_best_split_dense
+    hist, nb, mt, db, sum_g, n = _scan_operands(N, F, B, has_missing)
+    min_data, min_hess, l2 = 40, 100.5, 0.01
+    want = _host_scan(hist, nb, mt, db, sum_g, n, min_data, min_hess, l2)
+    rows = hist.reshape(N, -1)
+    got = find_best_split_dense(
+        jnp.asarray(rows), jnp.asarray(nb), jnp.asarray(mt),
+        jnp.asarray(db), jnp.ones(F, jnp.float32), jnp.ones(F, bool),
+        jnp.asarray(sum_g), jnp.full(N, n, jnp.float32),
+        jnp.full(N, n, jnp.int32), jnp.zeros(N, jnp.float32),
+        SplitParams(lambda_l2=l2, min_data_in_leaf=min_data,
+                    min_sum_hessian_in_leaf=min_hess,
+                    has_missing=has_missing),
+        max_bin=B)
+    bad = []
+    if not (np.isfinite(want["gain"]).all() and (want["gain"] > 0).all()):
+        bad.append("reference_has_no_split")
+    for name in ("feature", "threshold", "default_left", "left_count"):
+        if not np.array_equal(np.asarray(getattr(got, name)), want[name]):
+            bad.append(name)
+    for name, rtol in (("left_sum_gradient", 1e-5),
+                       ("left_sum_hessian", 1e-6), ("gain", 1e-4)):
+        if not np.allclose(np.asarray(getattr(got, name)), want[name],
+                           rtol=rtol, atol=1e-3):
+            bad.append(name)
+    # with missing types both scans have to win somewhere
+    dl = want["default_left"]
+    if has_missing and (dl.all() or not dl.any()):
+        bad.append("one_direction_only")
+    tag = "" if has_missing else "_nomissing"
+    return [f"split_scan_{F}x{B}{tag}_{b}" for b in bad]
 
 
 def _lookup_operands(n, L, seed=31):
