@@ -4,7 +4,13 @@ ref: src/objective/rank_objective.hpp (RankingObjective:28, LambdarankNDCG:131,
 RankXENDCG:362) and the CUDA twin src/objective/cuda/cuda_rank_objective.cu.
 
 Per-query lambda computation is vectorized over the full pairwise matrix of a
-query (no scalar pair loops); queries are processed host-side per iteration.
+query (no scalar pair loops).  Gradients run on the device
+(`make_device_grad_fn`: queries bucketed by padded length, one tensor
+program a bucket, under the device scope `GBDT::gradients`); the per-query
+host loop (`get_gradients_host`) is the reference the tests compare with
+and the path of position-bias rank_xendcg alone.  The plan is built once a
+booster on the host: spans `Rank::init` (max-DCGs) and `Rank::plan`
+(buckets, fills), counters `rank_*`.
 Deviations from the reference, both noted for parity review:
   * the exact sigmoid is used instead of the reference's 1024-bin lookup table
     (rank_objective.hpp GetSigmoid/ConstructSigmoidTable);
@@ -22,6 +28,7 @@ from .config import Config
 from .metric import default_label_gain
 from .objective import ObjectiveFunction
 from .utils import log
+from .utils.timer import global_timer
 
 K_EPSILON = 1e-15
 
@@ -33,7 +40,11 @@ def _discounts(n: int) -> np.ndarray:
 class RankingObjective(ObjectiveFunction):
     """Common per-query driver (ref: rank_objective.hpp:28)."""
 
-    run_on_host = True  # gradients computed host-side per query
+    # not an elementwise `get_gradients(score, label, weight)`: gbdt.py asks
+    # for the per-query program (`make_device_grad_fn`, on the device) and
+    # falls back to `get_gradients_host` where there is none; metrics of
+    # such an objective are evaluated on the host (ops/metrics.py)
+    run_on_host = True
 
     def __init__(self, config: Config):
         super().__init__(config)
@@ -73,6 +84,29 @@ class RankingObjective(ObjectiveFunction):
         # and the host Newton loop stay coherent (re-init, host path)
         self._pos_biases_dev = None
         self._pos_biases_host = v
+
+    def _plan_buckets(self, n_pad: int):
+        """The device program's plan: `metric.bucket_queries`' buckets
+        (queries grouped by padded pow2 length `m`), each with its
+        labels `lab` [Qb, m] in its doc positions.  Counts what the plan
+        holds where it is built: registry counters `rank_queries`,
+        `rank_docs`, `rank_padded_docs` (sum of Qb * m), `rank_buckets`."""
+        from .metric import bucket_queries
+        from .observability import global_registry
+        buckets = bucket_queries(self.query_boundaries, n_pad)
+        last = len(self.label) - 1
+        for b in buckets:
+            # padding points at row n_pad - 1: any label will do, `val` masks it
+            b["lab"] = np.where(
+                b["val"], self.label[np.minimum(b["idx"], last)],
+                0).astype(np.int32)
+        for name, value in (
+                ("rank_queries", self.num_queries),
+                ("rank_docs", int(self.query_boundaries[-1])),
+                ("rank_padded_docs", sum(b["idx"].size for b in buckets)),
+                ("rank_buckets", len(buckets))):
+            global_registry.inc(name, value)
+        return buckets
 
     def get_gradients_host(self, score: np.ndarray):
         """score [n] -> (grad, hess) on host (ref: RankingObjective::GetGradients)."""
@@ -142,14 +176,15 @@ class LambdarankNDCG(RankingObjective):
         if (self.label >= len(self.label_gain)).any() or (self.label < 0).any():
             log.fatal("Label exceeds label_gain size in lambdarank")
         # inverse max DCG at truncation level per query (ref: hpp:160-170)
-        self.inverse_max_dcgs = np.zeros(self.num_queries)
-        disc = _discounts(self.truncation_level)
-        for q in range(self.num_queries):
-            a, b = int(self.query_boundaries[q]), int(self.query_boundaries[q + 1])
-            g = np.sort(self.label_gain[self.label[a:b].astype(np.int64)])[::-1]
-            k = min(self.truncation_level, b - a)
-            max_dcg = float((g[:k] * disc[:k]).sum())
-            self.inverse_max_dcgs[q] = 1.0 / max_dcg if max_dcg > 0 else 0.0
+        with global_timer.scope("Rank::init"):
+            self.inverse_max_dcgs = np.zeros(self.num_queries)
+            disc = _discounts(self.truncation_level)
+            for q in range(self.num_queries):
+                a, b = int(self.query_boundaries[q]), int(self.query_boundaries[q + 1])
+                g = np.sort(self.label_gain[self.label[a:b].astype(np.int64)])[::-1]
+                k = min(self.truncation_level, b - a)
+                max_dcg = float((g[:k] * disc[:k]).sum())
+                self.inverse_max_dcgs[q] = 1.0 / max_dcg if max_dcg > 0 else 0.0
 
     # ------------------------------------------------------------------
     def make_device_grad_fn(self, n_pad: int):
@@ -169,78 +204,88 @@ class LambdarankNDCG(RankingObjective):
         import jax
         import jax.numpy as jnp
 
-        from .metric import bucket_queries
-        qb = self.query_boundaries
-        self._dev_buckets = []
-        for b in bucket_queries(qb, n_pad):
-            Qb, m = len(b["qs"]), b["m"]
-            lab = np.zeros((Qb, m), np.int32)
-            imd = np.zeros(Qb, np.float32)
-            for r, q in enumerate(b["qs"]):
-                a, e = int(qb[q]), int(qb[q + 1])
-                lab[r, :e - a] = self.label[a:e].astype(np.int32)
-                imd[r] = self.inverse_max_dcgs[q]
-            self._dev_buckets.append(dict(
-                m=m, idx=jnp.asarray(b["idx"]), lab=jnp.asarray(lab),
-                val=jnp.asarray(b["val"]), imd=jnp.asarray(imd)))
-        lg = jnp.asarray(self.label_gain, jnp.float32)
         sigmoid, norm, trunc = self.sigmoid, self.norm, self.truncation_level
+
+        def pair_rows(m):
+            """Rows i of a padded length-m block's [Tm, m] pair tensor."""
+            return max(1, min(trunc, m - 1))
+
+        with global_timer.scope("Rank::plan"):
+            self._dev_buckets = [dict(
+                m=b["m"], idx=jnp.asarray(b["idx"]),
+                lab=jnp.asarray(b["lab"]), val=jnp.asarray(b["val"]),
+                imd=jnp.asarray(self.inverse_max_dcgs[b["qs"]]
+                                .astype(np.float32)))
+                for b in self._plan_buckets(n_pad)]
+        # pairs the buckets' tensors evaluate an iteration, masked ones too
+        from .observability import RecompileDetector, global_registry
+        global_registry.inc("rank_pairs", sum(
+            bk["idx"].shape[0] * pair_rows(bk["m"]) * bk["m"]
+            for bk in self._dev_buckets))
+        lg = jnp.asarray(self.label_gain, jnp.float32)
         f32 = jnp.float32
+        dscope = global_timer.device_scope
 
         def bucket_lambdas(sc_b, lab_b, val_b, imd_b, m):
             """[Qb, m] padded query block -> (lambdas, hessians) in the
-            block's doc positions (mirrors _one_query, vectorized)."""
-            Tm = max(1, min(trunc, m - 1))
-            key = jnp.where(val_b, sc_b, -jnp.inf)
-            order = jnp.argsort(-key, axis=1, stable=True)
-            ss = jnp.take_along_axis(sc_b, order, 1)
-            sl = jnp.take_along_axis(lab_b, order, 1)
-            sv = jnp.take_along_axis(val_b, order, 1)
-            ssz = jnp.where(sv, ss, 0.0)
-            cnt = jnp.sum(sv.astype(jnp.int32), axis=1)
-            gains = jnp.take(lg, jnp.clip(sl, 0, lg.shape[0] - 1))
-            disc = (1.0 / jnp.log2(jnp.arange(m, dtype=f32) + 2.0))
-            best = ssz[:, 0]
-            worst = jnp.take_along_axis(
-                ssz, jnp.maximum(cnt - 1, 0)[:, None], 1)[:, 0]
-            gi, gj = gains[:, :Tm, None], gains[:, None, :]
-            si, sj = ssz[:, :Tm, None], ssz[:, None, :]
-            di, dj = disc[None, :Tm, None], disc[None, None, :]
-            li, lj = sl[:, :Tm, None], sl[:, None, :]
-            pair_ok = ((jnp.arange(m)[None, None, :]
-                        > jnp.arange(Tm)[None, :, None])
-                       & (li != lj) & sv[:, :Tm, None] & sv[:, None, :])
-            delta_ndcg = (jnp.abs(gi - gj) * jnp.abs(di - dj)
-                          * imd_b[:, None, None])
-            if norm:
-                dsa = jnp.abs(si - sj)
-                delta_ndcg = jnp.where(
-                    (best != worst)[:, None, None],
-                    delta_ndcg / (0.01 + dsa), delta_ndcg)
-            i_is_high = li > lj
-            d_s = jnp.where(i_is_high, si - sj, sj - si)
-            p = 1.0 / (1.0 + jnp.exp(sigmoid * d_s))
-            p_lambda = jnp.where(pair_ok, -sigmoid * delta_ndcg * p, 0.0)
-            p_hess = jnp.where(pair_ok,
-                               p * (1.0 - p) * sigmoid * sigmoid
-                               * delta_ndcg, 0.0)
-            sign_i = jnp.where(i_is_high, 1.0, -1.0)
-            lam_s = jnp.zeros_like(sc_b).at[:, :Tm].add(
-                jnp.sum(p_lambda * sign_i, axis=2))
-            lam_s = lam_s + jnp.sum(-p_lambda * sign_i, axis=1)
-            hes_s = jnp.zeros_like(sc_b).at[:, :Tm].add(
-                jnp.sum(p_hess, axis=2))
-            hes_s = hes_s + jnp.sum(p_hess, axis=1)
-            if norm:
-                sum_lam = -2.0 * jnp.sum(p_lambda, axis=(1, 2))
-                nf = jnp.where(sum_lam > 0,
-                               jnp.log2(1.0 + sum_lam)
-                               / jnp.maximum(sum_lam, K_EPSILON), 1.0)
-                lam_s = lam_s * nf[:, None]
-                hes_s = hes_s * nf[:, None]
-            inv_order = jnp.argsort(order, axis=1)
-            lam = jnp.take_along_axis(lam_s, inv_order, 1)
-            hes = jnp.take_along_axis(hes_s, inv_order, 1)
+            block's doc positions (mirrors _one_query, vectorized).  Its
+            parts carry `Rank::sort` (into score order and back) and
+            `Rank::pairs` (the [Qb, Tm, m] pair tensor) inside the
+            caller's `GBDT::gradients`."""
+            Tm = pair_rows(m)
+            with dscope("Rank::sort"):
+                key = jnp.where(val_b, sc_b, -jnp.inf)
+                order = jnp.argsort(-key, axis=1, stable=True)
+                ss = jnp.take_along_axis(sc_b, order, 1)
+                sl = jnp.take_along_axis(lab_b, order, 1)
+                sv = jnp.take_along_axis(val_b, order, 1)
+                ssz = jnp.where(sv, ss, 0.0)
+                cnt = jnp.sum(sv.astype(jnp.int32), axis=1)
+                gains = jnp.take(lg, jnp.clip(sl, 0, lg.shape[0] - 1))
+                best = ssz[:, 0]
+                worst = jnp.take_along_axis(
+                    ssz, jnp.maximum(cnt - 1, 0)[:, None], 1)[:, 0]
+            with dscope("Rank::pairs"):
+                disc = (1.0 / jnp.log2(jnp.arange(m, dtype=f32) + 2.0))
+                gi, gj = gains[:, :Tm, None], gains[:, None, :]
+                si, sj = ssz[:, :Tm, None], ssz[:, None, :]
+                di, dj = disc[None, :Tm, None], disc[None, None, :]
+                li, lj = sl[:, :Tm, None], sl[:, None, :]
+                pair_ok = ((jnp.arange(m)[None, None, :]
+                            > jnp.arange(Tm)[None, :, None])
+                           & (li != lj) & sv[:, :Tm, None] & sv[:, None, :])
+                delta_ndcg = (jnp.abs(gi - gj) * jnp.abs(di - dj)
+                              * imd_b[:, None, None])
+                if norm:
+                    dsa = jnp.abs(si - sj)
+                    delta_ndcg = jnp.where(
+                        (best != worst)[:, None, None],
+                        delta_ndcg / (0.01 + dsa), delta_ndcg)
+                i_is_high = li > lj
+                d_s = jnp.where(i_is_high, si - sj, sj - si)
+                p = 1.0 / (1.0 + jnp.exp(sigmoid * d_s))
+                p_lambda = jnp.where(pair_ok, -sigmoid * delta_ndcg * p, 0.0)
+                p_hess = jnp.where(pair_ok,
+                                   p * (1.0 - p) * sigmoid * sigmoid
+                                   * delta_ndcg, 0.0)
+                sign_i = jnp.where(i_is_high, 1.0, -1.0)
+                lam_s = jnp.zeros_like(sc_b).at[:, :Tm].add(
+                    jnp.sum(p_lambda * sign_i, axis=2))
+                lam_s = lam_s + jnp.sum(-p_lambda * sign_i, axis=1)
+                hes_s = jnp.zeros_like(sc_b).at[:, :Tm].add(
+                    jnp.sum(p_hess, axis=2))
+                hes_s = hes_s + jnp.sum(p_hess, axis=1)
+                if norm:
+                    sum_lam = -2.0 * jnp.sum(p_lambda, axis=(1, 2))
+                    nf = jnp.where(sum_lam > 0,
+                                   jnp.log2(1.0 + sum_lam)
+                                   / jnp.maximum(sum_lam, K_EPSILON), 1.0)
+                    lam_s = lam_s * nf[:, None]
+                    hes_s = hes_s * nf[:, None]
+            with dscope("Rank::sort"):
+                inv_order = jnp.argsort(order, axis=1)
+                lam = jnp.take_along_axis(lam_s, inv_order, 1)
+                hes = jnp.take_along_axis(hes_s, inv_order, 1)
             return lam, hes
 
         use_pos = self.positions is not None
@@ -262,8 +307,13 @@ class LambdarankNDCG(RankingObjective):
             lr = self.learning_rate
             reg = self.position_bias_regularization
 
+        @dscope("GBDT::gradients")
         def grad_fn(scores, weight, bucket_args, biases, pos_dev,
                     pos_mask, pos_cnt):
+            """The whole program under `GBDT::gradients`; a bucket's
+            parts under `Rank::gather` (scores into its block),
+            `Rank::sort`, `Rank::pairs` and `Rank::scatter` (back into
+            row order)."""
             sc = scores[0].astype(f32)
             if use_pos:
                 sc = sc + jnp.take(biases, pos_dev)     # hpp:68
@@ -271,13 +321,15 @@ class LambdarankNDCG(RankingObjective):
             h = jnp.zeros(n_pad, f32)
             for bk in bucket_args:
                 m = bk["idx"].shape[1]
-                sc_b = jnp.take(sc, bk["idx"])
+                with dscope("Rank::gather"):
+                    sc_b = jnp.take(sc, bk["idx"])
                 lam, hes = bucket_lambdas(sc_b, bk["lab"], bk["val"],
                                           bk["imd"], m)
-                lam = jnp.where(bk["val"], lam, 0.0)
-                hes = jnp.where(bk["val"], hes, 0.0)
-                g = g.at[bk["idx"].reshape(-1)].add(lam.reshape(-1))
-                h = h.at[bk["idx"].reshape(-1)].add(hes.reshape(-1))
+                with dscope("Rank::scatter"):
+                    lam = jnp.where(bk["val"], lam, 0.0)
+                    hes = jnp.where(bk["val"], hes, 0.0)
+                    g = g.at[bk["idx"].reshape(-1)].add(lam.reshape(-1))
+                    h = h.at[bk["idx"].reshape(-1)].add(hes.reshape(-1))
             if weight is not None:
                 g = g * weight
                 h = h * weight
@@ -293,7 +345,8 @@ class LambdarankNDCG(RankingObjective):
             return g[None, :], h[None, :], biases
 
         # tpulint: disable-next=donate-argnums -- gradient maps read the live score buffer; the boosting loop keeps updating it
-        jitted = jax.jit(grad_fn, static_argnames=())
+        jitted = RecompileDetector(jax.jit(grad_fn, static_argnames=()),
+                                   "gradients")
         zero1 = jnp.zeros(1, f32)
         zeroi = jnp.zeros(1, jnp.int32)
         if not use_pos:
@@ -398,21 +451,15 @@ class RankXENDCG(RankingObjective):
         import jax
         import jax.numpy as jnp
 
-        from .metric import bucket_queries
-        qb = self.query_boundaries
-        buckets = []
-        for b in bucket_queries(qb, n_pad):
-            Qb, m = len(b["qs"]), b["m"]
-            lab = np.zeros((Qb, m), np.int32)
-            for r, q in enumerate(b["qs"]):
-                a, e = int(qb[q]), int(qb[q + 1])
-                lab[r, :e - a] = self.label[a:e].astype(np.int32)
-            buckets.append(dict(
-                idx=jnp.asarray(b["idx"]), lab=jnp.asarray(lab),
+        with global_timer.scope("Rank::plan"):
+            buckets = [dict(
+                idx=jnp.asarray(b["idx"]), lab=jnp.asarray(b["lab"]),
                 val=jnp.asarray(b["val"]),
-                qid=jnp.asarray(np.asarray(b["qs"], np.int32))))
+                qid=jnp.asarray(np.asarray(b["qs"], np.int32)))
+                for b in self._plan_buckets(n_pad)]
         f32 = jnp.float32
         seed = self.seed
+        dscope = global_timer.device_scope
 
         def bucket_grads(key_it, sc_b, lab_b, val_b, qid_b):
             """Vectorized mirror of _one_query over a [Qb, m] block."""
@@ -445,24 +492,28 @@ class RankXENDCG(RankingObjective):
             return (jnp.where(keep & val_b, lambdas, 0.0),
                     jnp.where(keep & val_b, hess, 0.0))
 
+        @dscope("GBDT::gradients")
         def grad_fn(scores, weight, bucket_args, it):
             sc = scores[0].astype(f32)
             key_it = jax.random.fold_in(jax.random.PRNGKey(seed), it)
             g = jnp.zeros(n_pad, f32)
             h = jnp.zeros(n_pad, f32)
             for bk in bucket_args:
-                sc_b = jnp.take(sc, bk["idx"])
+                with dscope("Rank::gather"):
+                    sc_b = jnp.take(sc, bk["idx"])
                 lam, hes = bucket_grads(key_it, sc_b, bk["lab"],
                                         bk["val"], bk["qid"])
-                g = g.at[bk["idx"].reshape(-1)].add(lam.reshape(-1))
-                h = h.at[bk["idx"].reshape(-1)].add(hes.reshape(-1))
+                with dscope("Rank::scatter"):
+                    g = g.at[bk["idx"].reshape(-1)].add(lam.reshape(-1))
+                    h = h.at[bk["idx"].reshape(-1)].add(hes.reshape(-1))
             if weight is not None:
                 g = g * weight
                 h = h * weight
             return g[None, :], h[None, :]
 
+        from .observability import RecompileDetector
         # tpulint: disable-next=donate-argnums -- gradient maps read the live score buffer; the boosting loop keeps updating it
-        jitted = jax.jit(grad_fn)
+        jitted = RecompileDetector(jax.jit(grad_fn), "gradients")
         self._xe_iter = 0
 
         def call(scores, weight):
