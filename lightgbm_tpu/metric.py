@@ -688,12 +688,7 @@ def map_device_plan(metric: "MapMetric", n_pad: int, shared_buckets=None):
             npos = int(rq.sum())
             for ki, k in enumerate(ks):
                 denom[r, ki] = min(npos, min(k, e - a))
-        sh = (shared_buckets[bi] if shared_buckets is not None
-              and bi < len(shared_buckets)
-              and shared_buckets[bi]["idx"].shape == b["idx"].shape
-              else None)
-        buckets.append({"idx": sh["idx"] if sh else jnp.asarray(b["idx"]),
-                        "val": sh["val"] if sh else jnp.asarray(b["val"]),
+        buckets.append({**_shared_or_uploaded(b, shared_buckets, bi),
                         "rel": jnp.asarray(rel),
                         "denom": jnp.asarray(denom)})
         nq += Qb
@@ -724,11 +719,27 @@ def map_device_plan(metric: "MapMetric", n_pad: int, shared_buckets=None):
     return buckets, eval_fn
 
 
+def _shared_or_uploaded(b, shared_buckets, bi):
+    """A bucket's `idx` and `val` on the device.  A ranking objective's
+    plan is `bucket_queries`' too (deterministic), so what its bucket
+    `bi` already holds on the device under the same key and shape is
+    shared instead of kept twice; it holds `val`, and no `idx` since its
+    programs read a query's rows as the run they are (ranking.py)."""
+    import jax.numpy as jnp
+    have = (shared_buckets[bi] if shared_buckets is not None
+            and bi < len(shared_buckets) else {})
+    return {k: have[k] if k in have and have[k].shape == b[k].shape
+            else jnp.asarray(b[k]) for k in ("idx", "val")}
+
+
 def bucket_queries(query_boundaries, n_pad: int):
     """Group queries by pow2-padded length for device-side per-query
-    tensor programs (ranking gradients and ndcg eval share this):
-    returns a list of dicts {qs: [query ids], idx: [Qb, m] int32 global
-    row indices (padding -> n_pad - 1), val: [Qb, m] bool}."""
+    tensor programs: returns a list of dicts {qs: [query ids], m: the
+    padded length, idx: [Qb, m] int32 global row indices (padding ->
+    n_pad - 1), val: [Qb, m] bool}.  The ndcg and map eval plans take a
+    bucket's scores through `idx`; the ranking gradients' plan
+    (ranking.py `_plan_buckets`) fills its labels through it on the host
+    and reaches the rows on the device by the queries' starts instead."""
     qb = np.asarray(query_boundaries)
     lens = np.diff(qb).astype(np.int64)
     groups = {}
@@ -775,15 +786,7 @@ def ndcg_device_plan(metric: "NDCGMetric", n_pad: int,
             for ki, k in enumerate(ks):
                 kk = min(k, e - a)
                 idcg[r, ki] = (ideal[:kk] * disc[:kk]).sum()
-        # a lambdarank objective has already uploaded identical idx/val
-        # tensors (bucket_queries is deterministic) — share them instead
-        # of holding a second device copy
-        sh = (shared_buckets[bi] if shared_buckets is not None
-              and bi < len(shared_buckets)
-              and shared_buckets[bi]["idx"].shape == b["idx"].shape
-              else None)
-        buckets.append({"idx": sh["idx"] if sh else jnp.asarray(b["idx"]),
-                        "val": sh["val"] if sh else jnp.asarray(b["val"]),
+        buckets.append({**_shared_or_uploaded(b, shared_buckets, bi),
                         "g": jnp.asarray(g),
                         "idcg": jnp.asarray(idcg)})
         nq += Qb

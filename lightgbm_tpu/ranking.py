@@ -10,7 +10,11 @@ program a bucket, under the device scope `GBDT::gradients`); the per-query
 host loop (`get_gradients_host`) is the reference the tests compare with
 and the path of position-bias rank_xendcg alone.  The plan is built once a
 booster on the host: spans `Rank::init` (max-DCGs) and `Rank::plan`
-(buckets, fills), counters `rank_*`.
+(buckets, fills, row windows), counters `rank_*`.
+No per-document index map is in the device programs: a query's rows are
+one contiguous run of the score vector, so a bucket reads and writes them
+as whole 128-wide rows (`_read_block`, `_add_block`), and what has to be
+permuted rides a `lax.sort` as a payload.
 Deviations from the reference, both noted for parity review:
   * the exact sigmoid is used instead of the reference's 1024-bin lookup table
     (rank_objective.hpp GetSigmoid/ConstructSigmoidTable);
@@ -33,8 +37,91 @@ from .utils.timer import global_timer
 K_EPSILON = 1e-15
 
 
+LANES = 128     # width of a row of the [n_rows, LANES] view of a row vector
+
+
 def _discounts(n: int) -> np.ndarray:
     return 1.0 / np.log2(np.arange(n) + 2.0)
+
+
+def _window_plan(starts: np.ndarray, m: int, n_rows: int):
+    """Where a bucket's queries lie in the `[n_rows, LANES]` view of a
+    row-ordered vector: a query of padded length `m` that starts at row
+    `a` of the vector lies inside the `W = ceil(m / LANES) + 1` view rows
+    from `a // LANES`, `a % LANES` lanes in.  Returns (`rows` [Qb, W]
+    int32, `shift` [Qb] int32).  A window that runs past the last view
+    row repeats that row: its documents all lie before it."""
+    W = -(-m // LANES) + 1
+    rows = np.minimum(starts[:, None] // LANES + np.arange(W)[None, :],
+                      n_rows - 1)
+    return rows.astype(np.int32), (starts % LANES).astype(np.int32)
+
+
+def _as_rows(vec):
+    """[n] -> its [ceil(n / LANES), LANES] view (zeros after the end)."""
+    import jax.numpy as jnp
+    return jnp.pad(vec, (0, -vec.shape[0] % LANES)).reshape(-1, LANES)
+
+
+def _read_block(vec_rows, rows, shift, m: int):
+    """A bucket's `[Qb, m]` block out of the `[n_rows, LANES]` view: the
+    window's whole rows (one index a row, not one a document), then each
+    query moved left by its `shift`, a power of two a step (bit `k` of
+    the shift takes the copy `2^k` lanes on; the widths shrink with it).
+    Slots past a query's end hold its neighbours: the caller masks."""
+    import jax.numpy as jnp
+    x = jnp.take(vec_rows, rows, axis=0).reshape(rows.shape[0], -1)
+    for k in reversed(range(LANES.bit_length() - 1)):
+        step = 1 << k
+        x = jnp.where((shift >> k & 1).astype(bool)[:, None],
+                      x[:, step:], x[:, :-step])
+    return x[:, :m]
+
+
+def _add_block(acc_rows, block, rows, shift):
+    """`_read_block`'s mirror: `block` [Qb, C, m] (zero outside its
+    documents) widened to the window, each query moved right by its
+    `shift`, and the window's whole rows added into `acc_rows`
+    [n_rows, C, LANES].  Neighbouring queries share a view row; every
+    document gets one term that may be non-zero and zeros, so the sum is
+    exact in any order."""
+    import jax.numpy as jnp
+    Qb, C, m = block.shape
+    W = rows.shape[1]
+    x = jnp.pad(block, ((0, 0), (0, 0), (0, W * LANES - (LANES - 1) - m)))
+    for k in range(LANES.bit_length() - 1):
+        x = jnp.where((shift >> k & 1).astype(bool)[:, None, None],
+                      jnp.pad(x, ((0, 0), (0, 0), (1 << k, 0))),
+                      jnp.pad(x, ((0, 0), (0, 0), (0, 1 << k))))
+    x = x.reshape(Qb, C, W, LANES).transpose(0, 2, 1, 3)
+    return acc_rows.at[rows.reshape(-1)].add(x.reshape(Qb * W, C, LANES))
+
+
+def _over_buckets(sc, bucket_args, block_fn):
+    """(g, h), each [n], of the row-ordered scores `sc` [n]: every
+    bucket's block read out of the scores (device scope `Rank::gather`),
+    handed to `block_fn(sc_b [Qb, m], bucket) -> (lambdas, hessians)`,
+    and the two, zeroed outside the bucket's documents, added back into
+    row order (`Rank::scatter`)."""
+    import jax.numpy as jnp
+    dscope = global_timer.device_scope
+    n = sc.shape[0]
+    with dscope("Rank::gather"):
+        sc_rows = _as_rows(sc)
+    with dscope("Rank::scatter"):
+        gh = jnp.zeros((sc_rows.shape[0], 2, LANES), sc.dtype)
+    for bk in bucket_args:
+        with dscope("Rank::gather"):
+            sc_b = _read_block(sc_rows, bk["rows"], bk["shift"],
+                               bk["val"].shape[1])
+        lam, hes = block_fn(sc_b, bk)
+        with dscope("Rank::scatter"):
+            gh = _add_block(
+                gh, jnp.where(bk["val"][:, None, :],
+                              jnp.stack([lam, hes], axis=1), 0.0),
+                bk["rows"], bk["shift"])
+    with dscope("Rank::scatter"):
+        return gh[:, 0].reshape(-1)[:n], gh[:, 1].reshape(-1)[:n]
 
 
 class RankingObjective(ObjectiveFunction):
@@ -85,25 +172,36 @@ class RankingObjective(ObjectiveFunction):
         self._pos_biases_dev = None
         self._pos_biases_host = v
 
-    def _plan_buckets(self, n_pad: int):
+    def _plan_buckets(self, n_pad: int, label_gain=None):
         """The device program's plan: `metric.bucket_queries`' buckets
         (queries grouped by padded pow2 length `m`), each with its
-        labels `lab` [Qb, m] in its doc positions.  Counts what the plan
-        holds where it is built: registry counters `rank_queries`,
-        `rank_docs`, `rank_padded_docs` (sum of Qb * m), `rank_buckets`."""
+        labels `lab` [Qb, m] in its doc positions, its gains `gain`
+        [Qb, m] float32 (`label_gain[lab]`, where a table is given: the
+        labels never change, so the lookup is made here, once) and its
+        row windows `rows` [Qb, W], `shift` [Qb] (`_window_plan`).
+        Counts what the plan holds where it is built: registry counters
+        `rank_queries`, `rank_docs`, `rank_padded_docs` (sum of Qb * m),
+        `rank_window_rows` (sum of Qb * W: the indices a pass over the
+        buckets moves), `rank_buckets`."""
         from .metric import bucket_queries
         from .observability import global_registry
         buckets = bucket_queries(self.query_boundaries, n_pad)
         last = len(self.label) - 1
+        n_rows = -(-n_pad // LANES)
         for b in buckets:
             # padding points at row n_pad - 1: any label will do, `val` masks it
             b["lab"] = np.where(
                 b["val"], self.label[np.minimum(b["idx"], last)],
                 0).astype(np.int32)
+            if label_gain is not None:
+                b["gain"] = np.asarray(label_gain, np.float32)[b["lab"]]
+            b["rows"], b["shift"] = _window_plan(
+                self.query_boundaries[b["qs"]], b["m"], n_rows)
         for name, value in (
                 ("rank_queries", self.num_queries),
                 ("rank_docs", int(self.query_boundaries[-1])),
                 ("rank_padded_docs", sum(b["idx"].size for b in buckets)),
+                ("rank_window_rows", sum(b["rows"].size for b in buckets)),
                 ("rank_buckets", len(buckets))):
             global_registry.inc(name, value)
         return buckets
@@ -155,8 +253,12 @@ class LambdarankNDCG(RankingObjective):
     bucketed by padded pow2 length, each bucket computes its pairwise
     lambdas as one masked [Qb, T, m] tensor program (the TPU analogue of
     the per-query CUDA kernels in cuda_rank_objective.cu:131
-    GetGradientsKernel_LambdarankNDCG), and results scatter back through
-    the precomputed doc-index map; position-bias offsets and their
+    GetGradientsKernel_LambdarankNDCG).  A bucket reads its scores as
+    whole 128-wide rows of the score vector and adds its results back the
+    same way (a query's documents are contiguous: `_read_block`,
+    `_add_block`); scores, gains and labels go into score order as the
+    payloads of one stable sort and the results come back by a second,
+    keyed on the order the first gave.  Position-bias offsets and their
     Newton update run on device too, the bias vector threaded as
     explicit state."""
     name = "lambdarank"
@@ -191,8 +293,8 @@ class LambdarankNDCG(RankingObjective):
         """Build the jitted device gradient program (always available
         for lambdarank; position-bias mode included).
 
-        Bucket tensors (doc indices, labels, valid masks, 1/maxDCG) are
-        passed as explicit jit arguments — closing over large device
+        Bucket tensors (row windows, labels, gains, valid masks, 1/maxDCG)
+        are passed as explicit jit arguments — closing over large device
         arrays embeds them as constants in the executable (see gbdt.py
         _grad_fn note).
 
@@ -211,40 +313,47 @@ class LambdarankNDCG(RankingObjective):
             return max(1, min(trunc, m - 1))
 
         with global_timer.scope("Rank::plan"):
+            plan = self._plan_buckets(n_pad, self.label_gain)
             self._dev_buckets = [dict(
-                m=b["m"], idx=jnp.asarray(b["idx"]),
-                lab=jnp.asarray(b["lab"]), val=jnp.asarray(b["val"]),
+                rows=jnp.asarray(b["rows"]), shift=jnp.asarray(b["shift"]),
+                lab=jnp.asarray(b["lab"]), gain=jnp.asarray(b["gain"]),
+                val=jnp.asarray(b["val"]),
                 imd=jnp.asarray(self.inverse_max_dcgs[b["qs"]]
                                 .astype(np.float32)))
-                for b in self._plan_buckets(n_pad)]
+                for b in plan]
         # pairs the buckets' tensors evaluate an iteration, masked ones too
         from .observability import RecompileDetector, global_registry
         global_registry.inc("rank_pairs", sum(
-            bk["idx"].shape[0] * pair_rows(bk["m"]) * bk["m"]
-            for bk in self._dev_buckets))
-        lg = jnp.asarray(self.label_gain, jnp.float32)
+            len(b["qs"]) * pair_rows(b["m"]) * b["m"] for b in plan))
         f32 = jnp.float32
         dscope = global_timer.device_scope
 
-        def bucket_lambdas(sc_b, lab_b, val_b, imd_b, m):
+        def bucket_lambdas(sc_b, lab_b, gain_b, val_b, imd_b):
             """[Qb, m] padded query block -> (lambdas, hessians) in the
             block's doc positions (mirrors _one_query, vectorized).  Its
             parts carry `Rank::sort` (into score order and back) and
             `Rank::pairs` (the [Qb, Tm, m] pair tensor) inside the
             caller's `GBDT::gradients`."""
+            m = sc_b.shape[1]
             Tm = pair_rows(m)
             with dscope("Rank::sort"):
+                # one sort carries what the pairs read.  Its second key
+                # is the lane iota, which makes it the stable sort by the
+                # first and comes out as the order.  A block's documents
+                # are its first `cnt` slots and padding sorts last,
+                # behind them among equals: the sorted valid mask is a
+                # prefix, and the sorted scores are the key's negation
                 key = jnp.where(val_b, sc_b, -jnp.inf)
-                order = jnp.argsort(-key, axis=1, stable=True)
-                ss = jnp.take_along_axis(sc_b, order, 1)
-                sl = jnp.take_along_axis(lab_b, order, 1)
-                sv = jnp.take_along_axis(val_b, order, 1)
-                ssz = jnp.where(sv, ss, 0.0)
-                cnt = jnp.sum(sv.astype(jnp.int32), axis=1)
-                gains = jnp.take(lg, jnp.clip(sl, 0, lg.shape[0] - 1))
+                neg, order, gains, sl = jax.lax.sort(
+                    (-key, jax.lax.broadcasted_iota(jnp.int32, key.shape, 1),
+                     gain_b, lab_b),
+                    dimension=1, is_stable=False, num_keys=2)
+                cnt = jnp.sum(val_b.astype(jnp.int32), axis=1)
+                sv = jnp.arange(m)[None, :] < cnt[:, None]
+                ssz = jnp.where(sv, -neg, 0.0)
                 best = ssz[:, 0]
-                worst = jnp.take_along_axis(
-                    ssz, jnp.maximum(cnt - 1, 0)[:, None], 1)[:, 0]
+                # the last document of a descending order
+                worst = jnp.min(jnp.where(val_b, sc_b, jnp.inf), axis=1)
             with dscope("Rank::pairs"):
                 disc = (1.0 / jnp.log2(jnp.arange(m, dtype=f32) + 2.0))
                 gi, gj = gains[:, :Tm, None], gains[:, None, :]
@@ -283,9 +392,9 @@ class LambdarankNDCG(RankingObjective):
                     lam_s = lam_s * nf[:, None]
                     hes_s = hes_s * nf[:, None]
             with dscope("Rank::sort"):
-                inv_order = jnp.argsort(order, axis=1)
-                lam = jnp.take_along_axis(lam_s, inv_order, 1)
-                hes = jnp.take_along_axis(hes_s, inv_order, 1)
+                # back to doc order: the order's entries are unique
+                _, lam, hes = jax.lax.sort((order, lam_s, hes_s), dimension=1,
+                                           is_stable=False, num_keys=1)
             return lam, hes
 
         use_pos = self.positions is not None
@@ -311,25 +420,15 @@ class LambdarankNDCG(RankingObjective):
         def grad_fn(scores, weight, bucket_args, biases, pos_dev,
                     pos_mask, pos_cnt):
             """The whole program under `GBDT::gradients`; a bucket's
-            parts under `Rank::gather` (scores into its block),
-            `Rank::sort`, `Rank::pairs` and `Rank::scatter` (back into
-            row order)."""
-            sc = scores[0].astype(f32)
+            parts under `Rank::gather` (its scores' rows into its
+            block), `Rank::sort`, `Rank::pairs` and `Rank::scatter` (its
+            block's rows added back into row order)."""
+            with dscope("Rank::gather"):
+                sc = scores[0].astype(f32)
             if use_pos:
                 sc = sc + jnp.take(biases, pos_dev)     # hpp:68
-            g = jnp.zeros(n_pad, f32)
-            h = jnp.zeros(n_pad, f32)
-            for bk in bucket_args:
-                m = bk["idx"].shape[1]
-                with dscope("Rank::gather"):
-                    sc_b = jnp.take(sc, bk["idx"])
-                lam, hes = bucket_lambdas(sc_b, bk["lab"], bk["val"],
-                                          bk["imd"], m)
-                with dscope("Rank::scatter"):
-                    lam = jnp.where(bk["val"], lam, 0.0)
-                    hes = jnp.where(bk["val"], hes, 0.0)
-                    g = g.at[bk["idx"].reshape(-1)].add(lam.reshape(-1))
-                    h = h.at[bk["idx"].reshape(-1)].add(hes.reshape(-1))
+            g, h = _over_buckets(sc, bucket_args, lambda sc_b, bk: bucket_lambdas(
+                sc_b, bk["lab"], bk["gain"], bk["val"], bk["imd"]))
             if weight is not None:
                 g = g * weight
                 h = h * weight
@@ -453,8 +552,8 @@ class RankXENDCG(RankingObjective):
 
         with global_timer.scope("Rank::plan"):
             buckets = [dict(
-                idx=jnp.asarray(b["idx"]), lab=jnp.asarray(b["lab"]),
-                val=jnp.asarray(b["val"]),
+                rows=jnp.asarray(b["rows"]), shift=jnp.asarray(b["shift"]),
+                lab=jnp.asarray(b["lab"]), val=jnp.asarray(b["val"]),
                 qid=jnp.asarray(np.asarray(b["qs"], np.int32)))
                 for b in self._plan_buckets(n_pad)]
         f32 = jnp.float32
@@ -494,18 +593,11 @@ class RankXENDCG(RankingObjective):
 
         @dscope("GBDT::gradients")
         def grad_fn(scores, weight, bucket_args, it):
-            sc = scores[0].astype(f32)
+            with dscope("Rank::gather"):
+                sc = scores[0].astype(f32)
             key_it = jax.random.fold_in(jax.random.PRNGKey(seed), it)
-            g = jnp.zeros(n_pad, f32)
-            h = jnp.zeros(n_pad, f32)
-            for bk in bucket_args:
-                with dscope("Rank::gather"):
-                    sc_b = jnp.take(sc, bk["idx"])
-                lam, hes = bucket_grads(key_it, sc_b, bk["lab"],
-                                        bk["val"], bk["qid"])
-                with dscope("Rank::scatter"):
-                    g = g.at[bk["idx"].reshape(-1)].add(lam.reshape(-1))
-                    h = h.at[bk["idx"].reshape(-1)].add(hes.reshape(-1))
+            g, h = _over_buckets(sc, bucket_args, lambda sc_b, bk: bucket_grads(
+                key_it, sc_b, bk["lab"], bk["val"], bk["qid"]))
             if weight is not None:
                 g = g * weight
                 h = h * weight

@@ -462,7 +462,7 @@ def test_plan_counters_equal_the_hand_counts():
     y = np.random.default_rng(1).choice(5, size=sum(lengths)).astype(
         np.float32)
     names = ("rank_queries", "rank_docs", "rank_padded_docs", "rank_pairs",
-             "rank_buckets")
+             "rank_buckets", "rank_window_rows")
     before = {n: global_registry.counter(n) for n in names}
     spans = global_timer.snapshot()
     _objective(y, lengths).make_device_grad_fn(sum(lengths))
@@ -470,6 +470,8 @@ def test_plan_counters_equal_the_hand_counts():
     assert got == {
         "rank_queries": 5, "rank_docs": 69, "rank_buckets": 3,
         "rank_padded_docs": 2 * 8 + 2 * 16 + 64,
+        # ceil(m / 128) + 1 rows of the score vector's 128-wide view a query
+        "rank_window_rows": 5 * 2,
         # [Qb, min(30, m - 1), m] a bucket
         "rank_pairs": 2 * 7 * 8 + 2 * 15 * 16 + 1 * 30 * 64}
     after = global_timer.snapshot()

@@ -27,6 +27,12 @@ Checks (small shapes, seconds of chip time):
      `[256, 2000, 63]` the program the cells run, no missing type in the
      data and `has_missing=False` (the REVERSE scan alone)
 
+  7. the ranking gradient program (`ranking.py`: a query's rows read and
+     added back as whole 128-wide rows, payloads riding the sorts) == the
+     index-map formulation it replaced (`rank_index_map_reference.py`:
+     `jnp.take`, `argsort` + `take_along_axis`, `.at[].add`), bit for
+     bit, on 512 queries of 1 to 1,251 documents with tied scores
+
 `run_wide_checks()` (`python tools/kernel_checks.py --wide`; a minute of
 chip time, so not part of `run_checks`) holds the fused wave kernel to a
 plain float32 reference at the widest benchmark cell's own shape,
@@ -37,6 +43,10 @@ path, which 28 features never take.
 two minutes) times the score update with each form of the lookup at
 2,625,536 rows and 255 to 16,383 leaves, and checks the bits there too:
 the reading behind `boosting/leaf_lookup.py ONE_HOT_MAX_LEAVES`.
+
+`time_rank_gradients()` (`python tools/kernel_checks.py --rank-gradients`;
+two minutes) runs check 7's two programs on the ranking cell's own plan
+(18,919 queries, 2,270,296 rows), compares every bit and times both.
 """
 import os
 import sys
@@ -176,7 +186,105 @@ def run_checks():
         traceback.print_exc()
         failures.append(f"split_scan_raised({type(e).__name__})")
 
+    # 7. the ranking gradients vs the index-map formulation, bit for bit
+    try:
+        differ = _rank_gradient_bits(*_rank_gradient_case(
+            _rank_lengths(512, seed=35)))["differ"]
+        failures.extend(f"rank_gradients_{name}_differ_in_{k}"
+                        for name, k in differ.items() if k)
+    except Exception as e:    # noqa: BLE001 - named in the verdict
+        traceback.print_exc()
+        failures.append(f"rank_gradients_raised({type(e).__name__})")
+
     return "ok" if not failures else "fail:" + ",".join(failures)
+
+
+def _rank_lengths(queries, seed):
+    """Query lengths as the ranking cell's are drawn (log-normal, mean
+    120, 1 to 1,251), with 1, 2 and 1,251 written in."""
+    rng = np.random.RandomState(seed)
+    lens = np.clip(np.rint(np.exp(rng.normal(np.log(120) - 0.245, 0.7,
+                                             queries))), 1, 1251)
+    lens[rng.choice(queries, 3, replace=False)] = (1, 2, 1251)
+    return lens.astype(np.int64)
+
+
+def _rank_gradient_case(lengths, seed=35):
+    """(a lambdarank objective at the cell's settings over `lengths`,
+    n_pad, scores [1, n_pad] rounded to 1/16 so that many tie)."""
+    import jax.numpy as jnp
+    from types import SimpleNamespace
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.ranking import LambdarankNDCG
+    rng = np.random.RandomState(seed)
+    n = int(lengths.sum())
+    n_pad = -(-n // 1024) * 1024
+    y = rng.choice(5, size=n, p=[.52, .32, .13, .02, .01]).astype(np.float32)
+    obj = LambdarankNDCG(Config({"objective": "lambdarank"}))
+    obj.init(SimpleNamespace(
+        label=y, weight=None, position=None, init_score=None,
+        query_boundaries=np.concatenate([[0], np.cumsum(lengths)])), n)
+    scores = (np.round(rng.randn(n_pad) * 24) / 16).astype(np.float32)
+    return obj, n_pad, jnp.asarray(scores)[None, :]
+
+
+def _rank_gradient_bits(obj, n_pad, scores):
+    """The program's and the reference's gradients of `scores`:
+    {"differ": {"grad": entries whose bits differ, "hess": ...},
+     "new": the program's callable, "old": the reference's}.  On a TPU
+    both are compiled as the training loop compiles them; XLA's CPU
+    backend vectorises the same arithmetic differently behind different
+    data movement (an ulp: tests/test_rank_gradients_layout.py), so
+    there both are compiled with the backend's optimiser off."""
+    import jax
+    import jax.numpy as jnp
+    from tools import rank_index_map_reference as reference
+    new = obj.make_device_grad_fn(n_pad)
+    ref = reference.lambdarank(obj, n_pad)
+    old = lambda s, w: ref(s, w, jnp.zeros(1, jnp.float32))[:2]
+    if jax.default_backend() == "cpu":
+        plain = {"xla_backend_optimization_level": 0}
+        run = lambda fn: jax.jit(fn).lower(scores, None).compile(
+            compiler_options=plain)(scores, None)
+    else:
+        run = lambda fn: fn(scores, None)
+    as_bits = lambda a: np.asarray(
+        jax.lax.bitcast_convert_type(a, jnp.int32))
+    differ = {name: int((as_bits(a) != as_bits(b)).sum())
+              for name, a, b in zip(("grad", "hess"), run(new), run(old))}
+    return {"differ": differ, "new": new, "old": old}
+
+
+def time_rank_gradients(rows=2_270_296, seed=35, calls=20):
+    """One JSON line: the ranking cell's plan (`mslr_like.groups`), how
+    many entries of the program's gradients and hessians differ in their
+    bits from the index-map reference's, and milliseconds a call of
+    each, the host's clock around `calls` calls."""
+    import json
+    import time
+    import jax
+    from benchmarks.generators import mslr_like
+    from lightgbm_tpu.observability import global_registry
+    names = ("rank_queries", "rank_docs", "rank_padded_docs",
+             "rank_window_rows", "rank_buckets")
+    before = {n: global_registry.counter(n) for n in names}
+    obj, n_pad, scores = _rank_gradient_case(mslr_like.groups(rows, seed),
+                                             seed)
+    got = _rank_gradient_bits(obj, n_pad, scores)
+    line = {n: global_registry.counter(n) - before[n] for n in names}
+    line.update(device=jax.devices()[0].device_kind, n_pad=n_pad,
+                bits_differ=got["differ"])
+    for name in ("old", "new"):
+        fn = got[name]
+        for _ in range(3):
+            out = fn(scores, None)
+        jax.block_until_ready(out)
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(scores, None)
+        jax.block_until_ready(out)
+        line[f"{name}_ms"] = (time.perf_counter() - t0) / calls * 1e3
+    print(json.dumps(line), flush=True)
 
 
 def _scan_operands(N, F, B, has_missing, seed=41, rows_a_leaf=4000):
@@ -458,6 +566,8 @@ def run_wide_checks(n=400_384, F=2000, B=63, slot_counts=(1, 8, 128),
 if __name__ == "__main__":
     if "--score-lookup" in sys.argv[1:]:
         time_score_lookup()
+    elif "--rank-gradients" in sys.argv[1:]:
+        time_rank_gradients()
     else:
         print(run_wide_checks() if "--wide" in sys.argv[1:]
               else run_checks())
