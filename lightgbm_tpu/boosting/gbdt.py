@@ -310,6 +310,20 @@ class GBDT:
                 binned = build_bundled(binned, plan)
                 log.info(f"EFB bundled {len(plan.group_idx)} features into "
                          f"{plan.num_groups} columns")
+        if self.bundle_plan is not None:
+            # what bundling takes off the kernel's feature axis and what
+            # the decode must move, counted where the plan is fixed
+            # (benchmarks' efb_bundle_ratio, efb_decode_roofline)
+            from ..observability import global_registry
+            bp = self.bundle_plan
+            for name, value in (
+                    ("efb_features", len(bp.group_idx)),
+                    ("efb_bundles", bp.num_groups),
+                    ("efb_member_bins", sum(
+                        train_data.bin_mappers[f].num_bin
+                        for f in train_data.used_features)),
+                    ("efb_bundle_bins", int(bp.group_num_bin.sum()))):
+                global_registry.inc(name, value)
         dtype = np.uint8 if (binned.max() if self.bundle_plan else
                              train_data.max_num_bin - 1) <= 255 else np.int32
         self._n_device_cols = binned.shape[0]
@@ -452,8 +466,13 @@ class GBDT:
             in_bundle=None if bp is None else jnp.asarray(bp.in_bundle))
 
         max_b = int(self.f_num_bin.max()) if len(nb) else 1
+        # the shape the histogram kernel runs and the per-leaf stack
+        # holds: device columns x their bins, which under bundles is not
+        # features x max_bin (700 x 63 features in 13 columns of 255)
+        hist_b = max_b if bp is None else int(bp.group_num_bin.max())
         # histogram stack memory guard (HistogramPool analogue)
-        stack_bytes = config.num_leaves * len(nb) * max_b * 2 * 4
+        stack_bytes = (config.num_leaves * self._n_device_cols * hist_b
+                       * 2 * 4)
         budget = (config.histogram_pool_size * 1024 * 1024
                   if config.histogram_pool_size > 0 else 512 * 1024 * 1024)
         self.grow_params = GrowParams(
@@ -483,8 +502,7 @@ class GBDT:
                 cegb_penalty_split=config.cegb_penalty_split,
                 has_cegb_lazy=has_lazy),
             has_bundles=bp is not None,
-            group_max_bin=(0 if bp is None
-                           else int(bp.group_num_bin.max())),
+            group_max_bin=0 if bp is None else hist_b,
             feature_fraction_bynode=config.feature_fraction_bynode,
             bynode_seed=config.feature_fraction_seed + 1,
             monotone_intermediate=self._mono_intermediate,
@@ -602,8 +620,9 @@ class GBDT:
         plan = plan_growth(
             backend=jax.default_backend(),
             strategy=config.tpu_growth_strategy,
-            num_leaves=config.num_leaves, num_features=len(nb),
-            max_bin=max_b, gpu_use_dp=config.gpu_use_dp,
+            num_leaves=config.num_leaves,
+            num_features=self._n_device_cols, max_bin=hist_b,
+            gpu_use_dp=config.gpu_use_dp,
             pinned_leafwise=(self.grow_params.voting is not None
                              or self.grow_params.monotone_intermediate
                              or self.grow_params.split.has_cegb_lazy),

@@ -124,6 +124,14 @@ def plan_bundles_from_masks(nz, nbins: np.ndarray, zb: np.ndarray,
             group_bins[ng] = 1 + int(nbins[f])
             group_cnt[ng] = nz_cnt[f]
 
+    return plan_of_groups(groups, nbins, zb)
+
+
+def plan_of_groups(groups: List[List[int]], nbins: np.ndarray,
+                   zb: np.ndarray) -> BundlePlan:
+    """The plan that lays `groups` out: a bundle's members take disjoint
+    code ranges from 1 up, a column's only member keeps its own bins."""
+    F = len(nbins)
     group_idx = np.zeros(F, np.int32)
     offsets = np.zeros(F, np.int32)
     in_bundle = np.zeros(F, bool)

@@ -205,9 +205,28 @@ def bundle_hist_to_features(hist_g, sum_g, sum_h, meta: "FeatureMeta",
     """[F_groups, B', 2] group hist -> [F, B, 2] per-feature hist under
     EFB: each member's code range is sliced out and its default bin is
     recovered by subtraction from the leaf totals
-    (ref: dataset.h:759 FixHistogram).  No-op without bundles."""
+    (ref: dataset.h:759 FixHistogram).  No-op without bundles.
+
+    `hist_g` may carry a leading leaf axis ([N, F_groups, B', 2] with
+    `sum_g`, `sum_h` [N]: the wave engine's scan of a wave's leaves).
+    The decode runs under the device scope `Efb::decode`, a PART of the
+    caller's `Tree::split_find` (benchmarks' efb_decode_ms); the leaf
+    axis is mapped INSIDE the scope, since a scope entered under `vmap`
+    reaches the ops' names as `vmap(Efb.decode)`, which no reader of
+    parts matches."""
     if not has_bundles:
         return hist_g
+    def one(h, sg, sh):
+        return _bundle_hist_to_features(h, sg, sh, meta, B, hist_B)
+
+    with global_timer.device_scope("Efb::decode"):
+        if hist_g.ndim == 4:
+            return jax.vmap(one)(hist_g, sum_g, sum_h)
+        return one(hist_g, sum_g, sum_h)
+
+
+def _bundle_hist_to_features(hist_g, sum_g, sum_h, meta: "FeatureMeta",
+                             B: int, hist_B: int):
     cols = meta.offset[:, None] + jnp.arange(B, dtype=jnp.int32)[None, :]
     valid = ((jnp.arange(B, dtype=jnp.int32)[None, :]
               < meta.num_bin[:, None])
@@ -538,9 +557,12 @@ def grow_tree_impl(binned: jnp.ndarray, grad: jnp.ndarray,
         Under EFB, fbins are BUNDLE codes: decode the feature's range,
         anything else means the feature sits at its default bin."""
         if params.has_bundles:
-            local = fbins - meta.offset[feat]
-            fbins = jnp.where((local >= 0) & (local < meta.num_bin[feat]),
-                              local, meta.zero_bin[feat])
+            # a part of the caller's Tree::partition, as in wave.py
+            with global_timer.device_scope("Efb::route"):
+                local = fbins - meta.offset[feat]
+                fbins = jnp.where(
+                    (local >= 0) & (local < meta.num_bin[feat]),
+                    local, meta.zero_bin[feat])
         mt_f = meta.missing_type[feat]
         is_missing = (((mt_f == MISSING_NAN) & (fbins == meta.num_bin[feat] - 1))
                       | ((mt_f == MISSING_ZERO) & (fbins == meta.default_bin[feat])))

@@ -31,7 +31,10 @@ def plan_growth(*, backend: str, strategy: str, num_leaves: int,
     `strategy` is `tpu_growth_strategy` (auto / wave / leafwise, checked
     by the caller); `pinned_leafwise` names the modes that recompute
     global state after every split (below); `row_mesh` is a mesh that
-    shards rows, `voting` the PV-Tree learner on it."""
+    shards rows, `voting` the PV-Tree learner on it.  `num_features` and
+    `max_bin` are the shape the histogram kernel runs: device columns
+    and their bins, which under EFB are bundle columns and the largest
+    column's codes, not features and `max_bin`."""
     # Fused Pallas one-hot kernel on TPU (one-hot tiles live only in
     # VMEM, like the CUDA shared-memory histogram kernels); XLA's
     # scatter path wins on CPU.  Both accumulate fp32; gpu_use_dp

@@ -41,6 +41,7 @@ approximation for parity (feature_histogram.hpp:871-874).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 
@@ -247,8 +248,6 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
             return u >= kth
 
     def _best_one(h, sg, sh, c, po, cmin, cmax, dep, rb, rcu, used, bym):
-        h = bundle_hist_to_features(h, sg, sh, meta, B, hist_B,
-                                    params.has_bundles)
         kw = {}
         if sp.has_monotone:
             kw.update(monotone=meta.monotone, constraint_min=cmin,
@@ -281,8 +280,14 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
     def best_of(rows, sg, sh, c, po, cmin, cmax, dep, rb, rcu, used, bym):
         """Best split of each leaf whose cache row is in `rows` [N, Dh]."""
         if not dense_scan:
-            return best_vm(rows.reshape(-1, Fh, hist_B, 2), sg, sh, c, po,
-                           cmin, cmax, dep, rb, rcu, used, bym)
+            # bundle columns -> per-feature bins for all of `rows` first
+            # (its own part, Efb::decode, of the caller's scope), then
+            # the scan a leaf: the ops `vmap` made of both as one
+            hists = bundle_hist_to_features(
+                rows.reshape(-1, Fh, hist_B, 2), sg, sh, meta, B, hist_B,
+                params.has_bundles)
+            return best_vm(hists, sg, sh, c, po, cmin, cmax, dep, rb, rcu,
+                           used, bym)
         kw = {}
         if sp.extra_trees:
             kw["rand_bin"] = rb
@@ -749,14 +754,20 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
                 col_r = grp_r
             else:
                 col_r = feat_r
-            # per-row bin of the row's split column (one-hot select over F')
-            fbin = jnp.sum(jnp.where(
-                col_r[None, :] == jnp.arange(binned.shape[0],
-                                             dtype=i32)[:, None],
-                binned.astype(i32), 0), axis=0)
-            if params.has_bundles:
-                local = fbin - off_r
-                fbin = jnp.where((local >= 0) & (local < nb_r), local, zb_r)
+            # per-row bin of the row's split column (one-hot select over
+            # F'); under EFB the select is over bundle columns and the
+            # code is decoded to the feature's bin: Efb::route, a part of
+            # this scope (benchmarks' efb_route_ms)
+            with (global_timer.device_scope("Efb::route")
+                  if params.has_bundles else contextlib.nullcontext()):
+                fbin = jnp.sum(jnp.where(
+                    col_r[None, :] == jnp.arange(binned.shape[0],
+                                                 dtype=i32)[:, None],
+                    binned.astype(i32), 0), axis=0)
+                if params.has_bundles:
+                    local = fbin - off_r
+                    fbin = jnp.where((local >= 0) & (local < nb_r), local,
+                                     zb_r)
             is_missing = (((mt_r == MISSING_NAN) & (fbin == nb_r - 1))
                           | ((mt_r == MISSING_ZERO) & (fbin == db_r)))
             go_left = jnp.where(is_missing, dleft_r, fbin <= thr_r)

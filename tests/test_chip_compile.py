@@ -191,6 +191,43 @@ def test_wide_grow_program_compiles_on_one_chip(one_chip):
     assert mem.temp_size_in_bytes < int(2_027_847_168 * 1.1), mem
 
 
+def test_bundled_grow_program_compiles_at_the_one_hot_shape(one_chip):
+    """The whole-tree program at the one-hot cell's shape
+    (`expo-onehot700-b63.train_sparse`): 11,000,832 padded rows in 12
+    uint8 bundle columns of up to 255 codes, 504 used features at 63
+    bins, 255 leaves.  The kernels run the columns' shape (both of
+    them: 12 columns is one block for `_hl` too), the bundle decode and
+    the recolour's column select carry their parts, and the temporaries
+    are 4,176,885,248 B (380 B a row) plus 10%; PERF.md section 4 has
+    what the chip run reserved beside its buffers."""
+    from lightgbm_tpu.learner import FeatureMeta
+    from lightgbm_tpu.learner.wave import grow_tree_wave
+    cols, used, rows, codes = 12, 504, 11_000_832, 255
+    by_feature = {k: _sds((used,), "int32", one_chip)
+                  for k in ("num_bin", "missing_type", "default_bin",
+                            "group", "offset", "zero_bin")}
+    meta = FeatureMeta(penalty=_sds((used,), "float32", one_chip),
+                       in_bundle=_sds((used,), "bool", one_chip),
+                       **by_feature)
+    row = _sds((rows,), "float32", one_chip)
+    compiled = grow_tree_wave.lower(
+        _sds((cols, rows), "uint8", one_chip), row, row, row,
+        _sds((used,), "bool", one_chip), meta,
+        params=_grow_params(max_bin=63, has_bundles=True,
+                            group_max_bin=codes)).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"^\s*%([\w.\-]+) = .*custom_call_target="
+                       r'"tpu_custom_call"', text, re.M)
+    assert len(calls) >= 9
+    assert {re.sub(r"\.\d+$", "", c) for c in calls} == {
+        "build_histogram_wave", "build_histogram_wave_hl"}
+    for part in ("/Tree.split_find/Efb.decode/", "/Tree.partition/Efb.route/"):
+        assert part in text, part
+    assert "vmap(Efb." not in text      # no reader of parts matches that
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < int(4_176_885_248 * 1.1), mem
+
+
 def test_bucketize_program_compiles_at_the_wide_shape(one_chip):
     """`io/device_bin.py` at 458,752 padded rows x 2,000 features: the
     float matrix (3.67 GB), its transposed copy and the bins fit the

@@ -20,6 +20,10 @@ from lightgbm_tpu.ops.histogram import (plan_wave_kernel, spike_true_slots,
                                         wave_slot_pad)
 
 HIGGS_255, HIGGS_63, EPSILON_63 = (28, 255), (28, 63), (2000, 63)
+# the one-hot cell under EFB: device columns x the largest column's codes
+# (700 features at 63 bins in 12 columns on the chip, 13 in the issue's
+# scratch run), which is the shape the kernel runs
+EXPO_BUNDLED, EXPO_BUNDLED_13 = (12, 255), (13, 255)
 LADDER = (1, 2, 4, 8, 16, 32, 64, 128)      # true slots of a 255-leaf tree
 
 
@@ -38,6 +42,9 @@ def _ladder_plan(shape, true_slots, **kw):
     (HIGGS_63, {1: (16, 4), 2: (16, 4)}),
     # Epsilon: never `_hl` (its ungrouped blocks want 116 MB at one slot)
     (EPSILON_63, {}),
+    # bundle columns at 255 codes: Higgs-255's splits on fewer columns
+    (EXPO_BUNDLED, {1: (32, 8), 2: (32, 8), 4: (64, 4), 8: (64, 4)}),
+    (EXPO_BUNDLED_13, {1: (32, 8), 2: (32, 8), 4: (64, 4), 8: (64, 4)}),
 ])
 def test_ladder_kernels_are_the_ledgers(shape, hl_splits):
     for ts in LADDER:
@@ -63,6 +70,18 @@ def test_higgs_full_kernel_is_one_unpadded_block(shape, at_256_slots):
     plan = plan_wave_kernel(*shape, wave_slot_pad(255))
     assert (plan.feature_pad, plan.feature_group,
             plan.groups) == at_256_slots
+
+
+@pytest.mark.parametrize("shape", [EXPO_BUNDLED, EXPO_BUNDLED_13])
+def test_bundle_columns_are_one_unpadded_block(shape):
+    """12 or 13 columns are neither a multiple of 8 nor Higgs' 28: every
+    call of a 255-leaf tree, the chain tail's 256-slot one too, is the
+    full kernel's one block over the unpadded columns."""
+    for slots in LADDER + (255,):
+        plan = plan_wave_kernel(*shape, wave_slot_pad(slots))
+        assert (plan.kernel, plan.feature_pad, plan.feature_group,
+                plan.groups, plan.fits) == ("wave", shape[0], shape[0], 1,
+                                            True)
 
 
 @pytest.mark.parametrize("slots,group,groups", [
@@ -142,7 +161,8 @@ def _growth(shape=HIGGS_255, **kw):
 
 @pytest.mark.parametrize("shape,row_mesh", [
     (HIGGS_255, False), (HIGGS_63, False), (EPSILON_63, False),
-    (HIGGS_255, True)])     # the four configurations; dp4 shards rows
+    (HIGGS_255, True),      # the four dense configurations; dp4 shards rows
+    (EXPO_BUNDLED, False), (EXPO_BUNDLED_13, False)])
 def test_a_tpu_takes_wave_and_pallas_for_every_configuration(shape,
                                                              row_mesh):
     assert _growth(shape, row_mesh=row_mesh) == (
@@ -206,3 +226,50 @@ def test_a_cpu_booster_reports_the_plans_choice(extra):
     assert (g.growth_strategy, g.grow_params.hist_method) == plan[:2]
     assert g.growth_strategy == (
         "wave" if extra == {"tpu_growth_strategy": "wave"} else "leafwise")
+
+
+# --------------------------- (e) the shape a booster asks the plan about
+def _asked_shape(monkeypatch, X, **params):
+    """(num_features, max_bin) a booster over `X` hands `plan_growth`,
+    and the booster."""
+    from lightgbm_tpu.boosting import gbdt
+    asked = {}
+
+    def spy(**kw):
+        asked.update(kw)
+        return plan_growth(**kw)
+    monkeypatch.setattr(gbdt, "plan_growth", spy)
+    y = (np.arange(X.shape[0]) % 3 == 0).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 15, "max_bin": 63,
+              "min_data_in_leaf": 5, "verbosity": -1, **params}
+    g = lgb.Booster(params=params,
+                    train_set=lgb.Dataset(X, label=y, params=params))._gbdt
+    return (asked["num_features"], asked["max_bin"]), g
+
+
+def test_without_bundles_the_plan_is_asked_about_features_x_max_bin(
+        monkeypatch):
+    """Every dense cell: device columns are the used features and the
+    kernel's bins the booster's `max_bin`, as before the plan was asked
+    about the kernel's own shape."""
+    X = np.random.RandomState(0).rand(600, 6).astype(np.float32)
+    shape, g = _asked_shape(monkeypatch, X)
+    assert g.bundle_plan is None and not g.grow_params.has_bundles
+    assert shape == (6, g.grow_params.max_bin) == (
+        len(g.f_num_bin), int(g.f_num_bin.max()))
+    assert g.binned_dev.shape[0] == 6
+
+
+def test_under_bundles_the_plan_is_asked_about_the_kernels_shape(
+        monkeypatch):
+    """40 exclusive one-hot columns at 63 bins: one device column of 81
+    codes, and that — not 40 x 2 — is what the kernel plan is asked."""
+    from scipy import sparse
+    n, F = 800, 40
+    X = sparse.csr_matrix((np.ones(n, np.float32),
+                           (np.arange(n), np.arange(n) % F)), shape=(n, F))
+    shape, g = _asked_shape(monkeypatch, X)
+    assert g.grow_params.has_bundles
+    assert shape == (g.binned_dev.shape[0], g.grow_params.group_max_bin)
+    assert shape == (1, 2 * F + 1) and len(g.f_num_bin) == F
+    assert g.grow_params.max_bin == 2

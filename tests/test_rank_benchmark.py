@@ -396,10 +396,14 @@ def test_manifest_gives_the_cell_its_seven_metrics():
         "rank_scatter_ms", "rank_grad_roofline", "rank_pad_ratio",
         "rank_plan_s"]
     assert {m["layer"] for m in mine} == {"objective"}
-    assert manifest["per_layer"][-7:] == mine
-    assert manifest["workloads"][-1]["name"] == "mslr-2270k-b63.train_rank"
-    assert manifest["configs"][-1]["reduced"] == []
-    with open(os.path.join(ROOT, manifest["configs"][-1]["file"])) as f:
+    at = manifest["per_layer"].index(mine[0])     # entries are appended,
+    assert manifest["per_layer"][at:at + 7] == mine    # so later PRs' follow
+    assert [w["name"] for w in manifest["workloads"]].count(
+        "mslr-2270k-b63.train_rank") == 1
+    entry = [c for c in manifest["configs"]
+             if c["name"] == "mslr-2270k-b63"][0]
+    assert entry["reduced"] == []
+    with open(os.path.join(ROOT, entry["file"])) as f:
         config = json.load(f)
     assert (config["rows"], config["features"], config["queries"]) == (
         MSLR_ROWS, MSLR_FEATURES, 18919)

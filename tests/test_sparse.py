@@ -176,3 +176,42 @@ def test_sparse_categorical_matches_dense_path():
                      num_boost_round=6)
     assert b_sp.model_to_string() == b_dn.model_to_string()
     np.testing.assert_array_equal(b_sp.predict(m), b_dn.predict(X))
+
+
+def test_bundles_are_held_to_every_row_not_the_sample():
+    """Two rare columns that never meet among the planner's 50,000
+    sampled rows but do meet elsewhere: the sample alone would bundle
+    them and the rows where they meet would keep the later one's code;
+    held to every row (conflict rate 0) they take separate columns and
+    no row loses a code."""
+    import scipy.sparse as sp
+    from lightgbm_tpu.io.bundle import _SAMPLE
+    from lightgbm_tpu.io.sparse import _hold_to_all_rows
+    from lightgbm_tpu.observability import global_registry
+    n = _SAMPLE + 20_000
+    sampled = np.zeros(n, bool)
+    sampled[np.random.RandomState(3).choice(n, _SAMPLE, False)] = True
+    unsampled, drawn = np.flatnonzero(~sampled), np.flatnonzero(sampled)
+    a_rows = np.concatenate([drawn[:200], unsampled[:150]])
+    b_rows = np.concatenate([drawn[200:400], unsampled[100:250]])
+    c_rows = drawn[400:700]                      # exclusive everywhere
+    rows = np.concatenate([a_rows, b_rows, c_rows])
+    cols = np.repeat([0, 1, 2], [len(a_rows), len(b_rows), len(c_rows)])
+    X = sp.csr_matrix((np.ones(len(rows), np.float32), (rows, cols)),
+                      shape=(n, 3))
+    y = np.zeros(n, np.float32)
+    y[a_rows] = 1
+    before = global_registry.counter("efb_conflict_rows")
+    core = lgb.Dataset(X, label=y, params={"verbosity": -1})._core_or_construct()
+    assert global_registry.counter("efb_conflict_rows") == before
+    groups = sorted(sorted(g) for g in core.pre_bundled_plan.groups)
+    assert groups == [[0, 2], [1]]
+    # the 50 rows that hold both a and b keep both codes
+    both = np.intersect1d(a_rows, b_rows)
+    assert len(both) == 50
+    assert np.all(np.asarray(core.binned)[:, both] != 0)
+    # the unit: a cap of 50 rows lets the pair stay together
+    nz = [np.sort(a_rows), np.sort(b_rows), np.sort(c_rows)]
+    assert _hold_to_all_rows([[0, 1, 2]], nz, n, 0) == [[0, 2], [1]]
+    assert _hold_to_all_rows([[0, 1, 2]], nz, n, 50) == [[0, 1, 2]]
+    assert _hold_to_all_rows([[0, 1], [2]], nz, n, 49) == [[0], [2], [1]]

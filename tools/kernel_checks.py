@@ -33,6 +33,14 @@ Checks (small shapes, seconds of chip time):
      `jnp.take`, `argsort` + `take_along_axis`, `.at[].add`), bit for
      bit, on 512 queries of 1 to 1,251 documents with tied scores
 
+  8. the EFB decode of a bundle-column histogram
+     (`learner/grow.py bundle_hist_to_features`, under `vmap` as the wave
+     engine's scan calls it) == NumPy's slice of each member's code range
+     and subtraction from the leaf totals, bit for bit, on the one-hot
+     cell's plan (12 columns of up to 255 codes, 504 features at 63
+     bins); histogram entries are whole numbers, so no order of
+     summation can move a bit
+
 `run_wide_checks()` (`python tools/kernel_checks.py --wide`; a minute of
 chip time, so not part of `run_checks`) holds the fused wave kernel to a
 plain float32 reference at the widest benchmark cell's own shape,
@@ -196,7 +204,86 @@ def run_checks():
         traceback.print_exc()
         failures.append(f"rank_gradients_raised({type(e).__name__})")
 
+    # 8. the EFB decode vs NumPy's slice-and-subtract, bit for bit
+    try:
+        failures.extend(_efb_decode_mismatches())
+    except Exception as e:    # noqa: BLE001 - named in the verdict
+        traceback.print_exc()
+        failures.append(f"efb_decode_raised({type(e).__name__})")
+
     return "ok" if not failures else "fail:" + ",".join(failures)
+
+
+# the one-hot cell's bundle columns: codes a column, and the bins of each
+# of its members (one 63-bin numeric column alone; 2-bin one-hot members)
+EFB_COLUMNS = ((63, (63,)), (63, (63,)), (45, (2,) * 22), (15, (2,) * 7),
+               (25, (2,) * 12), (255, (2,) * 127), (255, (2,) * 127),
+               (63, (2,) * 31), (195, (2,) * 97), (115, (2,) * 57),
+               (43, (2,) * 21), (2, (2,)))
+
+
+def _efb_plan(columns=EFB_COLUMNS):
+    """(group, offset, zero_bin, in_bundle, num_bin) [F] as `io/bundle.py`
+    lays a plan out: a member's codes start at its offset (1 for the
+    first: code 0 is "every member at its default"), a column's only
+    member keeps its own bins (offset 0)."""
+    group, offset, in_bundle, num_bin = [], [], [], []
+    for gi, (_, members) in enumerate(columns):
+        off = 1
+        for nb in members:
+            group.append(gi)
+            offset.append(off if len(members) > 1 else 0)
+            in_bundle.append(len(members) > 1)
+            num_bin.append(nb)
+            off += nb
+    F = len(group)
+    return (np.array(group, np.int32), np.array(offset, np.int32),
+            np.zeros(F, np.int32), np.array(in_bundle, bool),
+            np.array(num_bin, np.int32))
+
+
+def _efb_decode_host(hist_g, sum_g, sum_h, plan, B):
+    """One leaf's [G, hist_B, 2] -> [F, B, 2], a feature at a time."""
+    group, offset, zero_bin, in_bundle, num_bin = plan
+    out = np.zeros((len(group), B, 2), np.float32)
+    total = np.array([sum_g, sum_h], np.float32)
+    for f in range(len(group)):
+        nb = int(num_bin[f])
+        out[f, :nb] = hist_g[group[f], offset[f]:offset[f] + nb]
+        if in_bundle[f]:
+            rest = np.delete(out[f], zero_bin[f], axis=0).sum(
+                0, dtype=np.float32)
+            out[f, zero_bin[f]] = total - rest
+    return out
+
+
+def _efb_decode_mismatches(leaves=8, B=63, seed=36):
+    import jax.numpy as jnp
+    from lightgbm_tpu.learner import FeatureMeta
+    from lightgbm_tpu.learner.grow import bundle_hist_to_features
+    plan = _efb_plan()
+    group, offset, zero_bin, in_bundle, num_bin = plan
+    hist_B = max(c for c, _ in EFB_COLUMNS)
+    rs = np.random.RandomState(seed)
+    hist = rs.randint(-999, 1000, (leaves, len(EFB_COLUMNS), hist_B, 2)
+                      ).astype(np.float32)
+    sums = rs.randint(-99999, 100000, (2, leaves)).astype(np.float32)
+    zeros = jnp.zeros(len(group), jnp.int32)
+    meta = FeatureMeta(
+        num_bin=jnp.asarray(num_bin), missing_type=zeros, default_bin=zeros,
+        penalty=jnp.ones(len(group), jnp.float32),
+        group=jnp.asarray(group), offset=jnp.asarray(offset),
+        zero_bin=jnp.asarray(zero_bin), in_bundle=jnp.asarray(in_bundle))
+    got = np.asarray(bundle_hist_to_features(
+        jnp.asarray(hist), jnp.asarray(sums[0]), jnp.asarray(sums[1]), meta,
+        B, hist_B, True))
+    want = np.stack([_efb_decode_host(hist[i], sums[0, i], sums[1, i], plan,
+                                      B) for i in range(leaves)])
+    # a bin outside a member's range is the gathered entry times 0.0,
+    # which keeps the entry's sign: -0.0 + 0.0 is +0.0
+    differ = int(np.sum((got + np.float32(0)).view(np.uint32)
+                        != want.view(np.uint32)))
+    return [f"efb_decode_differs_in_{differ}"] if differ else []
 
 
 def _rank_lengths(queries, seed):
