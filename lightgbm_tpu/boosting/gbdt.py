@@ -26,6 +26,7 @@ from ..learner import (FeatureMeta, GrowParams, grow_tree,
                        grow_tree_wave_donated, plan_growth)
 from ..models.tree import Tree
 from ..objective import ObjectiveFunction
+from ..ops.histogram import class_ordered, hist_classes_of
 from ..ops.split import SplitParams
 from ..metric import Metric
 from ..observability import (first_iter_compile_phases,
@@ -451,6 +452,13 @@ class GBDT:
             np.zeros((len(nb), self.n_pad), bool), axis=1)
             if has_lazy else None)
         bp = self.bundle_plan
+        # the device columns' histogram classes: the multiset is static
+        # in the grow program, which column holds which is data
+        # (ops/histogram.py hist_classes_of)
+        hist_classes, hist_order = hist_classes_of(
+            self.f_num_bin if bp is None else bp.group_num_bin)
+        one_class = len(hist_classes) < 2
+        _metrics.set_gauge("hist_classes", len(hist_classes))
         self.meta = FeatureMeta(
             num_bin=jnp.asarray(self.f_num_bin),
             missing_type=jnp.asarray(self.f_missing_type),
@@ -463,7 +471,10 @@ class GBDT:
             group=None if bp is None else jnp.asarray(bp.group_idx),
             offset=None if bp is None else jnp.asarray(bp.offsets),
             zero_bin=None if bp is None else jnp.asarray(bp.zero_bin),
-            in_bundle=None if bp is None else jnp.asarray(bp.in_bundle))
+            in_bundle=None if bp is None else jnp.asarray(bp.in_bundle),
+            hist_order=None if one_class else jnp.asarray(hist_order),
+            hist_inverse=(None if one_class else
+                          jnp.asarray(np.argsort(hist_order), jnp.int32)))
 
         max_b = int(self.f_num_bin.max()) if len(nb) else 1
         # the shape the histogram kernel runs and the per-leaf stack
@@ -503,6 +514,7 @@ class GBDT:
                 has_cegb_lazy=has_lazy),
             has_bundles=bp is not None,
             group_max_bin=0 if bp is None else hist_b,
+            hist_classes=() if one_class else hist_classes,
             feature_fraction_bynode=config.feature_fraction_bynode,
             bynode_seed=config.feature_fraction_seed + 1,
             monotone_intermediate=self._mono_intermediate,
@@ -666,6 +678,22 @@ class GBDT:
         # into the metrics registry.
         from ..observability import RecompileDetector
         self._grow_fn = RecompileDetector(self._grow_fn, "grow_tree")
+        # several histogram classes: the wave kernel reads the bins in
+        # class order — one more copy of them, made here once a booster
+        # (the int8 arm keeps one class and the engine's own order)
+        self._classed_kw = {}
+        if (plan.strategy == "wave" and plan.hist_method == "pallas"
+                and self.grow_params.hist_classes
+                and not self.grow_params.quant_bins):
+            # (under a mesh, sharded by rows like the bins; on one device
+            # left uncommitted like them: one committed argument would
+            # commit every later tree's gradients and lower the grow
+            # program a second time at the second iteration)
+            sharded = ({} if self.mesh is None else
+                       {"out_shardings": self.binned_dev.sharding})
+            self._classed_kw["binned_classed"] = jax.jit(
+                class_ordered, **sharded)(self.binned_dev,
+                                          self.meta.hist_order)
 
         # scores [K, n_pad] on device
         K = self.num_tree_per_iteration
@@ -1265,6 +1293,7 @@ class GBDT:
                             + k)
                     if self._lazy_used is not None:
                         grow_kw["lazy_used"] = self._lazy_used
+                    grow_kw.update(self._classed_kw)
                     if (qscales is not None
                             and self.growth_strategy == "wave"
                             and self.grow_params.quant_bins > 0):

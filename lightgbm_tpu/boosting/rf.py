@@ -93,6 +93,7 @@ class RF(GBDT):
                     grow_kw["cegb_used"] = self._cegb_used
                 if self._lazy_used is not None:
                     grow_kw["lazy_used"] = self._lazy_used
+                grow_kw.update(self._classed_kw)
                 out = self._grow_fn(
                     self.binned_dev, self._slice_row_fn(grad, k),
                     self._slice_row_fn(hess, k), bag_mask,

@@ -65,6 +65,11 @@ class FeatureMeta(NamedTuple):
     offset: jnp.ndarray = None      # [F] int32 code offset (0 singleton)
     zero_bin: jnp.ndarray = None    # [F] int32 default bin
     in_bundle: jnp.ndarray = None   # [F] bool
+    # the device columns sorted by histogram class and the inverse of
+    # that order (int32 [device columns]; None with one class): WHICH
+    # column holds which of `GrowParams.hist_classes` is data
+    hist_order: jnp.ndarray = None
+    hist_inverse: jnp.ndarray = None
 
 
 class GrowParams(NamedTuple):
@@ -86,6 +91,13 @@ class GrowParams(NamedTuple):
     # space for the scan (gather + FixHistogram by subtraction)
     has_bundles: bool = False
     group_max_bin: int = 0
+    # the device columns' code counts as a sorted multiset of (class
+    # codes, columns) — ops/histogram.py hist_classes_of: with several
+    # classes the wave kernel builds each column's one-hot at its
+    # class's codes.  Never per column: the column order is
+    # `FeatureMeta.hist_order`, so one table in two column orders is one
+    # program.  () = every column at `max_bin` / `group_max_bin`
+    hist_classes: tuple = ()
     # forced splits (ref: serial_tree_learner.cpp:614 ForceSplits):
     # static BFS-ordered (leaf, inner_feature, threshold_bin) tuples
     # applied before best-gain growth; needs use_hist_stack

@@ -83,8 +83,12 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
                         meta: FeatureMeta, params: GrowParams,
                         cegb_used: jnp.ndarray = None,
                         extra_tag: jnp.ndarray = None,
-                        quant_scales: jnp.ndarray = None):
-    """Grow one tree by waves.  Same contract as grow.grow_tree."""
+                        quant_scales: jnp.ndarray = None,
+                        binned_classed: jnp.ndarray = None):
+    """Grow one tree by waves.  Same contract as grow.grow_tree, plus
+    `binned_classed`: `binned[meta.hist_order]` (ops/histogram.py
+    class_ordered), which a booster whose columns hold several
+    `params.hist_classes` makes once and hands to every tree."""
     from ..ops.split import MISSING_NAN, MISSING_ZERO
 
     if params.has_bundles:
@@ -146,13 +150,27 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
             # tpulint: disable-next=collective-discipline -- the wave engine's single histogram/count reduction point; parallel/data_parallel.py wraps this engine in shard_map and owns the data_axis contract
             return jax.lax.psum(x, params.data_axis)
 
+    # several classes of column codes, and their class-ordered copy
+    # handed over: the full kernel reads that and `wave_histograms` hands
+    # the histograms back in this engine's order; one class (and the
+    # int8 arm) is the call on `binned` itself
+    hist_classes = (params.hist_classes
+                    if use_pallas and not use_int8
+                    and binned_classed is not None
+                    and len(params.hist_classes) > 1 else ())
+    classed = (dict(hist_classes=hist_classes,
+                    binned_classed=binned_classed,
+                    hist_inverse=meta.hist_inverse)
+               if hist_classes else {})
     binned_rm = None
     # both of the plan's `wave_hl` gates only close as the slots grow:
     # where one slot is refused (2,000 features: its ungrouped blocks
     # count 116 MB of VMEM) no wave of this tree can use the row-major
     # copy, and it is not built (0.8 GB there)
-    if use_pallas and plan_wave_kernel(binned.shape[0], hist_B, 1, 1,
-                                       int8=use_int8).kernel == "wave_hl":
+    hl_at_one_slot = plan_wave_kernel(
+        binned.shape[0], hist_B, 1, 1, int8=use_int8,
+        hist_classes=hist_classes).kernel == "wave_hl"
+    if use_pallas and hl_at_one_slot:
         # row-major copy for the decomposed small-S kernel's lo side
         # (transposed once per tree; bins are static so XLA keeps it
         # resident for all waves of the tree)
@@ -174,7 +192,8 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
             if use_pallas:
                 H, cnt = wave_histograms(
                     binned, binned_rm, kslot, ghm, max_bin=hist_B,
-                    num_slots=num_slots, true_slots=true_slots, **quant)
+                    num_slots=num_slots, true_slots=true_slots, **quant,
+                    **classed)
             else:
                 H, cnt = _hist_wave_xla(binned, kslot, ghm, max_bin=hist_B,
                                         num_slots=num_slots)

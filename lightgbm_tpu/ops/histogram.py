@@ -30,10 +30,12 @@ data counts are derived downstream from hessian sums exactly as the reference do
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from ..observability.registry import global_registry
@@ -191,7 +193,7 @@ def build_histogram_rows_pallas(rows: jnp.ndarray, gh: jnp.ndarray,
     return out[:F, :max_bin, :]                       # [F, B, C]
 
 
-def _wave_kernel(C: int, Fg: int, Bg: int, NLg: int):
+def _wave_kernel(C: int, Fg: int, Bg: int, NLg: int, classes: tuple = ()):
     """Multi-leaf fused histogram kernel for wave (level-batched) growth.
 
     Per (slot-group, bin-group, feature-group, row-tile) grid cell, build
@@ -208,7 +210,14 @@ def _wave_kernel(C: int, Fg: int, Bg: int, NLg: int):
     early waves with few slots pay for 128 lanes regardless — C dots at
     NLg<=64 slots cost C times one fused dot.  (TPU replacement for the
     CUDA per-leaf shared-memory kernels,
-    ref: cuda_histogram_constructor.cu:18.)"""
+    ref: cuda_histogram_constructor.cu:18.)
+
+    With `classes` — runs `(codes, columns)` of the block's Fg columns,
+    as `plan_wave_kernel` cut them — each run's one-hot is built at its
+    own `codes` and the runs are stacked on the sublane axis (every
+    `codes` a whole number of bf16 sublane tiles): the dot's M is the
+    sum of the columns' class codes and not Fg * Bg, and the out block
+    is the flat [M, lanes] that sum gives."""
     def kernel(rows_ref, slot_ref, gh_ref, out_ref, cnt_ref):
         bg = pl.program_id(0)
         g = pl.program_id(1)
@@ -232,9 +241,20 @@ def _wave_kernel(C: int, Fg: int, Bg: int, NLg: int):
         rows = rows_ref[...].astype(jnp.int32) - bg * Bg  # [Fg, Rt]
         slot = slot_ref[...]                             # [1, Rt]
         Rt = rows.shape[1]
-        biota = jax.lax.broadcasted_iota(jnp.int32, (Fg, Bg, Rt), 1)
-        oh = (rows[:, None, :] == biota).astype(mxu_t)
-        oh2 = oh.reshape(Fg * Bg, Rt)
+        if classes:
+            runs, f0 = [], 0
+            for codes, cols in classes:
+                biota = jax.lax.broadcasted_iota(jnp.int32,
+                                                 (cols, codes, Rt), 1)
+                run = jax.lax.slice_in_dim(rows, f0, f0 + cols)
+                oh = (run[:, None, :] == biota).astype(mxu_t)
+                runs.append(oh.reshape(cols * codes, Rt))
+                f0 += cols
+            oh2 = jnp.concatenate(runs, axis=0)
+        else:
+            biota = jax.lax.broadcasted_iota(jnp.int32, (Fg, Bg, Rt), 1)
+            oh = (rows[:, None, :] == biota).astype(mxu_t)
+            oh2 = oh.reshape(Fg * Bg, Rt)
         lanes = (((1,), (1,)), ((), ()))     # contract both over rows
         S = out_ref.shape[-1] // (C * NLg)
         for s in range(S):  # slot groups REUSE the bin one-hot (its
@@ -258,7 +278,10 @@ def _wave_kernel(C: int, Fg: int, Bg: int, NLg: int):
             # lane dim stays flat (Mosaic cannot split the lane dim); the
             # caller unscrambles the (slot-group, channel, slot) layout
             w = C * NLg
-            out_ref[:, :, s * w:(s + 1) * w] += acc.reshape(Fg, Bg, w)
+            if classes:
+                out_ref[:, s * w:(s + 1) * w] += acc
+            else:
+                out_ref[:, :, s * w:(s + 1) * w] += acc.reshape(Fg, Bg, w)
             # exact per-slot row counts ride along as a [8, NLg] dot of the
             # mask row (gh[C]) against the slot one-hot — one cell only,
             # replacing a separate 20ms scatter-add pass
@@ -409,17 +432,39 @@ def hl_split_of(max_bin: int, num_slots: int, C: int):
 #   1,023 (18.0) do not; at 63 bins 2,047 (8.5) do and 4,095 (16.5) do
 #   not.  (`wave_pallas_vmem_ok` counted one slot group and was true for
 #   every shape.)
+# A CLASSED call (several `hist_classes`, PR 38) is counted by the
+# one-hot row and not by the feature: `_onehot_row_bytes` (the same
+# accumulator row + one-hot row, `unit / Bg`) times the rows its block
+# builds, `sum(class codes x columns)`.  Each of its class groups is a
+# `pallas_call` of its own with one block over its columns — a one-group
+# call as above, so its gate is `_FULL_F_VMEM`: the ranking cell's 7,392
+# rows count 15.1 MB at 128 slots and compile as one block (unclassed:
+# 144 padded columns in three groups), the one-hot cell's 1,200 count
+# 2.5 MB (tests/test_chip_compile.py compiles both to 255 slots).
 _SCOPED_VMEM = 16 << 20     # the compiler's limit for one kernel
 _FULL_F_VMEM = 16 << 20     # one full-F block when F * unit fits this
 _GROUP_VMEM = 6 << 20       # else feature groups of at most this
 
 
+def _bin_pad(max_bin: int) -> Tuple[int, int]:
+    """(Bp, Bg) of `build_histogram_wave`: the bins padded to the
+    8-sublane granule, in one bin group where 256 hold them (rows are
+    then streamed once per wave) and else in groups of 256."""
+    Bp = max(8, _round_up(max_bin, 8))
+    Bg = min(Bp, 256)
+    return _round_up(Bp, Bg), Bg
+
+
+def _onehot_row_bytes(num_slots: int, C: int = 2, row_tile: int = 512) -> int:
+    """VMEM bytes a one-hot row of a `build_histogram_wave` block costs:
+    its f32 accumulator row [S*C*NLg] and its bf16 one-hot row [Rt]."""
+    return wave_slot_pad(num_slots) * C * 4 + row_tile * 2
+
+
 def _wave_unit_bytes(max_bin: int, num_slots: int, C: int = 2,
                      row_tile: int = 512) -> int:
     """VMEM bytes a feature of a `build_histogram_wave` block costs."""
-    NLp = wave_slot_pad(num_slots)
-    Bg = min(max(8, (max_bin + 7) // 8 * 8), 256)
-    return Bg * (NLp * C * 4 + row_tile * 2)
+    return _bin_pad(max_bin)[1] * _onehot_row_bytes(num_slots, C, row_tile)
 
 
 def _pick_feature_group(Fp: int, unit_bytes: int, budget: int) -> int:
@@ -445,7 +490,9 @@ def wave_slot_pad(num_slots: int) -> int:
 # wt in f32, sc in bf16) and the f32 accumulator must fit together
 _HL_VMEM = 12 << 20
 # measured crossover of the decomposed kernel's materialized volume
-# against the full kernel's F*B *(old chip)*; re-tuning it is ROADMAP S1 (2)
+# against the full kernel's F*B *(old chip)* — against what the full
+# kernel really builds a column, which with several classes is their
+# mean and not `max_bin`; re-tuning it is ROADMAP S1 (2)
 _HL_CROSSOVER = 0.6
 # The spike waves (learner/wave.py) name their true slot count to the plan
 # only up to this many and take the full kernel past it, whatever the
@@ -461,61 +508,155 @@ def spike_true_slots(true_slots: int) -> Optional[int]:
     return true_slots if true_slots <= _HL_SPIKE_MAX_SLOTS else None
 
 
+# A column's class: its codes rounded up to whole bf16 sublane tiles (16
+# rows), so that the classes' one-hots stack in VMEM without a relayout.
+# Past 256 codes the kernel runs bin groups, which a classed call has
+# none of: such a table is one class.
+_CLASS_STEP = 16
+_CLASS_MAX_CODES = 256
+
+
+def hist_classes_of(num_bins) -> Tuple[tuple, np.ndarray]:
+    """(`hist_classes`, `hist_order`) of a table's device columns, from
+    their code counts `num_bins` [F] — on the host, once a booster.
+
+    `hist_classes` is what the program may know statically: the sorted
+    tuple of (class codes, columns of that class), a function of the
+    MULTISET of the columns' classes.  Which column holds which class
+    is `hist_order` (int32 [F]: a stable sort of the columns by class),
+    and that reaches the program as data — a table whose columns arrive
+    in another order runs the same program (PERF.md section 6, PR 38)."""
+    num_bins = np.asarray(num_bins, np.int64)
+    cls = np.maximum(_round_up(num_bins, _CLASS_STEP), _CLASS_STEP)
+    if cls.size and cls.max() > _CLASS_MAX_CODES:
+        cls[:] = cls.max()
+    codes, counts = np.unique(cls, return_counts=True)
+    return (tuple((int(c), int(k)) for c, k in zip(codes, counts)),
+            np.argsort(cls, kind="stable").astype(np.int32))
+
+
+def class_ordered(binned_fm: jnp.ndarray,
+                  hist_order: jnp.ndarray) -> jnp.ndarray:
+    """`binned_fm[hist_order]`: the bins with their columns in class
+    order, moved a column at a time.  (As one gather of whole rows XLA
+    cuts the copy into 32,512-element pieces, 339 of them at 11,000,832
+    rows: 15.6 s to compile for a v5e and a second to load, against 0.4 s
+    for this loop.)"""
+    def move(j, out):
+        column = jax.lax.dynamic_slice_in_dim(binned_fm, hist_order[j], 1)
+        return jax.lax.dynamic_update_slice_in_dim(out, column, j, axis=0)
+    return jax.lax.fori_loop(0, binned_fm.shape[0], move,
+                             jnp.zeros_like(binned_fm))
+
+
 class WaveKernelPlan(NamedTuple):
     """Which wave kernel a call takes and in what blocks: shapes in,
     nothing of the data."""
     kernel: str                 # "wave" | "wave_hl"
     feature_pad: int            # full kernel: F as its grid sees it (Fp)
     feature_group: int          # full kernel: features a block (Fg)
-    groups: int                 # full kernel: Fp // Fg passes over the rows
+    groups: int                 # full kernel: passes over the rows
     hl_split: Optional[Tuple[int, int]]   # decomposed kernel: (Bh, Bl)
     vmem_bytes: int             # of `kernel`, as its gate counted them
     fits: bool                  # the full kernel's smallest group compiles
+    onehot_rows: int            # full kernel: one-hot rows a row tile
+    # full kernel, several classes: a group's runs (codes, columns) of
+    # the class-ordered columns, a `pallas_call` a group; else ()
+    class_groups: tuple = ()
+
+
+def _class_groups(hist_classes: tuple, row_bytes: int, budget: int) -> tuple:
+    """The class-ordered columns cut into runs whose one-hot rows, at
+    `row_bytes` each, fit `budget`: each group a tuple of (codes,
+    columns).  A column past the budget alone stands alone (`fits` is
+    what says it cannot run)."""
+    room = budget // row_bytes
+    groups, rows = [[]], 0
+    for codes in (c for c, cols in hist_classes for _ in range(cols)):
+        if groups[-1] and rows + codes > room:
+            groups.append([])
+            rows = 0
+        groups[-1].append(codes)
+        rows += codes
+    return tuple(tuple((c, len(list(run))) for c, run in itertools.groupby(g))
+                 for g in groups)
 
 
 def plan_wave_kernel(num_features: int, max_bin: int, num_slots: int,
                      true_slots: Optional[int] = None, *,
                      int8: bool = False, C: int = 2,
-                     row_tile: int = 512) -> WaveKernelPlan:
+                     row_tile: int = 512,
+                     hist_classes: tuple = ()) -> WaveKernelPlan:
     """The one owner of "which histogram kernel does a wave run".
 
     `num_slots` is the padded computed-slot bound (the output's), and
     `true_slots` the unpadded one where the caller knows it: only then,
     and never for int8 operands, can the decomposed kernel take the wave
-    — when its materialized volume is meaningfully below the full
-    kernel's F*B and its ungrouped blocks fit VMEM.  Otherwise the full
-    kernel runs, as one full-F block where that fits (no padding of F to
-    the 8-sublane granule: 12.5% of one-hot volume and MXU rows at 28
-    features, and fewer grid cells) and else in feature groups.  `fits`
-    is false where even the smallest legal group (8 features, all
+    — when its materialized volume is meaningfully below what the full
+    kernel builds a column and its ungrouped blocks fit VMEM.  Otherwise
+    the full kernel runs, as one full-F block where that fits (no padding
+    of F to the 8-sublane granule: 12.5% of one-hot volume and MXU rows
+    at 28 features, and fewer grid cells) and else in feature groups.
+    `fits` is false where even the smallest legal group (8 features, all
     `num_slots` slot groups) is past the compiler's scoped VMEM: such a
-    booster takes the leaf-wise engine (learner/select.py)."""
+    booster takes the leaf-wise engine (learner/select.py).
+
+    `hist_classes` (`hist_classes_of`) is the table's sorted (codes,
+    columns) multiset.  One class, or none named, is the call at
+    `max_bin` for every column.  With several (never for int8 operands:
+    that arm keeps one class) the full kernel builds each column's
+    one-hot at its class's codes: VMEM is counted on the classed one-hot
+    — `sum(codes x columns) x _onehot_row_bytes` a block — a group is a
+    run of class-ordered columns within `_FULL_F_VMEM` (each its own
+    one-block call, so a column is never padded), and the decomposed
+    kernel is held against the classed volume a column, not `max_bin`."""
+    if int8 or len(hist_classes) < 2:
+        hist_classes = ()
     unit = _wave_unit_bytes(max_bin, num_slots, C, row_tile)
-    if num_features * unit <= _FULL_F_VMEM:
-        Fp = Fg = num_features
+    class_groups = ()
+    if hist_classes:
+        assert sum(k for _, k in hist_classes) == num_features
+        row_bytes = _onehot_row_bytes(num_slots, C, row_tile)
+        class_groups = _class_groups(hist_classes, row_bytes, _FULL_F_VMEM)
+        group_rows = [sum(c * k for c, k in g) for g in class_groups]
+        Fp, Fg = num_features, max(sum(k for _, k in g)
+                                   for g in class_groups)
+        groups, onehot_rows = len(class_groups), sum(group_rows)
+        full_vmem = max(group_rows) * row_bytes
     else:
-        # TPU block constraint: the binned block's second-to-last dim
-        # (Fg) must be a multiple of 8 OR the whole (unpadded) F
-        Fp = _round_up(num_features, 8)
-        # feature group bounded by the VMEM accumulator [Fg, Bg, S*C*NLg]
-        # plus the [Fg, Bg, Rt] bf16 one-hot
-        Fg = _pick_feature_group(Fp, unit, _GROUP_VMEM)
-    kernel, split, vmem = "wave", None, Fg * unit
+        if num_features * unit <= _FULL_F_VMEM:
+            Fp = Fg = num_features
+        else:
+            # TPU block constraint: the binned block's second-to-last dim
+            # (Fg) must be a multiple of 8 OR the whole (unpadded) F
+            Fp = _round_up(num_features, 8)
+            # feature group bounded by the VMEM accumulator
+            # [Fg, Bg, S*C*NLg] plus the [Fg, Bg, Rt] bf16 one-hot
+            Fg = _pick_feature_group(Fp, unit, _GROUP_VMEM)
+        groups, onehot_rows = Fp // Fg, Fp * _bin_pad(max_bin)[0]
+        full_vmem = Fg * unit
+    kernel, split, vmem = "wave", None, full_vmem
     if true_slots is not None:
         Bh, Bl = split = hl_split_of(max_bin, true_slots, C)
         CS = C * true_slots
         Wd = num_features * Bl * CS
         hl_vmem = (num_features * Bh * row_tile * 2 + row_tile * Wd * 10
                    + num_features * Bh * Bl * CS * 4)
+        # what the full kernel builds a column: `max_bin` with one
+        # class, the classed mean with several (100 codes at the one-hot
+        # cell's 12 columns, where `max_bin` is 255)
+        full_codes = (onehot_rows / num_features if hist_classes
+                      else max_bin)
         # Bh > 256 would overflow the feature-packed M dimension (and
         # such giant max_bin configs gain nothing from decomposition
         # anyway)
         if (not int8 and Bh <= 256
-                and Bh + Bl * CS <= _HL_CROSSOVER * max_bin
+                and Bh + Bl * CS <= _HL_CROSSOVER * full_codes
                 and hl_vmem <= _HL_VMEM):
             kernel, vmem = "wave_hl", hl_vmem
-    return WaveKernelPlan(kernel, Fp, Fg, Fp // Fg, split, vmem,
-                          8 * unit <= _SCOPED_VMEM)
+    return WaveKernelPlan(kernel, Fp, Fg, groups, split, vmem,
+                          8 * unit <= _SCOPED_VMEM, onehot_rows,
+                          class_groups)
 
 
 @functools.partial(jax.jit,
@@ -575,11 +716,12 @@ def build_histogram_wave_hl(binned_fm: jnp.ndarray, binned_rm: jnp.ndarray,
 
 @functools.partial(jax.jit,
                    static_argnames=("max_bin", "num_slots", "row_tile",
-                                    "quant_bins"))
+                                    "quant_bins", "hist_classes"))
 def build_histogram_wave(binned_fm: jnp.ndarray, slot: jnp.ndarray,
                          gh: jnp.ndarray, *, max_bin: int, num_slots: int,
                          row_tile: int = 512, quant_bins: int = 0,
-                         quant_scales: jnp.ndarray = None):
+                         quant_scales: jnp.ndarray = None,
+                         hist_classes: tuple = ()):
     """Histograms for all leaf slots in one fused pass over the rows.
 
     Grid = (bin groups, feature groups, row tiles); each cell builds the
@@ -609,6 +751,12 @@ def build_histogram_wave(binned_fm: jnp.ndarray, slot: jnp.ndarray,
         histograms through the MXU's 2x int8 path, dequantizing on the
         way out — the TPU analogue of the reference's int16/int32
         quantized histograms (dense_bin.hpp:174 ConstructHistogramIntInner).
+      hist_classes: `hist_classes_of`'s (codes, columns) multiset where
+        `binned_fm`'s columns are in CLASS ORDER (`hist_order`), each
+        column's codes below its class's: with several classes each
+        column's one-hot is built at its class's codes and the result's
+        columns are in class order too (`wave_histograms` brings them
+        back).  Not for int8 operands.
 
     Returns: (hist [NL, F, B, C] float32, counts [NL] float32).
     """
@@ -627,15 +775,13 @@ def build_histogram_wave(binned_fm: jnp.ndarray, slot: jnp.ndarray,
                  (gh[C:] > 0).astype(jnp.int32)], axis=0)
     NLp = wave_slot_pad(num_slots)
     NLg = min(NLp, 128)
-    Bp = max(8, (max_bin + 7) // 8 * 8)
     # one bin group when it fits: rows are then streamed once per wave
-    Bg = min(Bp, 256)
-    if Bp % Bg != 0:
-        Bp = (Bp + Bg - 1) // Bg * Bg
+    Bp, Bg = _bin_pad(max_bin)
     if n % row_tile != 0:
         raise ValueError(f"n {n} not a multiple of row_tile {row_tile}")
     S = NLp // NLg
-    plan = plan_wave_kernel(F, max_bin, num_slots, C=C, row_tile=row_tile)
+    plan = plan_wave_kernel(F, max_bin, num_slots, C=C, row_tile=row_tile,
+                            int8=use_int8, hist_classes=hist_classes)
     Fp, Fg = plan.feature_pad, plan.feature_group
     if Fp != F:
         with global_timer.device_scope("Tree::hist_operands"):
@@ -643,26 +789,66 @@ def build_histogram_wave(binned_fm: jnp.ndarray, slot: jnp.ndarray,
     acc_t = jnp.int32 if use_int8 else jnp.float32
     with global_timer.device_scope("Tree::hist_operands"):
         slot_row = slot.reshape(1, n)
-    _count_traced_call(Fp // Fg)
-    out, cnt = pl.pallas_call(
-        _wave_kernel(C, Fg, Bg, NLg),
-        grid=(Bp // Bg, Fp // Fg, n // row_tile),
-        in_specs=[
-            pl.BlockSpec((Fg, row_tile), lambda bg, g, i: (g, i)),
-            pl.BlockSpec((1, row_tile), lambda bg, g, i: (0, i)),
-            pl.BlockSpec((C + 1, row_tile), lambda bg, g, i: (0, i))],
-        out_specs=[
-            pl.BlockSpec((Fg, Bg, S * C * NLg),
-                         lambda bg, g, i: (g, bg, 0)),
-            pl.BlockSpec((8, NLp), lambda bg, g, i: (0, 0))],
-        out_shape=[
-            jax.ShapeDtypeStruct((Fp, Bp, S * C * NLg), acc_t),
-            jax.ShapeDtypeStruct((8, NLp), acc_t)],
-        name="build_histogram_wave",
-    )(binned_fm, slot_row, gh)
+    _count_traced_call(plan.groups)
+    global_registry.set_gauge("hist_onehot_rows", plan.onehot_rows)
+    global_registry.set_gauge("hist_onehot_rows_unclassed", Fp * Bp)
+    row_specs = [pl.BlockSpec((1, row_tile), lambda bg, g, i: (0, i)),
+                 pl.BlockSpec((C + 1, row_tile), lambda bg, g, i: (0, i))]
+    cnt_spec = pl.BlockSpec((8, NLp), lambda bg, g, i: (0, 0))
+    cnt_shape = jax.ShapeDtypeStruct((8, NLp), acc_t)
+    if plan.class_groups:
+        # a `pallas_call` a group, each ONE block over its run of the
+        # class-ordered columns (the one group of every cell's 1-128
+        # slot calls is the operand itself); every class's rows are
+        # cut or zero-padded to B on the way out
+        f0, parts = 0, []
+        for group in plan.class_groups:
+            Fk = sum(k for _, k in group)
+            Mk = sum(c * k for c, k in group)
+            cols = binned_fm
+            if Fk != F:
+                with global_timer.device_scope("Tree::hist_operands"):
+                    cols = binned_fm[f0:f0 + Fk]
+            out, cnt_k = pl.pallas_call(
+                _wave_kernel(C, Fk, Bg, NLg, group),
+                grid=(1, 1, n // row_tile),
+                in_specs=[pl.BlockSpec((Fk, row_tile),
+                                       lambda bg, g, i: (0, i))] + row_specs,
+                out_specs=[pl.BlockSpec((Mk, S * C * NLg),
+                                        lambda bg, g, i: (0, 0)), cnt_spec],
+                out_shape=[jax.ShapeDtypeStruct((Mk, S * C * NLg), acc_t),
+                           cnt_shape],
+                name="build_histogram_wave",
+            )(cols, slot_row, gh)
+            if f0 == 0:
+                cnt = cnt_k
+            r0 = 0
+            for codes, k in group:
+                part = out[r0:r0 + codes * k].reshape(k, codes, -1)
+                parts.append(
+                    part[:, :max_bin] if codes >= max_bin else
+                    jnp.pad(part, ((0, 0), (0, max_bin - codes), (0, 0))))
+                r0 += codes * k
+            f0 += Fk
+        out = jnp.concatenate(parts, axis=0)        # [F, B, (s, c, lg)]
+    else:
+        out, cnt = pl.pallas_call(
+            _wave_kernel(C, Fg, Bg, NLg),
+            grid=(Bp // Bg, Fp // Fg, n // row_tile),
+            in_specs=[pl.BlockSpec((Fg, row_tile),
+                                   lambda bg, g, i: (g, i))] + row_specs,
+            out_specs=[
+                pl.BlockSpec((Fg, Bg, S * C * NLg),
+                             lambda bg, g, i: (g, bg, 0)), cnt_spec],
+            out_shape=[
+                jax.ShapeDtypeStruct((Fp, Bp, S * C * NLg), acc_t),
+                cnt_shape],
+            name="build_histogram_wave",
+        )(binned_fm, slot_row, gh)
     # [Fp, Bp, (s, c, lg)] -> [NL, F, B, C]
-    out = out.reshape(Fp, Bp, S, C, NLg).transpose(2, 4, 0, 1, 3)
-    hist = out.reshape(S * NLg, Fp, Bp, C)[:num_slots, :F, :max_bin, :]
+    Fo, Bo = out.shape[:2]
+    out = out.reshape(Fo, Bo, S, C, NLg).transpose(2, 4, 0, 1, 3)
+    hist = out.reshape(S * NLg, Fo, Bo, C)[:num_slots, :F, :max_bin, :]
     if use_int8:
         # dequantize the exact int sums back to the float grid
         hist = hist.astype(jnp.float32) * quant_scales[None, None, None, :]
@@ -673,24 +859,36 @@ def build_histogram_wave(binned_fm: jnp.ndarray, slot: jnp.ndarray,
 def wave_histograms(binned_fm: jnp.ndarray, binned_rm: jnp.ndarray,
                     slot: jnp.ndarray, gh: jnp.ndarray, *, max_bin: int,
                     num_slots: int, true_slots: Optional[int] = None,
-                    quant_bins: int = 0, quant_scales: jnp.ndarray = None):
+                    quant_bins: int = 0, quant_scales: jnp.ndarray = None,
+                    hist_classes: tuple = (),
+                    binned_classed: jnp.ndarray = None,
+                    hist_inverse: jnp.ndarray = None):
     """Run the kernel `plan_wave_kernel` names for this wave (plain Python:
     the kernels are the jitted entries and carry the scopes).  Operands as
     `build_histogram_wave`'s, plus `binned_rm` [n, F] — built by the
     caller where the plan gives `wave_hl` at one slot, which every
     `wave_hl` wave of the same shape implies (both gates only close as
     the slots grow) — and the wave's `true_slots` where it knows them.
-    `quant_scales` selects the full kernel's int8 operands.
+    `quant_scales` selects the full kernel's int8 operands.  Where the
+    plan cuts `hist_classes` into class groups the full kernel reads
+    `binned_classed`, the caller's `binned_fm[hist_order]`, and its
+    histograms come back through `hist_inverse` (the order's inverse):
+    every caller sees its own column order, whatever kernel ran.
     Returns (hist [num_slots, F, B, C] float32, counts [num_slots])."""
     plan = plan_wave_kernel(binned_fm.shape[0], max_bin, num_slots,
                             true_slots, int8=quant_scales is not None,
-                            C=gh.shape[0] - 1)
+                            C=gh.shape[0] - 1, hist_classes=hist_classes)
     if plan.kernel == "wave_hl":
         return build_histogram_wave_hl(
             binned_fm, binned_rm, slot, gh, max_bin=max_bin,
             num_slots=true_slots, out_slots=num_slots)
     # Rt stays 512: 1024 is ~3% faster on small slot counts but exceeds
     # the 16 MB scoped-VMEM limit at 128 slots
+    if plan.class_groups:
+        hist, cnt = build_histogram_wave(
+            binned_classed, slot, gh, max_bin=max_bin, num_slots=num_slots,
+            hist_classes=hist_classes)
+        return jnp.take(hist, hist_inverse, axis=1, mode="clip"), cnt
     return build_histogram_wave(
         binned_fm, slot, gh, max_bin=max_bin, num_slots=num_slots,
         quant_bins=quant_bins, quant_scales=quant_scales)

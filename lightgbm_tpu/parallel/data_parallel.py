@@ -94,10 +94,14 @@ def make_sharded_wave_fn(mesh: Mesh, donate: bool = False):
         # check_vma off: replication of the tree outputs is by
         # construction (all inputs to the bookkeeping are psum results),
         # which the static checker cannot see through the Pallas calls.
+        # (the class-ordered copy of the bins is sharded by rows like
+        # the bins; every other extra is replicated)
+        extra_specs = tuple(P(None, ax) if k == "binned_classed" else P()
+                            for k in keys)
         mapped = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(P(None, ax), P(ax), P(ax), P(ax), P(), P())
-            + (P(),) * len(keys),
+            + extra_specs,
             out_specs=(P(), P(ax)),
             check_vma=False)
         if not donate:
@@ -115,13 +119,16 @@ def make_sharded_wave_fn(mesh: Mesh, donate: bool = False):
         return jax.jit(
             mapped,
             in_shardings=(NamedSharding(mesh, P(None, ax)), row, row,
-                          row, repl, repl) + (repl,) * len(keys),
+                          row, repl, repl)
+            + tuple(NamedSharding(mesh, spec) for spec in extra_specs),
             donate_argnums=(1, 2))
 
     def call(binned, grad, hess, row_mask, col_mask, meta, params,
-             cegb_used=None, extra_tag=None, quant_scales=None):
+             cegb_used=None, extra_tag=None, quant_scales=None,
+             binned_classed=None):
         opt = (("cegb_used", cegb_used), ("extra_tag", extra_tag),
-               ("quant_scales", quant_scales))
+               ("quant_scales", quant_scales),
+               ("binned_classed", binned_classed))
         keys = tuple(k for k, v in opt if v is not None)
         extras = tuple(v for _, v in opt if v is not None)
         import jax.numpy as jnp

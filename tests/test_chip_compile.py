@@ -228,6 +228,79 @@ def test_bundled_grow_program_compiles_at_the_one_hot_shape(one_chip):
     assert mem.temp_size_in_bytes < int(4_176_885_248 * 1.1), mem
 
 
+# the ranking and one-hot cells' device columns by code count
+# (`mslr-2270k-b63.train_rank`, `expo-onehot700-b63.train_sparse`) and
+# their padded rows
+from tools.kernel_checks import EXPO_CODES, MSLR_CODES  # noqa: E402
+MSLR_N, EXPO_N = 2_271_232, 11_000_832
+
+
+@pytest.mark.parametrize("codes,rows,slots", [
+    (MSLR_CODES, MSLR_N, 8), (MSLR_CODES, MSLR_N, 128),
+    (MSLR_CODES, MSLR_N, 255), (EXPO_CODES, EXPO_N, 8),
+    (EXPO_CODES, EXPO_N, 128), (EXPO_CODES, EXPO_N, 255)])
+def test_classed_wave_kernel_compiles_within_scoped_vmem(one_chip, codes,
+                                                         rows, slots):
+    """The call with each column's one-hot at its class's codes, at both
+    cells' shapes: one block of 7,392 / 1,200 one-hot rows up to 128
+    slots (15.1 MB counted at the ranking cell's 128, where the
+    unclassed call ran three feature groups), two calls at the chain
+    tail's 256 there — each within the compiler's scoped VMEM."""
+    from lightgbm_tpu.ops.histogram import (build_histogram_wave,
+                                            hist_classes_of,
+                                            plan_wave_kernel)
+    classes, _ = hist_classes_of(codes)
+    plan = plan_wave_kernel(len(codes), max(codes), slots,
+                            hist_classes=classes)
+    assert plan.vmem_bytes <= 16 << 20 and plan.groups == (
+        2 if (len(codes), slots) == (137, 255) else 1)
+    compiled = build_histogram_wave.lower(
+        *_kernel_args(one_chip, len(codes), rows), max_bin=max(codes),
+        num_slots=slots, hist_classes=classes).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= plan.groups
+
+
+def test_grow_program_is_one_for_two_column_orders(one_chip):
+    """What refused PR 37: the grow program at the ranking cell's shape,
+    lowered from the code counts of two column orders (the 45 few-code
+    columns at other indices, as `--seed` moves them), is the same
+    module text — classes are static as a sorted multiset, the order is
+    the data in `FeatureMeta.hist_order` / `hist_inverse` and in the
+    class-ordered copy of the bins — so the second order finds the
+    first's executable in the compile cache.  It compiles, the full
+    kernel classed (`f32[7392, .]` results) beside the decomposed one."""
+    from lightgbm_tpu.learner import FeatureMeta
+    from lightgbm_tpu.learner.wave import grow_tree_wave
+    from lightgbm_tpu.ops.histogram import hist_classes_of
+    F = len(MSLR_CODES)
+    by_col = {k: _sds((F,), "int32", one_chip)
+              for k in ("num_bin", "missing_type", "default_bin",
+                        "hist_order", "hist_inverse")}
+    meta = FeatureMeta(penalty=_sds((F,), "float32", one_chip), **by_col)
+    row = _sds((MSLR_N,), "float32", one_chip)
+    bins = _sds((F, MSLR_N), "uint8", one_chip)
+    args = (bins, row, row, row, _sds((F,), "bool", one_chip), meta)
+    texts, orders = [], []
+    for seed in (1, 2):
+        codes = np.random.RandomState(seed).permutation(MSLR_CODES)
+        classes, order = hist_classes_of(codes)
+        orders.append(order)
+        lowered = grow_tree_wave.lower(
+            *args, params=_grow_params(max_bin=63, hist_classes=classes),
+            binned_classed=bins)    # as a booster hands it over
+        texts.append(lowered.as_text())
+    assert not np.array_equal(*orders)
+    assert texts[0] == texts[1]
+    text = lowered.compile().as_text()
+    calls = re.findall(r"^\s*%([\w.\-]+) = \((f32\[[\d,]+\]).*"
+                       r'custom_call_target="tpu_custom_call"', text, re.M)
+    full = [shape for name, shape in calls
+            if name.startswith("build_histogram_wave.")]
+    assert len(full) == 7 and all(s.startswith("f32[7392,") for s in full)
+    assert sum(name.startswith("build_histogram_wave_hl.")
+               for name, _ in calls) == 2
+
+
 def test_bucketize_program_compiles_at_the_wide_shape(one_chip):
     """`io/device_bin.py` at 458,752 padded rows x 2,000 features: the
     float matrix (3.67 GB), its transposed copy and the bins fit the

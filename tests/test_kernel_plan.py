@@ -16,8 +16,9 @@ import pytest
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.learner import plan_growth
-from lightgbm_tpu.ops.histogram import (plan_wave_kernel, spike_true_slots,
-                                        wave_slot_pad)
+from lightgbm_tpu.ops.histogram import (hist_classes_of, plan_wave_kernel,
+                                        spike_true_slots, wave_slot_pad)
+from tools.kernel_checks import EXPO_CODES, MSLR_CODES
 
 HIGGS_255, HIGGS_63, EPSILON_63 = (28, 255), (28, 63), (2000, 63)
 # the one-hot cell under EFB: device columns x the largest column's codes
@@ -25,6 +26,13 @@ HIGGS_255, HIGGS_63, EPSILON_63 = (28, 255), (28, 63), (2000, 63)
 # scratch run), which is the shape the kernel runs
 EXPO_BUNDLED, EXPO_BUNDLED_13 = (12, 255), (13, 255)
 LADDER = (1, 2, 4, 8, 16, 32, 64, 128)      # true slots of a 255-leaf tree
+# the ranking cell: 137 columns at 63 bins, 45 of them integer-valued
+# with 4-32 codes; and both cells' columns by histogram class
+# (`hist_classes_of`: codes rounded up to 16), as their boosters hold them
+MSLR_63 = (137, 63)
+MSLR_CLASSES = ((16, 24), (32, 7), (64, 106))
+EXPO_CLASSES = ((16, 2), (32, 1), (48, 2), (64, 3), (128, 1), (208, 1),
+                (256, 2))
 
 
 def _ladder_plan(shape, true_slots, **kw):
@@ -91,6 +99,100 @@ def test_epsilon_feature_groups(slots, group, groups):
     assert (plan.kernel, plan.feature_pad, plan.feature_group,
             plan.groups) == ("wave", 2000, group, groups)
     assert plan.vmem_bytes <= 6 << 20
+
+
+def test_ranking_cell_unclassed_plan():
+    """(137, 63) with every column at 63 bins — the program before the
+    one-hot was classed, and any 137-column table of one class: `_hl`
+    for the 1-slot waves alone (2 slots: 11.2 MB of expander products
+    against `_HL_VMEM`), one 137-column block to 64 slots, three groups
+    of 48 over 144 padded columns at 128."""
+    for ts in LADDER:
+        plan = _ladder_plan(MSLR_63, ts)
+        assert plan.kernel == ("wave_hl" if ts == 1 else "wave")
+        full = plan_wave_kernel(*MSLR_63, wave_slot_pad(ts))
+        assert (full.feature_pad, full.feature_group, full.groups,
+                full.onehot_rows) == ((144, 48, 3, 9216) if ts == 128
+                                      else (137, 137, 1, 8768))
+    assert _ladder_plan(MSLR_63, 1).hl_split == (16, 4)
+
+
+# -------------------------- (a') several classes of column codes (PR 38)
+def test_classes_are_the_sorted_multiset_of_the_columns_codes():
+    assert hist_classes_of(MSLR_CODES)[0] == MSLR_CLASSES
+    assert hist_classes_of(EXPO_CODES)[0] == EXPO_CLASSES
+    # 113 / 45 codes (the plan of another seed) are the same classes
+    assert EXPO_CODES[9:] == (115, 43, 2)
+    other = EXPO_CODES[:9] + (113, 45, 2)
+    assert hist_classes_of(other)[0] == EXPO_CLASSES
+    # the order: a stable sort by class, whatever the columns' order
+    classes, order = hist_classes_of((63, 5, 255, 17, 16, 64))
+    assert classes == ((16, 2), (32, 1), (64, 2), (256, 1))
+    assert order.tolist() == [1, 4, 3, 0, 5, 2] and order.dtype == np.int32
+    # one class: every dense cell; past 256 codes (bin groups) always
+    assert hist_classes_of((255,) * 28)[0] == ((256, 28),)
+    assert hist_classes_of((63,) * 2000)[0] == ((64, 2000),)
+    assert hist_classes_of((1000, 20, 63))[0] == ((1008, 3),)
+
+
+@pytest.mark.parametrize("shape,classes,rows,hl_slots", [
+    # 7,392 one-hot rows a tile for 8,768: `_hl` keeps the 1-slot waves
+    # (24 <= 0.6 x 54.0 codes a column; at 2 slots `_HL_VMEM` refuses)
+    (MSLR_63, MSLR_CLASSES, 7392, (1,)),
+    # 1,200 for 3,072: the 2-, 4- and 8-slot waves leave `_hl`, whose
+    # 64 / 80 / 96 lane-units are over 0.6 x 100 codes a column
+    (EXPO_BUNDLED, EXPO_CLASSES, 1200, (1,))])
+def test_classed_plans_of_the_two_cells(shape, classes, rows, hl_slots):
+    for ts in LADDER:
+        plan = _ladder_plan(shape, ts, hist_classes=classes)
+        assert plan.fits
+        assert plan.kernel == ("wave_hl" if ts in hl_slots else "wave")
+        full = plan_wave_kernel(*shape, wave_slot_pad(ts),
+                                hist_classes=classes)
+        # one block over the unpadded, class-ordered columns
+        assert (full.kernel, full.feature_pad, full.feature_group,
+                full.groups, full.onehot_rows) == (
+                    "wave", shape[0], shape[0], 1, rows)
+        assert full.class_groups == (classes,)
+        assert full.vmem_bytes == rows * (wave_slot_pad(ts) * 8 + 1024)
+        assert full.vmem_bytes <= 16 << 20
+    # the decomposed kernel itself is the unclassed plan's, split and all
+    assert (_ladder_plan(shape, 1, hist_classes=classes).hl_split
+            == _ladder_plan(shape, 1).hl_split)
+
+
+def test_ranking_cell_chain_tail_is_two_class_groups():
+    """256 slots (the chain tail's traced call): 3,072 B a one-hot row,
+    so 5,461 rows a call — the 64-code class is cut after 75 columns."""
+    plan = plan_wave_kernel(*MSLR_63, 256, hist_classes=MSLR_CLASSES)
+    assert plan.class_groups == (((16, 24), (32, 7), (64, 75)),
+                                 ((64, 31),))
+    assert (plan.groups, plan.feature_group, plan.onehot_rows) == (
+        2, 106, 7392)
+    assert plan.vmem_bytes == 5408 * 3072
+
+
+@pytest.mark.parametrize("shape", [HIGGS_255, HIGGS_63, EPSILON_63,
+                                   EXPO_BUNDLED, EXPO_BUNDLED_13, MSLR_63])
+def test_one_class_is_the_unclassed_plan_field_for_field(shape):
+    """The switch is the NUMBER of classes: a table whose columns share
+    a class (Higgs, dp4, Epsilon) gets the plan — and so the call — it
+    had before classes existed, at every shape pinned above."""
+    one = hist_classes_of((shape[1],) * shape[0])[0]
+    assert len(one) == 1
+    for ts in LADDER + (255,):
+        for true_slots in (None, ts):
+            for int8 in (False, True):
+                args = (*shape, wave_slot_pad(ts), true_slots)
+                assert (plan_wave_kernel(*args, int8=int8, hist_classes=one)
+                        == plan_wave_kernel(*args, int8=int8))
+
+
+def test_int8_operands_keep_one_class():
+    for ts in LADDER:
+        assert (_ladder_plan(EXPO_BUNDLED, ts, int8=True,
+                             hist_classes=EXPO_CLASSES)
+                == _ladder_plan(EXPO_BUNDLED, ts, int8=True))
 
 
 # ------------------------------------------------------ (b) the gate's edges
@@ -273,3 +375,90 @@ def test_under_bundles_the_plan_is_asked_about_the_kernels_shape(
     assert shape == (g.binned_dev.shape[0], g.grow_params.group_max_bin)
     assert shape == (1, 2 * F + 1) and len(g.f_num_bin) == F
     assert g.grow_params.max_bin == 2
+
+
+# ------------- (f) what is static in the grow program ignores column order
+def _table_with_codes(codes, n, seed=38):
+    """[n, F] float32 whose column f holds `codes[f]` distinct whole
+    numbers (a continuous column where that is the booster's max_bin)."""
+    rng = np.random.RandomState(seed)
+    top = max(codes)
+    return np.stack([rng.rand(n) if c == top and c >= 63
+                     else rng.randint(0, c, n) for c in codes],
+                    axis=1).astype(np.float32)
+
+
+def _grow_call_signature(g):
+    """What `jax.jit` keys the grow entry's trace on, besides the static
+    `params`: the tree structure of the arguments of one
+    `train_one_iter`'s grow call and each leaf's shape and dtype."""
+    import jax
+    seen = []
+    inner = g._grow_fn
+
+    def spy(*args, **kw):
+        dynamic = (args[:6], kw)            # args[6] is `params` (static)
+        seen.append((jax.tree_util.tree_structure(dynamic),
+                     [(np.shape(x), np.result_type(x).name)
+                      for x in jax.tree_util.tree_leaves(dynamic)]))
+        assert args[6] == g.grow_params
+        return inner(*args, **kw)
+    g._grow_fn = spy
+    g.train_one_iter()
+    g._grow_fn = inner
+    assert len(seen) == 1
+    return seen[0]
+
+
+@pytest.mark.parametrize("codes,max_bin,classes,rows", [
+    (MSLR_CODES, 63, MSLR_CLASSES, 3000),
+    (EXPO_CODES, 255, EXPO_CLASSES, 8000)])
+def test_column_order_reaches_the_grow_program_as_data(codes, max_bin,
+                                                       classes, rows):
+    """PR 37 was refused for this: the benchmark's `--seed` permutes the
+    feature columns, a per-column layout static in column order made
+    every seed a new program, and `setup_s` paid a cold compile (38.6 s
+    at the ranking cell) on every run.  Boosters on three column
+    permutations of one table hold EQUAL `GrowParams` (the jitted
+    entry's static argument) and hand the grow entry arguments of equal
+    structure, shapes and dtypes: one trace, one executable, one entry
+    in the compile cache.  Only `hist_order` / `hist_inverse` differ."""
+    X = _table_with_codes(codes, rows)
+    y = (X[:, 0] > np.median(X[:, 0])).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 15, "max_bin": max_bin,
+              "min_data_in_bin": 1, "min_data_in_leaf": 5,
+              "tpu_growth_strategy": "wave", "verbosity": -1}
+    boosters = []
+    for seed in (None, 1, 2):
+        order = (np.arange(len(codes)) if seed is None
+                 else np.random.RandomState(seed).permutation(len(codes)))
+        g = lgb.Booster(params=params, train_set=lgb.Dataset(
+            X[:, order], label=y, params=params))._gbdt
+        assert g.f_num_bin.tolist() == [codes[i] for i in order]
+        boosters.append((g, _grow_call_signature(g)))
+    first, signature = boosters[0]
+    assert first.grow_params.hist_classes == classes
+    assert first.growth_strategy == "wave"
+    orders = set()
+    for g, sig in boosters:
+        assert g.grow_params == first.grow_params
+        assert hash(g.grow_params) == hash(first.grow_params)
+        assert sig == signature
+        order = np.asarray(g.meta.hist_order)
+        orders.add(tuple(order.tolist()))
+        # the order sorts this booster's columns by class, and the
+        # inverse undoes it
+        cls = -(-g.f_num_bin // 16) * 16
+        assert (np.diff(cls[order]) >= 0).all()
+        assert (order[np.asarray(g.meta.hist_inverse)]
+                == np.arange(len(codes))).all()
+    assert len(orders) == 3
+
+
+def test_a_table_of_one_class_carries_no_order(monkeypatch):
+    """Every dense cell: no `hist_classes`, no order in `FeatureMeta`,
+    the grow entry's arguments as they were before classes existed."""
+    X = np.random.RandomState(0).rand(600, 6).astype(np.float32)
+    _, g = _asked_shape(monkeypatch, X)
+    assert g.grow_params.hist_classes == ()
+    assert g.meta.hist_order is None and g.meta.hist_inverse is None

@@ -152,3 +152,146 @@ def test_leafwise_engine_bf16_onehot_leaf_sums_are_consistent():
         jnp.asarray(binned), jnp.asarray(grad), jnp.asarray(hess),
         jnp.ones(n, jnp.float32), jnp.ones(F, bool), meta, params)
     _assert_leaf_sums_match_rows(tree, leaf_id, grad, hess, L)
+
+
+# ------------------------------------- the one-hot at each column's class
+@pytest.mark.parametrize("num_slots", [1, 8, 64, 128, 255])
+def test_classed_wave_kernel_equals_unclassed_bit_for_bit(interpret_pallas,
+                                                          num_slots):
+    """tools/kernel_checks.py check 9 (which the chip runs at 8 and 128
+    slots): the one-hot cell's twelve code counts and the ranking cell's
+    classes in a shuffled column order, every column reaching its last
+    code (15 of a 16-code class among them), histograms and counts
+    compared bit for bit."""
+    from tools.kernel_checks import _classed_mismatches
+    assert _classed_mismatches(slot_counts=(num_slots,), n=1024,
+                               grid=0.125) == []
+
+
+def test_classed_wave_kernel_in_class_groups_equals_unclassed(
+        interpret_pallas):
+    """108 columns at 255 slots: 8,160 classed one-hot rows at 3,072 B
+    each are two one-block calls, the cut inside the 256-code class."""
+    from lightgbm_tpu.ops.histogram import hist_classes_of, plan_wave_kernel
+    from tools.kernel_checks import CLASSED_CODES, _classed_mismatches
+    codes = CLASSED_CODES * 6
+    plan = plan_wave_kernel(len(codes), 255, 255,
+                            hist_classes=hist_classes_of(codes)[0])
+    assert plan.groups == 2 and plan.onehot_rows == 8160
+    assert [sum(c * k for c, k in g) for g in plan.class_groups] == [
+        5344, 2816]
+    assert plan.class_groups[0][-1] == (256, 1)
+    assert plan.class_groups[1] == ((256, 11),)
+    assert _classed_mismatches(codes, slot_counts=(255,), n=512,
+                               grid=0.125) == []
+
+
+def test_int8_arm_keeps_one_class(interpret_pallas):
+    """`quant_scales` with `hist_classes` handed over: the plan names no
+    class group (tests/test_kernel_plan.py) and the call is the unclassed
+    one on the engine's own column order, so both sides agree to the
+    bit."""
+    from tools.kernel_checks import _classed_mismatches
+    quant = dict(quant_bins=16, quant_scales=jnp.asarray([0.125, 0.125]))
+    assert _classed_mismatches(slot_counts=(8,), n=1024, grid=0.125,
+                               quant=quant) == []
+
+
+def test_wave_engine_grows_the_same_tree_classed_and_unclassed(
+        interpret_pallas):
+    """The whole grow program on a table of three classes (16 / 32 / 64)
+    in a mixed column order: with `hist_classes` and the order in
+    `FeatureMeta` the tree and every row's leaf are those of the program
+    that builds every column at `max_bin` — gradients on a grid of
+    eighths, so that no order of summation can move a bit."""
+    from lightgbm_tpu.learner.wave import grow_tree_wave
+    from lightgbm_tpu.ops.histogram import class_ordered, hist_classes_of
+    n, B, L = 4096, 64, 31
+    rng = np.random.RandomState(38)
+    codes = np.array([64, 16, 32, 64, 9, 64, 30, 16])
+    binned = np.stack([rng.randint(0, c, n) for c in codes]).astype(np.uint8)
+    score = binned[0] / 64 + (binned[1] > 7) + 0.5 * (binned[2] % 5)
+    grad = (np.round((score - score.mean() + rng.randn(n)) * 8) / 8)
+    hess = rng.randint(1, 9, n) / 8
+    F = len(codes)
+    base = dict(num_bin=jnp.asarray(codes, jnp.int32),
+                missing_type=jnp.full(F, MISSING_NONE, jnp.int32),
+                default_bin=jnp.zeros(F, jnp.int32),
+                penalty=jnp.ones(F, jnp.float32))
+    classes, order = hist_classes_of(codes)
+    assert classes == ((16, 3), (32, 2), (64, 3))
+    params = GrowParams(num_leaves=L, max_bin=B, hist_method="pallas",
+                        split=SplitParams(min_data_in_leaf=20))
+    handed = class_ordered(jnp.asarray(binned), jnp.asarray(order))
+    np.testing.assert_array_equal(np.asarray(handed), binned[order])
+    grown = []
+    for meta, p, kw in (
+            (FeatureMeta(**base), params, {}),
+            (FeatureMeta(hist_order=jnp.asarray(order),
+                         hist_inverse=jnp.asarray(np.argsort(order),
+                                                  jnp.int32), **base),
+             params._replace(hist_classes=classes),
+             {"binned_classed": handed})):
+        grown.append(grow_tree_wave(
+            jnp.asarray(binned), jnp.asarray(grad, jnp.float32),
+            jnp.asarray(hess, jnp.float32), jnp.ones(n, jnp.float32),
+            jnp.ones(F, bool), meta, p, **kw))
+    (tree_a, leaf_a), (tree_b, leaf_b) = grown
+    assert int(tree_a.num_leaves) == L
+    for name, a, b in zip(tree_a._fields, tree_a, tree_b):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+    np.testing.assert_array_equal(np.asarray(leaf_a), np.asarray(leaf_b))
+
+
+@pytest.mark.parametrize("extra", [{}, {"tree_learner": "data"}],
+                         ids=["one_device", "row_mesh"])
+def test_booster_hands_the_class_ordered_bins_to_every_tree(
+        interpret_pallas, monkeypatch, extra):
+    """A booster that takes the wave engine and the Pallas kernel (the
+    plan asked as a TPU would be) on a table of three classes gathers
+    the bins into class order ONCE, sharded by rows like the bins, and
+    grows the trees of the booster that builds every column at
+    `max_bin`: the model text is equal after three iterations, on one
+    device and under `tree_learner=data`'s shard_map.  On one device the
+    copy is left uncommitted like the bins, so the grow entry is lowered
+    once: committed, it committed every later tree's gradients, and the
+    second iteration lowered and loaded the program again (6 s of
+    `setup_s` at the ranking cell: my chip run, PR 38)."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.boosting import gbdt
+    from lightgbm_tpu.learner import plan_growth
+    from lightgbm_tpu.learner.wave import grow_tree_wave_donated as entry
+    monkeypatch.setattr(gbdt, "plan_growth",
+                        lambda **kw: plan_growth(**{**kw, "backend": "tpu"}))
+    rng = np.random.RandomState(0)
+    codes, n = [63, 16, 32, 63, 9, 63, 30, 16], 4096
+    X = np.stack([rng.rand(n) if c == 63 else rng.randint(0, c, n)
+                  for c in codes], axis=1).astype(np.float32)
+    y = (X[:, 0] + X[:, 1] / 16 + 0.3 * rng.randn(n) > 1).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 15, "max_bin": 63,
+              "min_data_in_bin": 1, "min_data_in_leaf": 5, "verbosity": -1,
+              **extra}
+    models = []
+    for classed in (True, False):
+        booster = lgb.Booster(params=params, train_set=lgb.Dataset(
+            X, label=y, params=params))
+        g = booster._gbdt
+        assert (g.growth_strategy, g.grow_params.hist_method) == (
+            "wave", "pallas")
+        assert g.grow_params.hist_classes == ((16, 3), (32, 2), (64, 3))
+        handed = g._classed_kw["binned_classed"]
+        assert handed.sharding == g.binned_dev.sharding
+        np.testing.assert_array_equal(
+            np.asarray(handed),
+            np.asarray(g.binned_dev)[np.asarray(g.meta.hist_order)])
+        if not classed:
+            g.grow_params = g.grow_params._replace(hist_classes=())
+            g._classed_kw = {}
+        lowered_before = entry._cache_size()
+        for _ in range(3):
+            booster.update()
+        if not extra:
+            assert entry._cache_size() - lowered_before == 1
+        models.append(booster.model_to_string())
+    assert models[0] == models[1]

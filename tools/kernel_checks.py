@@ -41,6 +41,14 @@ Checks (small shapes, seconds of chip time):
      bins); histogram entries are whole numbers, so no order of
      summation can move a bit
 
+  9. the fused wave kernel with each column's one-hot at its class's
+     codes (`hist_classes`, the bins in class order, the result brought
+     back by the order's inverse) == the same kernel with every column
+     at the largest's codes, bit for bit and counts too, on the one-hot
+     cell's twelve code counts and the ranking cell's three classes in a
+     shuffled column order (codes that reach their class's bound among
+     them), at 8 and 128 slots
+
 `run_wide_checks()` (`python tools/kernel_checks.py --wide`; a minute of
 chip time, so not part of `run_checks`) holds the fused wave kernel to a
 plain float32 reference at the widest benchmark cell's own shape,
@@ -55,6 +63,11 @@ the reading behind `boosting/leaf_lookup.py ONE_HOT_MAX_LEAVES`.
 `time_rank_gradients()` (`python tools/kernel_checks.py --rank-gradients`;
 two minutes) runs check 7's two programs on the ranking cell's own plan
 (18,919 queries, 2,270,296 rows), compares every bit and times both.
+
+`time_classed_kernel()` (`python tools/kernel_checks.py --classed`; two
+minutes) runs check 9's two calls at the one-hot cell's and the ranking
+cell's own shapes (`[12, 11,000,832]` at 255 codes, `[137, 2,271,232]`
+at 63) and 8 / 64 / 128 slots: bits compared, ms a call of each.
 """
 import os
 import sys
@@ -211,6 +224,13 @@ def run_checks():
         traceback.print_exc()
         failures.append(f"efb_decode_raised({type(e).__name__})")
 
+    # 9. the classed wave kernel vs the unclassed one, bit for bit
+    try:
+        failures.extend(_classed_mismatches())
+    except Exception as e:    # noqa: BLE001 - named in the verdict
+        traceback.print_exc()
+        failures.append(f"classed_raised({type(e).__name__})")
+
     return "ok" if not failures else "fail:" + ",".join(failures)
 
 
@@ -284,6 +304,119 @@ def _efb_decode_mismatches(leaves=8, B=63, seed=36):
     differ = int(np.sum((got + np.float32(0)).view(np.uint32)
                         != want.view(np.uint32)))
     return [f"efb_decode_differs_in_{differ}"] if differ else []
+
+
+# the device columns of the one-hot and the ranking cell by code count
+# (the bundle plan's twelve; 45 of 137 columns integer-valued), and check
+# 9's: the twelve, then the ranking cell's classes at their bounds (16 /
+# 32 codes) and under them
+EXPO_CODES = tuple(c for c, _ in EFB_COLUMNS)
+MSLR_CODES = (63,) * 92 + (16,) * 24 + (32,) * 7 + (63,) * 14
+CLASSED_CODES = EXPO_CODES + (16, 32, 4, 8, 63, 16)
+
+
+def _classed_operands(codes, n, seed=38, grid=0.0):
+    """(binned [F, n] with column f drawing all of `codes[f]` codes, its
+    columns shuffled; gh [3, n]; the shuffled codes).  `grid` > 0 puts
+    gradients and hessians on multiples of it (every float32 sum exact in
+    any order: what the CPU's interpreter needs, whose dot may block its
+    adds by the operand's height); 0 leaves them on the bf16 grid, random
+    — what the chip is held to."""
+    import ml_dtypes
+    rng = np.random.RandomState(seed)
+    codes = np.asarray(codes)[rng.permutation(len(codes))]
+    binned = np.stack([rng.randint(0, c, n) for c in codes]).astype(np.uint8)
+    binned[:, 0] = codes - 1            # every column reaches its last code
+    mask = (rng.rand(n) < 0.9).astype(np.float32)
+    if grid:
+        g = rng.randint(-16, 17, n) * grid
+        h = rng.randint(1, 17, n) * grid
+    else:
+        g, h = rng.randn(n), rng.rand(n) * 0.25
+    gh = np.stack([g * mask, h * mask, mask]).astype(np.float32)
+    return (binned, gh.astype(ml_dtypes.bfloat16).astype(np.float32), codes)
+
+
+def _classed_calls(binned, codes, max_bin):
+    """(classed, unclassed): each `(slot, gh, num_slots, **quant) ->
+    (hist, counts)` through `wave_histograms`, the classed one as the
+    wave engine hands it over (`learner/wave.py`)."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.histogram import (class_ordered, hist_classes_of,
+                                            wave_histograms)
+    classes, order = hist_classes_of(codes)
+    assert len(classes) > 1
+    binned = jnp.asarray(binned)
+    handed = dict(hist_classes=classes,
+                  binned_classed=class_ordered(binned, jnp.asarray(order)),
+                  hist_inverse=jnp.asarray(np.argsort(order), jnp.int32))
+
+    def call(extra):
+        return lambda slot, gh, num_slots, **quant: wave_histograms(
+            binned, None, slot, gh, max_bin=max_bin, num_slots=num_slots,
+            **quant, **extra)
+    return call(handed), call({})
+
+
+def _bits_differ(a, b):
+    """Entries of two float32 arrays whose bits differ."""
+    return int(np.count_nonzero(np.asarray(a).view(np.uint32)
+                                != np.asarray(b).view(np.uint32)))
+
+
+def _classed_mismatches(codes=CLASSED_CODES, slot_counts=(8, 128), n=2048,
+                        grid=0.0, quant=None):
+    """Check 9.  `quant` (`quant_bins`, `quant_scales`) runs the int8
+    arm, which keeps one class: the same call on both sides."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.histogram import wave_slot_pad
+    binned, gh, codes = _classed_operands(codes, n, grid=grid)
+    classed, unclassed = _classed_calls(binned, codes, int(codes.max()))
+    rng = np.random.RandomState(39)
+    failures = []
+    for slots in slot_counts:
+        slot = jnp.asarray(np.where(rng.rand(n) < 0.8,
+                                    rng.randint(0, slots, n),
+                                    wave_slot_pad(255)).astype(np.int32))
+        got, want = (f(slot, jnp.asarray(gh), slots, **(quant or {}))
+                     for f in (classed, unclassed))
+        for name, a, b in zip(("sums", "counts"), got, want):
+            differ = _bits_differ(a, b)
+            if differ or not np.any(b):
+                failures.append(f"classed_{name}_differ_in_{differ}"
+                                f"_at_{slots}_slots")
+    return failures
+
+
+def time_classed_kernel(calls=10):
+    """Check 9's two calls at the cells' own shapes: bits, ms a call."""
+    import time
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.histogram import wave_slot_pad
+    for name, codes, n in (("expo", EXPO_CODES, 11_000_832),
+                           ("mslr", MSLR_CODES, 2_271_232)):
+        binned, gh, codes = _classed_operands(codes, n)
+        fns = _classed_calls(binned, codes, int(codes.max()))
+        gh = jnp.asarray(gh)
+        rng = np.random.RandomState(39)
+        for slots in (8, 64, 128):
+            slot = jnp.asarray(np.where(
+                rng.rand(n) < 0.8, rng.randint(0, slots, n),
+                wave_slot_pad(255)).astype(np.int32))
+            outs, ms = [], []
+            for f in fns:
+                outs.append(jax.block_until_ready(f(slot, gh, slots)))
+                t0 = time.perf_counter()
+                for _ in range(calls):
+                    out = f(slot, gh, slots)
+                jax.block_until_ready(out)
+                ms.append((time.perf_counter() - t0) / calls * 1e3)
+            differ = [_bits_differ(a, b) for a, b in zip(*outs)]
+            print(f"classed {name} [{len(codes)}, {n}] slots={slots}: "
+                  f"classed {ms[0]:.2f} ms, unclassed {ms[1]:.2f} ms a call "
+                  f"(the inverse take included), entries whose bits differ "
+                  f"(sums, counts) {differ}", file=sys.stderr)
 
 
 def _rank_lengths(queries, seed):
@@ -655,6 +788,8 @@ if __name__ == "__main__":
         time_score_lookup()
     elif "--rank-gradients" in sys.argv[1:]:
         time_rank_gradients()
+    elif "--classed" in sys.argv[1:]:
+        time_classed_kernel()
     else:
         print(run_wide_checks() if "--wide" in sys.argv[1:]
               else run_checks())
