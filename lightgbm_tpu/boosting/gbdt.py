@@ -455,10 +455,14 @@ class GBDT:
         # the device columns' histogram classes: the multiset is static
         # in the grow program, which column holds which is data
         # (ops/histogram.py hist_classes_of)
-        hist_classes, hist_order = hist_classes_of(
-            self.f_num_bin if bp is None else bp.group_num_bin)
+        column_codes = self.f_num_bin if bp is None else bp.group_num_bin
+        hist_classes, hist_order = hist_classes_of(column_codes)
         one_class = len(hist_classes) < 2
         _metrics.set_gauge("hist_classes", len(hist_classes))
+        # what a histogram of this table must multiply a row and output
+        # column, whatever the kernels pad it to (the benchmark's
+        # `hist_mxu_roofline` counts useful MXU work from it)
+        _metrics.inc("hist_codes", int(np.sum(column_codes)))
         self.meta = FeatureMeta(
             num_bin=jnp.asarray(self.f_num_bin),
             missing_type=jnp.asarray(self.f_missing_type),

@@ -58,6 +58,30 @@ def _count_traced_call(feature_groups: int) -> None:
     global_registry.inc("hist_feature_group_passes", feature_groups)
 
 
+def mxu_call_scope(plan: "WaveKernelPlan", num_features: int,
+                   num_slots: int, true_slots: Optional[int] = None,
+                   C: int = 2):
+    """The `device_scope` PART a histogram kernel call runs under, inside
+    `Tree::histogram`: `Hist::mxu_n<n>_f<f>_e<e>`, three integers that
+    ride each kernel event's `op_name` into the device trace at no run
+    time (docs/Observability.md has the grammar; the benchmark's
+    `hist_mxu_roofline` / `hist_mxu_padding` read them).  `n`: the useful
+    output columns, channels x the wave's TRUE computed slots where it
+    names them (the ladder's 2- and 4-slot waves are both padded to 8);
+    `f`: the MXU FLOP a row the call's dots ask (`mxu_flop_per_row`);
+    `e`: the `pallas_call`s the call issues, so that a reader shares `f`
+    among the call's events.  Shapes and the sorted class multiset in,
+    never the column order: every `--seed` of a cell traces the same
+    labels.  `scope_layout.json`'s label pattern does not match `Hist.`,
+    so every op stays under `Tree.histogram`."""
+    n = C * (num_slots if true_slots is None else true_slots)
+    f = mxu_flop_per_row(
+        plan, num_features,
+        true_slots if plan.kernel == "wave_hl" else num_slots, C)
+    e = max(len(plan.class_groups), 1)
+    return global_timer.device_scope(f"Hist::mxu_n{n}_f{f}_e{e}")
+
+
 # lowerings whose MXU operands are bf16: each row's accumuland is rounded
 # to bf16 on its way into the dot (the one-hot side is exact, the
 # accumulator is fp32)
@@ -181,15 +205,21 @@ def build_histogram_rows_pallas(rows: jnp.ndarray, gh: jnp.ndarray,
             rows_fm = jnp.pad(rows_fm, ((0, Fp - F), (0, 0)))
     # feature group bounded by the [Fg, Bp, Rt] bf16 one-hot in VMEM (~2MB)
     Fg = _pick_feature_group(Fp, Bp * row_tile * 2, 2 << 20)
-    out = pl.pallas_call(
-        _hist_pallas_kernel(Fg, Bp, C),
-        grid=(Fp // Fg, S // row_tile),
-        in_specs=[pl.BlockSpec((Fg, row_tile), lambda g, i: (g, i)),
-                  pl.BlockSpec((row_tile, C), lambda g, i: (i, 0))],
-        out_specs=pl.BlockSpec((Fg, Bp, C), lambda g, i: (g, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((Fp, Bp, C), jnp.float32),
-        name="build_histogram_rows",
-    )(rows_fm, gh)
+    plan = WaveKernelPlan(kernel="rows", feature_pad=Fp, feature_group=Fg,
+                          groups=Fp // Fg, hl_split=None,
+                          vmem_bytes=Fg * Bp * row_tile * 2, fits=True,
+                          onehot_rows=Fp * Bp)
+    with mxu_call_scope(plan, F, 1, C=C):    # one leaf's histogram
+        out = pl.pallas_call(
+            _hist_pallas_kernel(Fg, Bp, C),
+            grid=(Fp // Fg, S // row_tile),
+            in_specs=[pl.BlockSpec((Fg, row_tile), lambda g, i: (g, i)),
+                      pl.BlockSpec((row_tile, C), lambda g, i: (i, 0))],
+            out_specs=pl.BlockSpec((Fg, Bp, C), lambda g, i: (g, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((Fp, Bp, C), jnp.float32),
+            metadata=_kernel_metadata(plan, F, 1, C),
+            name="build_histogram_rows",
+        )(rows_fm, gh)
     return out[:F, :max_bin, :]                       # [F, B, C]
 
 
@@ -235,9 +265,10 @@ def _wave_kernel(C: int, Fg: int, Bg: int, NLg: int, classes: tuple = ()):
         mxu_t = jnp.int8 if int8_mode else jnp.bfloat16
         acc_t = jnp.int32 if int8_mode else jnp.float32
         # offset the SMALL [Fg, Rt] rows instead of the big [Fg, Bg, Rt]
-        # iota: the one-hot construction was the per-wave cost floor
-        # *(old chip)*; not re-measured, see ROADMAP S1 — every
-        # elementwise pass over the big shape counts
+        # iota: one elementwise pass less over the big shape.  (On this
+        # chip the build is not the call's floor, the dot is: fed a
+        # constant one-hot the kernel costs what it costs with the build,
+        # `tools/hist_roof_probe.py`, PERF.md section 6, PR 39)
         rows = rows_ref[...].astype(jnp.int32) - bg * Bg  # [Fg, Rt]
         slot = slot_ref[...]                             # [1, Rt]
         Rt = rows.shape[1]
@@ -257,9 +288,9 @@ def _wave_kernel(C: int, Fg: int, Bg: int, NLg: int, classes: tuple = ()):
             oh2 = oh.reshape(Fg * Bg, Rt)
         lanes = (((1,), (1,)), ((), ()))     # contract both over rows
         S = out_ref.shape[-1] // (C * NLg)
-        for s in range(S):  # slot groups REUSE the bin one-hot (its
-            # construction, not the MXU dot, was the per-wave cost floor
-            # *(old chip)*; not re-measured, see ROADMAP S1)
+        for s in range(S):  # slot groups REUSE the bin one-hot; each
+            # costs its MXU dot and nothing else (a call: 25.4 ms to 64
+            # slots, 50.2 at 128; its dots alone 25.4 and 50.1: PR 39)
             # rows stay on lanes: the slot one-hot [NLg, Rt] and the
             # slot-separated channel matrix [C*NLg, Rt] (c-major) are
             # built from sublane broadcasts of the [1, Rt] operand rows —
@@ -299,10 +330,12 @@ def _wave_kernel(C: int, Fg: int, Bg: int, NLg: int, classes: tuple = ()):
 def _wave_kernel_hl(C: int, Fg: int, Bh: int, Bl: int, S: int, P: int):
     """Decomposed (hi/lo outer-product) wave kernel for FEW computed slots.
 
-    The flat cost of `_wave_kernel` is the F*B*Rt bin one-hot built in
-    VMEM every wave (its floor *(old chip)*; not re-measured, see ROADMAP
-    S1).  For waves whose computed-slot count S is small,
-    the one-hot factors over a hi/lo split of the bin code
+    The flat cost of `_wave_kernel` to 64 slots is its dot over the
+    F*B*Rt bin one-hot, whose N the MXU pads to a 128-column tile however
+    few slots ride it (MXU-bound: PR 39's probe, PERF.md section 6; the
+    one-hot's build hides under the dot).  For waves whose computed-slot
+    count S is small, the one-hot factors over a hi/lo split of the bin
+    code
 
         onehot_B(bin) = onehot_Bh(bin >> log2(Bl)) (x) onehot_Bl(bin & Bl-1)
 
@@ -552,7 +585,7 @@ def class_ordered(binned_fm: jnp.ndarray,
 class WaveKernelPlan(NamedTuple):
     """Which wave kernel a call takes and in what blocks: shapes in,
     nothing of the data."""
-    kernel: str                 # "wave" | "wave_hl"
+    kernel: str                 # "wave" | "wave_hl" ("rows": leaf-wise)
     feature_pad: int            # full kernel: F as its grid sees it (Fp)
     feature_group: int          # full kernel: features a block (Fg)
     groups: int                 # full kernel: passes over the rows
@@ -659,6 +692,77 @@ def plan_wave_kernel(num_features: int, max_bin: int, num_slots: int,
                           class_groups)
 
 
+def _hl_pack(num_features: int, Bh: int) -> int:
+    """Features `_wave_kernel_hl` packs into the M of one main dot."""
+    return next((p for p in (4, 2, 1)
+                 if num_features % p == 0 and p * Bh <= 256), 1)
+
+
+def mxu_flop_per_row(plan: WaveKernelPlan, num_features: int,
+                     num_slots: int, C: int = 2) -> int:
+    """The MXU FLOP A ROW of the table that a call of `plan.kernel` asks:
+    its dots as they are written, tile-padded as the v5e's 128 x 128 MXU
+    runs them.  Shapes and `plan` (which holds the sorted class multiset
+    and nothing of the column order) in; `num_slots` is what the kernel
+    computes — the padded bound for `wave`, the true slots for `wave_hl`.
+
+    THE PADDING RULE, written here and nowhere else: a dot
+    `[M, K] x [K, N]` costs `2 x M8 x K128 x N128` — the output columns N
+    and the contracted K go up to whole 128-wide tiles of the array, the
+    streamed rows M to the 8-sublane granule.  A dot that contracts over
+    the row tile (K = Rt, whole tiles) so costs `2 x M8 x N128` a row; one
+    whose M is the row tile costs `2 x K128 x N128` a row.
+
+    * `wave` (`_wave_kernel`): a slot group's `[M, Rt] x [C*NLg, Rt]` dot
+      S times, M the one-hot rows the call really builds
+      (`plan.onehot_rows`: classed or not, feature- and bin-padded, over
+      all its blocks), and the `[8, Rt] x [NLg, Rt]` count dot S times in
+      each of its `pallas_call`s.  At `[28 x 256]` and up to 64 slots:
+      2 x 7,168 x 128 = 1,835,008 for the histogram (24.5 ms at
+      2,625,536 rows and 197e12 FLOP/s) + 2,048 for the counts; at 128
+      slots N is two tiles and the histogram's part doubles.
+    * `wave_hl` (`_wave_kernel_hl`): the F / P main dots
+      `[P*Bh, Rt] x [Rt, P*Bl*CS]`, the expander
+      `d = [Rt, F+1] x [F+1, Wd]`, `wt = [CS, Rt]^T x [CS, Wd]` and the
+      `[8, Rt] x [max(S, 8), Rt]` count dot.
+    * `rows` (`_hist_pallas_kernel`): `[Fp*Bp, Rt] x [Rt, C]` over its
+      feature groups."""
+    tiles = _round_up
+    rows = tiles(plan.onehot_rows, 8)
+    if plan.kernel == "rows":
+        return 2 * rows * tiles(C, 128)
+    if plan.kernel == "wave":
+        NLp = wave_slot_pad(num_slots)
+        NLg = min(NLp, 128)
+        S = NLp // NLg
+        calls = max(len(plan.class_groups), 1)
+        return S * (2 * rows * tiles(C * NLg, 128)
+                    + calls * 2 * 8 * tiles(NLg, 128))
+    Bh, Bl = plan.hl_split
+    CS = C * num_slots
+    P = _hl_pack(num_features, Bh)
+    Wd = tiles(num_features * Bl * CS, 128)
+    return ((num_features // P) * 2 * tiles(P * Bh, 8)
+            * tiles(P * Bl * CS, 128)
+            + 2 * tiles(num_features + 1, 128) * Wd
+            + 2 * tiles(CS, 128) * Wd
+            + 2 * 8 * tiles(max(num_slots, 8), 128))
+
+
+def _kernel_metadata(plan: WaveKernelPlan, num_features: int,
+                     num_slots: int, C: int) -> dict:
+    """What a `pallas_call` says of itself in its custom-call's
+    `kernel_metadata`: the MXU FLOP a row it asks.  A frontend attribute
+    is part of the HLO and so of the compile cache's key, which ignores
+    the `op_name` labels: a program that differed from another in
+    `mxu_call_scope`'s labels alone would be handed that one's
+    executable, and its trace would carry no label (ROADMAP correction
+    (k)).  Not a `cost_estimate`: with one the compiler scheduled the
+    Higgs cells' program 0.1% slower (PERF.md section 6, PR 39)."""
+    return {"mxu_flop_per_row":
+            str(mxu_flop_per_row(plan, num_features, num_slots, C))}
+
+
 @functools.partial(jax.jit,
                    static_argnames=("max_bin", "num_slots", "out_slots",
                                     "row_tile"))
@@ -677,9 +781,10 @@ def build_histogram_wave_hl(binned_fm: jnp.ndarray, binned_rm: jnp.ndarray,
     F, n = binned_fm.shape
     C = gh.shape[0] - 1
     S = num_slots
-    Bh, Bl = plan_wave_kernel(F, max_bin, out_slots, S, C=C,
-                              row_tile=row_tile).hl_split
-    P = next((p for p in (4, 2, 1) if F % p == 0 and p * Bh <= 256), 1)
+    plan = plan_wave_kernel(F, max_bin, out_slots, S, C=C,
+                            row_tile=row_tile)._replace(kernel="wave_hl")
+    Bh, Bl = plan.hl_split
+    P = _hl_pack(F, Bh)
     if n % row_tile != 0:
         raise ValueError(f"n {n} not a multiple of row_tile {row_tile}")
     with global_timer.device_scope("Tree::hist_operands"):
@@ -699,6 +804,7 @@ def build_histogram_wave_hl(binned_fm: jnp.ndarray, binned_rm: jnp.ndarray,
         out_shape=[
             jax.ShapeDtypeStruct((F, Bh, Bl * C * S), jnp.float32),
             jax.ShapeDtypeStruct((8, S), jnp.float32)],
+        metadata=_kernel_metadata(plan, F, S, C),
         name="build_histogram_wave_hl",
     )(binned_fm, binned_rm, slot_row, gh)
     # [F, Bh, (bl, c, s)] -> [S, F, B, C], zero-padded to out_slots
@@ -729,9 +835,10 @@ def build_histogram_wave(binned_fm: jnp.ndarray, slot: jnp.ndarray,
     slot group whose output columns are (channel, slot) pairs.  The leaf-
     slot axis fills the MXU's 128-wide output dimension — a plain per-leaf
     histogram dot has C=2 output columns and idles most of the systolic
-    array.  The one-hot's construction was the cost floor *(old chip)*;
-    not re-measured, see ROADMAP S1 — so its volume (F*B*n per wave) is
-    built exactly once regardless of slot count.
+    array.  The one-hot (F*B*n per wave) is built exactly once
+    regardless of slot count and hides under the dots, which are what a
+    call costs (MXU-bound at every cell's shape: PR 39's probe,
+    `tools/hist_roof_probe.py`, PERF.md section 6).
     Exact per-slot row counts ride along as a second output — the
     mask column against the slot one-hot.  (TPU replacement for the CUDA
     per-leaf shared-memory kernels, cuda_histogram_constructor.cu:18.)
@@ -790,8 +897,6 @@ def build_histogram_wave(binned_fm: jnp.ndarray, slot: jnp.ndarray,
     with global_timer.device_scope("Tree::hist_operands"):
         slot_row = slot.reshape(1, n)
     _count_traced_call(plan.groups)
-    global_registry.set_gauge("hist_onehot_rows", plan.onehot_rows)
-    global_registry.set_gauge("hist_onehot_rows_unclassed", Fp * Bp)
     row_specs = [pl.BlockSpec((1, row_tile), lambda bg, g, i: (0, i)),
                  pl.BlockSpec((C + 1, row_tile), lambda bg, g, i: (0, i))]
     cnt_spec = pl.BlockSpec((8, NLp), lambda bg, g, i: (0, 0))
@@ -818,6 +923,9 @@ def build_histogram_wave(binned_fm: jnp.ndarray, slot: jnp.ndarray,
                                         lambda bg, g, i: (0, 0)), cnt_spec],
                 out_shape=[jax.ShapeDtypeStruct((Mk, S * C * NLg), acc_t),
                            cnt_shape],
+                metadata=_kernel_metadata(
+                    plan._replace(onehot_rows=Mk, class_groups=(group,)),
+                    Fk, num_slots, C),
                 name="build_histogram_wave",
             )(cols, slot_row, gh)
             if f0 == 0:
@@ -843,6 +951,7 @@ def build_histogram_wave(binned_fm: jnp.ndarray, slot: jnp.ndarray,
             out_shape=[
                 jax.ShapeDtypeStruct((Fp, Bp, S * C * NLg), acc_t),
                 cnt_shape],
+            metadata=_kernel_metadata(plan, F, num_slots, C),
             name="build_histogram_wave",
         )(binned_fm, slot_row, gh)
     # [Fp, Bp, (s, c, lg)] -> [NL, F, B, C]
@@ -875,23 +984,26 @@ def wave_histograms(binned_fm: jnp.ndarray, binned_rm: jnp.ndarray,
     histograms come back through `hist_inverse` (the order's inverse):
     every caller sees its own column order, whatever kernel ran.
     Returns (hist [num_slots, F, B, C] float32, counts [num_slots])."""
-    plan = plan_wave_kernel(binned_fm.shape[0], max_bin, num_slots,
-                            true_slots, int8=quant_scales is not None,
-                            C=gh.shape[0] - 1, hist_classes=hist_classes)
-    if plan.kernel == "wave_hl":
-        return build_histogram_wave_hl(
-            binned_fm, binned_rm, slot, gh, max_bin=max_bin,
-            num_slots=true_slots, out_slots=num_slots)
-    # Rt stays 512: 1024 is ~3% faster on small slot counts but exceeds
-    # the 16 MB scoped-VMEM limit at 128 slots
-    if plan.class_groups:
-        hist, cnt = build_histogram_wave(
-            binned_classed, slot, gh, max_bin=max_bin, num_slots=num_slots,
-            hist_classes=hist_classes)
-        return jnp.take(hist, hist_inverse, axis=1, mode="clip"), cnt
-    return build_histogram_wave(
-        binned_fm, slot, gh, max_bin=max_bin, num_slots=num_slots,
-        quant_bins=quant_bins, quant_scales=quant_scales)
+    F, C = binned_fm.shape[0], gh.shape[0] - 1
+    plan = plan_wave_kernel(F, max_bin, num_slots, true_slots,
+                            int8=quant_scales is not None, C=C,
+                            hist_classes=hist_classes)
+    # what the call asks of the MXU rides its kernel events' `op_name`
+    with mxu_call_scope(plan, F, num_slots, true_slots, C):
+        if plan.kernel == "wave_hl":
+            return build_histogram_wave_hl(
+                binned_fm, binned_rm, slot, gh, max_bin=max_bin,
+                num_slots=true_slots, out_slots=num_slots)
+        # Rt stays 512: 1024 is ~3% faster on small slot counts but
+        # exceeds the 16 MB scoped-VMEM limit at 128 slots
+        if plan.class_groups:
+            hist, cnt = build_histogram_wave(
+                binned_classed, slot, gh, max_bin=max_bin,
+                num_slots=num_slots, hist_classes=hist_classes)
+            return jnp.take(hist, hist_inverse, axis=1, mode="clip"), cnt
+        return build_histogram_wave(
+            binned_fm, slot, gh, max_bin=max_bin, num_slots=num_slots,
+            quant_bins=quant_bins, quant_scales=quant_scales)
 
 
 @functools.partial(jax.jit, static_argnames=("max_bin", "method", "row_chunk"))

@@ -389,9 +389,11 @@ def test_grow_program_names_its_kernels_and_scopes(grow_compiled):
         assert f"/{scope}/" in text, scope
 
 
-def test_sharded_wave_program_compiles_on_four_chips(topo):
+@pytest.fixture(scope="module")
+def sharded_compiled(topo):
     """`tree_learner=data`: the production shard_map (its own builder,
-    its own specs) with the Pallas kernel inside, histograms psum'd."""
+    its own specs) around the wave engine, compiled for the four chips
+    of the described host."""
     from lightgbm_tpu.parallel import (grow_params_for_mesh,
                                        make_sharded_wave_fn)
     mesh = Mesh(np.array(topo.devices), ("data",))
@@ -401,9 +403,88 @@ def test_sharded_wave_program_compiles_on_four_chips(topo):
     repl = NamedSharding(mesh, P())
     jitted = make_sharded_wave_fn(mesh).build(
         grow_params_for_mesh(_grow_params()), ())
-    hlo = jitted.lower(*_grow_args(row, by_row, repl)).compile().as_text()
+    return jitted.lower(*_grow_args(row, by_row, repl)).compile()
+
+
+def test_sharded_wave_program_compiles_on_four_chips(sharded_compiled):
+    """The Pallas kernel inside the shard_map, histograms psum'd."""
+    hlo = sharded_compiled.as_text()
     assert "tpu_custom_call" in hlo
     assert "all-reduce" in hlo
+
+
+def _kernel_calls(compiled):
+    """[(instruction text)] of the program's Pallas custom-calls.  (An
+    instruction may run over several lines of the text: a frontend
+    attribute's JSON.)"""
+    return [instr for instr in re.split(
+        r"\n(?=\s*(?:ROOT )?%[\w.\-]+ = )", compiled.as_text())
+        if 'custom_call_target="tpu_custom_call"' in instr]
+
+
+def _kernel_labels(compiled):
+    """[(instruction, (n, f, e) or None)] of the program's Pallas
+    custom-calls: the `Hist.mxu_n<n>_f<f>_e<e>` part in each one's
+    `op_name` (`ops/histogram.py mxu_call_scope`)."""
+    out = []
+    for instr in _kernel_calls(compiled):
+        name = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", instr).group(1)
+        part = re.search(r'op_name="[^"]*/Tree\.histogram/'
+                         r'Hist\.mxu_n(\d+)_f(\d+)_e(\d+)/[^"]*"', instr)
+        out.append((name, part and tuple(map(int, part.groups()))))
+    return out
+
+
+@pytest.mark.parametrize("program,rows_local", [
+    ("grow_compiled", N), ("sharded_compiled", N // 4)])
+def test_every_kernel_call_carries_what_it_asks_of_the_mxu(
+        request, program, rows_local):
+    """The benchmark's `hist_mxu_roofline` / `hist_mxu_padding` read each
+    kernel event's label; an event without one is work they do not see.
+    On one chip and under `shard_map` on four, every Pallas custom-call
+    of the grow program holds the part inside `Tree.histogram`, and the
+    ladder's labels are the 255-leaf tree's: 1, 1, 2, 4, 8 true slots
+    through `_hl`, 16 to 64 through the full kernel at 1,837,056 FLOP a
+    row, and the `while_loop`'s 128-slot wave, which names none."""
+    labels = _kernel_labels(request.getfixturevalue(program))
+    assert len(labels) >= 9
+    assert all(part is not None for _, part in labels), labels
+    by_kernel = {}
+    for name, part in labels:
+        by_kernel.setdefault(re.sub(r"\.\d+$", "", name), []).append(part)
+    assert sorted(by_kernel["build_histogram_wave_hl"]) == [
+        (2, 493568, 1), (2, 493568, 1), (4, 690176, 1), (8, 919552, 1),
+        (16, 1837056, 1)]
+    assert sorted(by_kernel["build_histogram_wave"]) == [
+        (32, 1837056, 1), (64, 1837056, 1), (128, 1837056, 1),
+        (256, 3672064, 1)]
+
+
+def test_each_kernel_call_holds_its_count_where_the_cache_key_sees_it(
+        grow_compiled):
+    """The compile cache's key ignores op metadata, so labels alone
+    would be hidden by another program's cached executable: each
+    `pallas_call` also states `mxu_flop_per_row` in its custom-call's
+    `kernel_metadata`, a frontend attribute, which the key holds.  It is
+    the `f` of the call's label."""
+    stated = [re.search(r'kernel_metadata=\{\s*"mxu_flop_per_row":"(\d+)"',
+                        instr) for instr in _kernel_calls(grow_compiled)]
+    assert len(stated) == 9 and all(stated)
+    assert [int(m.group(1)) for m in stated] == [
+        f for _, (_, f, _) in _kernel_labels(grow_compiled)]
+
+
+def test_cost_analysis_still_counts_no_kernel(grow_compiled):
+    """Whether XLA's cost analysis counts the kernels: it does not.
+    With `cost_estimate=` on the three `pallas_call`s it did (the
+    program's FLOP read 14.32e12, the labels' 14.28e12 and little else),
+    but the compiler's scheduler reads the estimate too, and the Higgs
+    cells' iteration came out 0.1% slower on the chip (PERF.md section 6,
+    PR 39), so it is left out: `observability/costmodel.py`'s `roofline`
+    events go on reporting an MFU without the kernels (ROADMAP D2)."""
+    kernels = sum(f * N for _, (_, f, _) in _kernel_labels(grow_compiled))
+    assert kernels == 13_617_152 * N
+    assert grow_compiled.cost_analysis()["flops"] < 0.01 * kernels
 
 
 HIGGS_N = 2_625_536     # the Higgs cells' padded rows a chip

@@ -295,3 +295,99 @@ def test_booster_hands_the_class_ordered_bins_to_every_tree(
             assert entry._cache_size() - lowered_before == 1
         models.append(booster.model_to_string())
     assert models[0] == models[1]
+
+
+# ------------------- tools/hist_roof_probe.py: the chip probe's variants
+def _probe_operands(codes, n, slots, seed=39):
+    rng = np.random.RandomState(seed)
+    binned = np.stack([rng.randint(0, c, n) for c in codes]).astype(np.uint8)
+    slot = np.where(rng.rand(n) < 0.8, rng.randint(0, slots, n),
+                    256).astype(np.int32)
+    mask = (rng.rand(n) < 0.9).astype(np.float32)
+    gh = np.stack([rng.randint(-16, 17, n) / 8.0 * mask,
+                   rng.randint(1, 17, n) / 8.0 * mask, mask])
+    return binned, slot, gh.astype(np.float32)
+
+
+def _numpy_onehot(binned, row_codes):
+    """[sum(row_codes), n]: column f's one-hot at `row_codes[f]` codes,
+    stacked as `_wave_kernel` stacks them."""
+    return np.concatenate([
+        (binned[f][None, :] == np.arange(c)[:, None])
+        for f, c in enumerate(row_codes)]).astype(np.float32)
+
+
+def _packed_or(onehot, n):
+    """The `build` variant's reduction of a one-hot [M, n]: any 1 over
+    the 128-lane pieces of the rows, two one-hot rows a 32-bit word as a
+    TPU packs bf16 (row 2k the low half: 1.0 is 0x3F80)."""
+    hit = onehot.reshape(len(onehot), n // 128, 128).max(axis=1)
+    hit = hit.astype(np.int32) * 0x3F80
+    return hit[0::2] | (hit[1::2] << 16)
+
+
+@pytest.mark.parametrize("codes,max_bin,slots", [
+    ((24,) * 5, 24, 16),                # one class: [5 x 24] in one block
+    ((5, 16, 17, 40, 33, 9), 40, 8),    # classes 16 x 3, 48 x 3: classed
+    ((40,) * 6, 40, 255)])              # two slot groups of 128
+def test_roof_probe_variants_are_the_kernels_blocks(interpret_pallas, codes,
+                                                    max_bin, slots):
+    """The probe times the kernel's dot without its one-hot and the
+    one-hot without its dot; that they ARE the kernel's is held here: the
+    `dot` variant fed the kernel's own one-hot (in place of its constant)
+    returns the kernel's histograms and counts bit for bit, and the
+    `build` variant's reduction is that one-hot's packed words OR-ed over
+    the 128-lane pieces of every row tile."""
+    from lightgbm_tpu.ops.histogram import (build_histogram_wave,
+                                            hist_classes_of, wave_slot_pad)
+    from tools.hist_roof_probe import variant
+    n = 1536                                       # shapes of no other test
+    classes, order = hist_classes_of(codes)
+    classed = len(classes) > 1
+    codes = np.asarray(codes)[order]
+    binned, slot, gh = _probe_operands(codes, n, slots)
+    kw = dict(max_bin=max_bin, num_slots=slots,
+              hist_classes=classes if classed else ())
+    args = (jnp.asarray(binned), jnp.asarray(slot), jnp.asarray(gh))
+    hist, cnt = build_histogram_wave(*args, **kw)
+    row_codes = (-(-codes // 16) * 16 if classed
+                 else np.full(len(codes), -(-max_bin // 8) * 8))
+    onehot = _numpy_onehot(binned, row_codes)
+    (out, cnt_v), = variant("dot", *args, **kw,
+                            onehot=jnp.asarray(onehot, jnp.bfloat16))
+    # [M, (s, c, lg)] -> [NL, M, C], against the kernel's [NL, F, B, C]
+    NLp = wave_slot_pad(slots)
+    NLg = min(NLp, 128)
+    out = np.asarray(out).reshape(len(onehot), NLp // NLg, 2, NLg)
+    out = out.transpose(1, 3, 0, 2).reshape(NLp, len(onehot), 2)[:slots]
+    r0 = 0
+    for f, c in enumerate(row_codes):
+        keep = min(int(c), max_bin)
+        assert np.array_equal(out[:, r0:r0 + keep],
+                              np.asarray(hist)[:, f, :keep]), f
+        r0 += int(c)
+    assert np.array_equal(np.asarray(cnt_v)[0, :slots], np.asarray(cnt))
+    assert np.asarray(hist).any()
+    built, = variant("build", *args, **kw)
+    assert np.array_equal(np.asarray(built), _packed_or(onehot, n))
+    # the constant-fed dot (what the chip times) runs in the same blocks
+    (const, _), = variant("dot", *args, **kw)
+    assert const.shape == (len(onehot), 2 * NLp) and np.asarray(const).any()
+
+
+def test_roof_probe_variants_run_in_feature_groups(interpret_pallas):
+    """The wide cell's path: 2,000... here 200 columns at 128 slots run
+    in the plan's feature groups, each variant over the same grid."""
+    from lightgbm_tpu.ops.histogram import plan_wave_kernel
+    from tools.hist_roof_probe import variant
+    F, B, n, slots = 200, 63, 1024, 128
+    plan = plan_wave_kernel(F, B, slots)
+    assert plan.groups > 1
+    binned, slot, gh = _probe_operands((B,) * F, n, slots)
+    args = (jnp.asarray(binned), jnp.asarray(slot), jnp.asarray(gh))
+    kw = dict(max_bin=B, num_slots=slots)
+    (out, cnt), = variant("dot", *args, **kw)
+    assert out.shape == (plan.onehot_rows, 256) and cnt.shape == (8, 128)
+    built, = variant("build", *args, **kw)
+    onehot = _numpy_onehot(binned, np.full(F, 64))
+    assert np.array_equal(np.asarray(built)[:F * 32], _packed_or(onehot, n))

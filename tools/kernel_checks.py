@@ -68,6 +68,12 @@ two minutes) runs check 7's two programs on the ranking cell's own plan
 minutes) runs check 9's two calls at the one-hot cell's and the ranking
 cell's own shapes (`[12, 11,000,832]` at 255 codes, `[137, 2,271,232]`
 at 63) and 8 / 64 / 128 slots: bits compared, ms a call of each.
+
+`python tools/kernel_checks.py --roof` (`tools/hist_roof_probe.py`; four
+minutes) names the unit that bounds the fused wave kernel: at each
+cell's kernel shape and 1 / 16 / 64 / 128 slots, ms a call of the kernel
+as it is, of its dot fed a constant one-hot, and of its one-hot built
+without a dot (ROADMAP S1 (1); PERF.md section 6, PR 39).
 """
 import os
 import sys
@@ -790,6 +796,9 @@ if __name__ == "__main__":
         time_rank_gradients()
     elif "--classed" in sys.argv[1:]:
         time_classed_kernel()
+    elif "--roof" in sys.argv[1:]:
+        from tools.hist_roof_probe import time_roof
+        time_roof()
     else:
         print(run_wide_checks() if "--wide" in sys.argv[1:]
               else run_checks())
