@@ -15,9 +15,10 @@ work:
      memory the same way),
   2. one vmapped gain scan over [NLp, F, B] (ref:
      feature_histogram.hpp:192 FindBestThreshold, batched over leaves),
-  3. one vectorized recolor pass (rows look up their leaf's split through a
-     single packed [NLp, 8] table row-gather; ref: dense_bin.hpp:346
-     SplitInner applied to all splitting leaves at once).
+  3. one recolour pass (ops/recolour.py: rows look up their leaf's split
+     in one small per-wave table, a row-tiled Pallas call with rows on
+     lanes; ref: dense_bin.hpp:346 SplitInner applied to all splitting
+     leaves at once).
 
 The wave loop is UNROLLED over ceil(log2(num_leaves)) rounds with a
 per-round slot bound (8, 16, ..., padded num_leaves), so early rounds pay
@@ -41,7 +42,6 @@ approximation for parity (feature_histogram.hpp:871-874).
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import math
 
@@ -51,6 +51,8 @@ import jax.numpy as jnp
 from ..ops.histogram import (plan_wave_kernel, snap_to_operand_grid,
                              spike_true_slots, wave_histograms,
                              wave_slot_pad)
+from ..ops.recolour import (pack_table, recolour_wave, recolour_xla,
+                            table_layout)
 from ..ops.split import (K_MIN_SCORE, SplitResult, cat_bitset_words,
                          find_best_split, find_best_split_dense)
 from .grow import (FeatureMeta, GrowParams, TreeArrays,
@@ -89,7 +91,6 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
     `binned_classed`: `binned[meta.hist_order]` (ops/histogram.py
     class_ordered), which a booster whose columns hold several
     `params.hist_classes` makes once and hands to every tree."""
-    from ..ops.split import MISSING_NAN, MISSING_ZERO
 
     if params.has_bundles:
         num_features = meta.num_bin.shape[0]
@@ -707,105 +708,44 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
             # one histogram pass over the rows per wave
             waves=t.waves + 1)
 
-        # 4. recolor rows: one packed table row-gather per row.  The table
-        # is [NLp, 8] numerical-only; the categorical columns (is_cat +
-        # bitset words) are appended only when the dataset has categorical
-        # features, keeping the hot gather narrow in the common case.
+        # 4. recolour rows (ops/recolour.py): the wave's per-leaf records
+        # as one small byte table, each row's record read from it by the
+        # one-hot of its leaf (no per-row XLA gather: 8.2 ns a row on a
+        # TPU; the prune's row remap and the score update read their
+        # tables the same way), its bin of the split column picked, its
+        # side taken.  With the Pallas kernels a record lives in VMEM and
+        # only the new leaf_id and kslot reach HBM; the XLA form is the
+        # same rule for a backend without them.
         # smaller side per split pair, chosen by the SCAN's (approximate,
         # RoundInt-parity) counts — either choice yields the same exact
         # pair of histograms by subtraction
         with global_timer.device_scope("Tree::partition"):
             small_left = best.left_count <= best.right_count
-            cols = [split_sel.astype(i32), best.feature, best.threshold,
-                    best.default_left.astype(i32), newleaf_of,
-                    jnp.take(meta.missing_type, best.feature),
-                    jnp.take(meta.default_bin, best.feature),
-                    jnp.take(meta.num_bin, best.feature),
-                    rank_of, small_left.astype(i32)]
+            layout = table_layout(
+                num_columns=binned.shape[0], max_bin=B, column_bins=hist_B,
+                num_slots=NLp, sentinel=Lp, has_bundles=params.has_bundles,
+                cat_words=W if sp.has_categorical else 0)
+            fields = dict(
+                split_sel=split_sel, column=best.feature,
+                threshold=best.threshold, default_left=best.default_left,
+                new_leaf=newleaf_of, rank=rank_of, small_left=small_left,
+                missing_type=jnp.take(meta.missing_type, best.feature),
+                default_bin=jnp.take(meta.default_bin, best.feature),
+                num_bin=jnp.take(meta.num_bin, best.feature))
             if params.has_bundles:
-                cols += [jnp.take(meta.group, best.feature),
-                         jnp.take(meta.offset, best.feature),
-                         jnp.take(meta.zero_bin, best.feature)]
-            n_base = len(cols)
+                fields.update(
+                    column=jnp.take(meta.group, best.feature),
+                    offset=jnp.take(meta.offset, best.feature),
+                    zero_bin=jnp.take(meta.zero_bin, best.feature))
             if sp.has_categorical:
-                # cat bitset words carry full 32-bit patterns: pre-split into
-                # positive 16-bit halves so the byte decomposition below stays
-                # exact
-                bs = best.cat_bitset
-                cols = (cols + [best.is_cat.astype(i32)]
-                        + [bs[:, w] & 0xFFFF for w in range(W)]
-                        + [(bs[:, w] >> 16) & 0xFFFF for w in range(W)])
-            packed = jnp.stack(cols, axis=1)                # [NLp, nc] < 2^24
-            # per-row table lookup as a one-hot MXU matmul instead of an XLA
-            # row gather (8.2 ns a row on a TPU): values are decomposed into
-            # bytes so the bf16 operands are exact, and each output sums
-            # exactly one nonzero product — bit-exact reconstruction.  The
-            # prune's row remap and the score update (boosting/
-            # leaf_lookup.py) read their tables by one-hot too: the training
-            # loop holds no per-row XLA gather
-            nc = packed.shape[1]
-            tab = jnp.concatenate([packed & 255, (packed >> 8) & 255,
-                                   (packed >> 16) & 255], axis=1)
-            oh_rows = (leaf_id[:, None] ==
-                       jnp.arange(NLp, dtype=i32)[None, :]).astype(
-                           jnp.bfloat16)
-            got = jax.lax.dot_general(
-                oh_rows, tab.astype(jnp.bfloat16),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)      # [n, 3*nc]
-            prow = (got[:, :nc].astype(i32)
-                    + (got[:, nc:2 * nc].astype(i32) << 8)
-                    + (got[:, 2 * nc:].astype(i32) << 16))
-            sel_r = prow[:, 0] > 0
-            feat_r = prow[:, 1]
-            thr_r = prow[:, 2]
-            dleft_r = prow[:, 3] > 0
-            new_r = prow[:, 4]
-            mt_r = prow[:, 5]
-            db_r = prow[:, 6]
-            nb_r = prow[:, 7]
-            rank_r = prow[:, 8]
-            sleft_r = prow[:, 9] > 0
-            if params.has_bundles:
-                grp_r = prow[:, 10]
-                off_r = prow[:, 11]
-                zb_r = prow[:, 12]
-                col_r = grp_r
-            else:
-                col_r = feat_r
-            # per-row bin of the row's split column (one-hot select over
-            # F'); under EFB the select is over bundle columns and the
-            # code is decoded to the feature's bin: Efb::route, a part of
-            # this scope (benchmarks' efb_route_ms)
-            with (global_timer.device_scope("Efb::route")
-                  if params.has_bundles else contextlib.nullcontext()):
-                fbin = jnp.sum(jnp.where(
-                    col_r[None, :] == jnp.arange(binned.shape[0],
-                                                 dtype=i32)[:, None],
-                    binned.astype(i32), 0), axis=0)
-                if params.has_bundles:
-                    local = fbin - off_r
-                    fbin = jnp.where((local >= 0) & (local < nb_r), local,
-                                     zb_r)
-            is_missing = (((mt_r == MISSING_NAN) & (fbin == nb_r - 1))
-                          | ((mt_r == MISSING_ZERO) & (fbin == db_r)))
-            go_left = jnp.where(is_missing, dleft_r, fbin <= thr_r)
-            if sp.has_categorical:
-                isc_r = prow[:, n_base] > 0
-                widx = jnp.clip(fbin // 32, 0, W - 1)[:, None]
-                w_lo = jnp.take_along_axis(
-                    prow[:, n_base + 1:n_base + 1 + W], widx, 1)[:, 0]
-                w_hi = jnp.take_along_axis(
-                    prow[:, n_base + 1 + W:n_base + 1 + 2 * W], widx, 1)[:, 0]
-                word_r = w_lo | (w_hi << 16)
-                cat_left = ((word_r >> (fbin % 32)) & 1) > 0
-                go_left = jnp.where(isc_r, cat_left, go_left)
-            leaf_id = jnp.where(sel_r & ~go_left, new_r, leaf_id)
-            # the NEXT wave's computed-slot assignment rides this recolor pass
-            # (no extra per-row gather): a row is in the computed set iff it
-            # landed in its pair's smaller child; everyone else gets the
-            # out-of-range sentinel Lp, which matches no slot one-hot bucket
-            kslot = jnp.where(sel_r & (go_left == sleft_r), rank_r, Lp)
+                fields.update(is_cat=best.is_cat,
+                              cat_bitset=best.cat_bitset)
+            # the NEXT wave's computed-slot assignment rides this pass: a
+            # row is in the computed set iff it landed in its pair's
+            # smaller child; everyone else gets the sentinel Lp
+            recolour = recolour_wave if use_pallas else recolour_xla
+            leaf_id, kslot = recolour(pack_table(layout, **fields), leaf_id,
+                                      binned, layout=layout)
 
         if sp.has_cegb:
             # all of this wave's winning features become used (coupled

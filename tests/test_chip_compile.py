@@ -163,18 +163,20 @@ def grow_compiled(one_chip):
 def test_grow_tree_wave_program_compiles_on_one_chip(grow_compiled):
     assert "tpu_custom_call" in grow_compiled.as_text()
     mem = grow_compiled.memory_analysis()
-    # the program's temporaries: 406,655,488 B at these 2^20 rows (388 B a
-    # row) plus 10%.  With gh [N, 3] and slot [N, 1] padded to 128 lanes
-    # it read 1,825,379,840 (1.74 KB a row): a rise to that is a per-row
-    # operand back in a padded layout (PERF.md section 4)
-    assert mem.temp_size_in_bytes < int(406_655_488 * 1.1), mem
+    # the program's temporaries: 201,573,888 B at these 2^20 rows (192 B a
+    # row) plus 10%.  Before the recolour was a kernel it read 340,418,048
+    # (the rows' records as f32 bytes and as int32 words: PERF.md, PR 40);
+    # with gh [N, 3] and slot [N, 1] padded to 128 lanes 1,825,379,840
+    # (1.74 KB a row): a rise to that is a per-row operand back in a
+    # padded layout (PERF.md section 4)
+    assert mem.temp_size_in_bytes < int(201_573_888 * 1.1), mem
 
 
 def test_wide_grow_program_compiles_on_one_chip(one_chip):
     """The whole-tree program at the wide cell's shape: every wave
     through the full kernel (the decomposed one has no feature grouping
     and wants 40 MB of VMEM at one slot), no row-major copy of the bins,
-    and temporaries of 2,027,847,168 B (the leaf cache [384, 256,000]
+    and temporaries of 820,994,560 B (the leaf cache [384, 256,000]
     f32 and its update's operands) plus 10%."""
     from lightgbm_tpu.learner.wave import grow_tree_wave
     compiled = grow_tree_wave.lower(
@@ -183,12 +185,12 @@ def test_wide_grow_program_compiles_on_one_chip(one_chip):
     text = compiled.as_text()
     calls = re.findall(r"^\s*%([\w.\-]+) = .*custom_call_target="
                        r'"tpu_custom_call"', text, re.M)
-    assert len(calls) >= 9
+    assert len(calls) >= 18             # a histogram and a recolour a wave
     assert {re.sub(r"\.\d+$", "", c) for c in calls} == {
-        "build_histogram_wave"}
+        "build_histogram_wave", "recolour_wave"}
     assert f"u8[{WIDE_N},{WIDE_F}]" not in text
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < int(2_027_847_168 * 1.1), mem
+    assert mem.temp_size_in_bytes < int(820_994_560 * 1.1), mem
 
 
 def test_bundled_grow_program_compiles_at_the_one_hot_shape(one_chip):
@@ -196,10 +198,13 @@ def test_bundled_grow_program_compiles_at_the_one_hot_shape(one_chip):
     (`expo-onehot700-b63.train_sparse`): 11,000,832 padded rows in 12
     uint8 bundle columns of up to 255 codes, 504 used features at 63
     bins, 255 leaves.  The kernels run the columns' shape (both of
-    them: 12 columns is one block for `_hl` too), the bundle decode and
-    the recolour's column select carry their parts, and the temporaries
-    are 4,176,885,248 B (380 B a row) plus 10%; PERF.md section 4 has
-    what the chip run reserved beside its buffers."""
+    them: 12 columns is one block for `_hl` too), the bundle decode
+    carries its part, the recolour is the kernel with the bundle route
+    inside it — NOT entered under `Efb::route`, which would report the
+    whole recolour as the route; the part is the XLA form's (below) —
+    and the temporaries are 1,757,799,424 B (160 B a row; 4,176,885,248,
+    380 B a row, with the rows' records in memory) plus 10%; PERF.md
+    section 4 has what the chip run reserved beside its buffers."""
     from lightgbm_tpu.learner import FeatureMeta
     from lightgbm_tpu.learner.wave import grow_tree_wave
     cols, used, rows, codes = 12, 504, 11_000_832, 255
@@ -218,14 +223,36 @@ def test_bundled_grow_program_compiles_at_the_one_hot_shape(one_chip):
     text = compiled.as_text()
     calls = re.findall(r"^\s*%([\w.\-]+) = .*custom_call_target="
                        r'"tpu_custom_call"', text, re.M)
-    assert len(calls) >= 9
+    assert len(calls) >= 18             # a histogram and a recolour a wave
     assert {re.sub(r"\.\d+$", "", c) for c in calls} == {
-        "build_histogram_wave", "build_histogram_wave_hl"}
-    for part in ("/Tree.split_find/Efb.decode/", "/Tree.partition/Efb.route/"):
-        assert part in text, part
+        "build_histogram_wave", "build_histogram_wave_hl", "recolour_wave"}
+    assert "/Tree.split_find/Efb.decode/" in text
+    assert "Efb.route" not in text
     assert "vmap(Efb." not in text      # no reader of parts matches that
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < int(4_176_885_248 * 1.1), mem
+    assert mem.temp_size_in_bytes < int(1_757_799_424 * 1.1), mem
+
+
+def test_xla_recolour_carries_the_bundle_route_part(one_chip):
+    """The recolour's XLA form (a backend without the Pallas kernels)
+    under bundles: the column select and the code's decode are the part
+    `Efb::route` of the caller's `Tree::partition`, which the benchmark's
+    `efb_route_ms` reads where that form runs."""
+    from lightgbm_tpu.ops.recolour import recolour_xla, table_layout
+    from lightgbm_tpu.utils.timer import global_timer
+    layout = table_layout(num_columns=12, max_bin=63, column_bins=255,
+                          num_slots=64, sentinel=256, has_bundles=True)
+
+    def partition(tab, leaf_id, binned):
+        with global_timer.device_scope("Tree::partition"):
+            return recolour_xla(tab, leaf_id, binned, layout=layout)
+
+    rows = 1 << 16
+    text = jax.jit(partition).lower(
+        _sds((layout.rows, 64), jnp.bfloat16, one_chip),
+        _sds((rows,), "int32", one_chip),
+        _sds((12, rows), "uint8", one_chip)).compile().as_text()
+    assert "/Tree.partition/Efb.route/" in text
 
 
 # the ranking and one-hot cells' device columns by code count
@@ -376,17 +403,70 @@ def test_grow_program_hands_the_kernels_unpadded_row_operands(grow_compiled):
 def test_grow_program_names_its_kernels_and_scopes(grow_compiled):
     """What the benchmark's trace readers hold on to: each Pallas
     custom-call is named after its kernel (`pallas_call(name=...)`; the
-    profiler's event name starts with the instruction's), 9 calls a tree,
-    and the ops around them carry the program's scopes in `op_name`."""
-    calls = re.findall(r"^\s*%([\w.\-]+) = .*custom_call_target="
-                       r'"tpu_custom_call"', grow_compiled.as_text(), re.M)
-    heads = sorted({re.sub(r"\.\d+$", "", c) for c in calls})
-    assert heads == ["build_histogram_wave", "build_histogram_wave_hl"]
-    assert len(calls) >= 9
+    profiler's event name starts with the instruction's), a histogram
+    and a recolour a wave, 9 waves a tree, and the ops around them carry
+    the program's scopes in `op_name`.  `recolour_wave` is not a
+    `^%build_histogram`: the benchmark reads it by its scope, under
+    `Tree.partition`, which the custom call's own `op_name` holds."""
+    calls = _kernel_calls(grow_compiled)
+    names = [re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", c).group(1)
+             for c in calls]
+    heads = sorted({re.sub(r"\.\d+$", "", c) for c in names})
+    assert heads == ["build_histogram_wave", "build_histogram_wave_hl",
+                     "recolour_wave"]
+    assert sum(n.startswith("recolour_wave") for n in names) == 9
+    assert len(calls) == 18
+    for name, call in zip(names, calls):
+        if name.startswith("recolour_wave"):
+            assert re.search(r'op_name="[^"]*/Tree\.partition/[^"]*"', call)
     text = grow_compiled.as_text()
     for scope in ("Tree.hist_operands", "Tree.histogram", "Tree.cache",
                   "Tree.split_find", "Tree.partition"):
         assert f"/{scope}/" in text, scope
+
+
+@pytest.mark.parametrize("F,rows,leaves,kw", [
+    (F, N, 8, {}), (F, N, 256, {}),
+    (WIDE_F, WIDE_N, 256, dict(max_bin=WIDE_B, column_bins=WIDE_B)),
+    (12, 11_000_832, 256, dict(max_bin=63, has_bundles=True)),
+    (F, N, 256, dict(cat_words=8))])
+def test_recolour_kernel_compiles(one_chip, F, rows, leaves, kw):
+    """`ops/recolour.py recolour_wave` at (28, 2^20) with 8 and 256
+    leaves, at the wide cell's 2,000 columns (eight column blocks of 256
+    on a second grid axis), at the one-hot cell's 12 bundle columns (nine one-byte fields; 32,768 rows a
+    step, the last block hanging over the 11,000,832) and with the
+    categorical bitset's 32 byte rows."""
+    from lightgbm_tpu.ops.recolour import (plan_recolour, recolour_wave,
+                                           table_layout)
+    layout = table_layout(**{**dict(num_columns=F, max_bin=B, column_bins=B,
+                                    num_slots=leaves, sentinel=256), **kw})
+    compiled = recolour_wave.lower(
+        _sds((layout.rows, leaves), jnp.bfloat16, one_chip),
+        _sds((rows,), "int32", one_chip),
+        _sds((F, rows), "uint8", one_chip), layout=layout).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert plan_recolour(F, rows) == {
+        F: (28, 32768), WIDE_F: (256, 8192), 12: (12, 32768)}[F]
+
+
+def test_grow_program_keeps_no_row_records_in_memory(grow_compiled):
+    """What the recolour cost before it was a kernel (PERF.md, PR 40):
+    every row's record written out as `f32[N, 30]` bytes and again as
+    `s32[N, 10]` words, and the words read back six times — 1.8 GB a
+    wave for 0.1 GB of rows.  Under `Tree.partition` the compiled
+    program now holds no 32-bit array with a row axis and another axis
+    over 1, in either order: the records live in the kernel's VMEM."""
+    instrs = re.split(r"\n(?=\s*(?:ROOT )?%[\w.\-]+ = )",
+                      grow_compiled.as_text())
+    assert len(instrs) > 1000                     # the split still reads
+    wide = re.compile(rf"= [fs]32\[(?:{N},(\d+)|(\d+),{N})\]")
+    kept = []
+    for instr in instrs:
+        m = wide.search(instr.split("\n")[0])
+        if (m and int(m.group(1) or m.group(2)) > 1
+                and re.search(r'op_name="[^"]*/Tree\.partition/', instr)):
+            kept.append(instr.strip()[:200])
+    assert not kept, kept
 
 
 @pytest.fixture(scope="module")
@@ -407,10 +487,14 @@ def sharded_compiled(topo):
 
 
 def test_sharded_wave_program_compiles_on_four_chips(sharded_compiled):
-    """The Pallas kernel inside the shard_map, histograms psum'd."""
+    """The Pallas kernels inside the shard_map — the histogram's and the
+    recolour's, each on its chip's rows — histograms psum'd."""
     hlo = sharded_compiled.as_text()
     assert "tpu_custom_call" in hlo
     assert "all-reduce" in hlo
+    assert len(re.findall(r"^\s*%recolour_wave[\w.\-]* = .*"
+                          r'custom_call_target="tpu_custom_call"', hlo,
+                          re.M)) == 9
 
 
 def _kernel_calls(compiled):
@@ -422,12 +506,19 @@ def _kernel_calls(compiled):
         if 'custom_call_target="tpu_custom_call"' in instr]
 
 
+def _hist_kernel_calls(compiled):
+    """`_kernel_calls` less the recolour's: the histogram kernels, which
+    the benchmark reads by the head `build_histogram`."""
+    return [instr for instr in _kernel_calls(compiled)
+            if re.match(r"\s*(?:ROOT )?%build_histogram", instr)]
+
+
 def _kernel_labels(compiled):
-    """[(instruction, (n, f, e) or None)] of the program's Pallas
+    """[(instruction, (n, f, e) or None)] of the program's histogram
     custom-calls: the `Hist.mxu_n<n>_f<f>_e<e>` part in each one's
     `op_name` (`ops/histogram.py mxu_call_scope`)."""
     out = []
-    for instr in _kernel_calls(compiled):
+    for instr in _hist_kernel_calls(compiled):
         name = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", instr).group(1)
         part = re.search(r'op_name="[^"]*/Tree\.histogram/'
                          r'Hist\.mxu_n(\d+)_f(\d+)_e(\d+)/[^"]*"', instr)
@@ -441,8 +532,9 @@ def test_every_kernel_call_carries_what_it_asks_of_the_mxu(
         request, program, rows_local):
     """The benchmark's `hist_mxu_roofline` / `hist_mxu_padding` read each
     kernel event's label; an event without one is work they do not see.
-    On one chip and under `shard_map` on four, every Pallas custom-call
-    of the grow program holds the part inside `Tree.histogram`, and the
+    On one chip and under `shard_map` on four, every histogram
+    custom-call of the grow program holds the part inside
+    `Tree.histogram`, and the
     ladder's labels are the 255-leaf tree's: 1, 1, 2, 4, 8 true slots
     through `_hl`, 16 to 64 through the full kernel at 1,837,056 FLOP a
     row, and the `while_loop`'s 128-slot wave, which names none."""
@@ -468,7 +560,7 @@ def test_each_kernel_call_holds_its_count_where_the_cache_key_sees_it(
     `kernel_metadata`, a frontend attribute, which the key holds.  It is
     the `f` of the call's label."""
     stated = [re.search(r'kernel_metadata=\{\s*"mxu_flop_per_row":"(\d+)"',
-                        instr) for instr in _kernel_calls(grow_compiled)]
+                        instr) for instr in _hist_kernel_calls(grow_compiled)]
     assert len(stated) == 9 and all(stated)
     assert [int(m.group(1)) for m in stated] == [
         f for _, (_, f, _) in _kernel_labels(grow_compiled)]

@@ -49,6 +49,13 @@ Checks (small shapes, seconds of chip time):
      shuffled column order (codes that reach their class's bound among
      them), at 8 and 128 slots
 
+  10. the recolour (`ops/recolour.py`): the row-tiled kernel == its XLA
+     form == the rule written out in NumPy, `leaf_id` and `kslot` bit for
+     bit, at 8 and 256 leaves: plain columns with all three missing
+     types, bundle columns (codes inside a member's range, outside it,
+     the zero bin), categorical bitsets, leaves that do not split, and
+     more columns than one block holds
+
 `run_wide_checks()` (`python tools/kernel_checks.py --wide`; a minute of
 chip time, so not part of `run_checks`) holds the fused wave kernel to a
 plain float32 reference at the widest benchmark cell's own shape,
@@ -74,6 +81,12 @@ minutes) names the unit that bounds the fused wave kernel: at each
 cell's kernel shape and 1 / 16 / 64 / 128 slots, ms a call of the kernel
 as it is, of its dot fed a constant one-hot, and of its one-hot built
 without a dot (ROADMAP S1 (1); PERF.md section 6, PR 39).
+
+`time_recolour()` (`python tools/kernel_checks.py --recolour`; two
+minutes) runs check 10's two forms at each cell's own shape (28 / 137 /
+2,000 plain columns, 12 bundle columns; 8 and 256 leaves): rows whose
+`leaf_id` or `kslot` differ between the kernel, the XLA form and NumPy,
+and ms a call of each form.
 """
 import os
 import sys
@@ -236,6 +249,13 @@ def run_checks():
     except Exception as e:    # noqa: BLE001 - named in the verdict
         traceback.print_exc()
         failures.append(f"classed_raised({type(e).__name__})")
+
+    # 10. the recolour: kernel, XLA form and the host's rule, bit for bit
+    try:
+        failures.extend(_recolour_mismatches())
+    except Exception as e:    # noqa: BLE001 - named in the verdict
+        traceback.print_exc()
+        failures.append(f"recolour_raised({type(e).__name__})")
 
     return "ok" if not failures else "fail:" + ",".join(failures)
 
@@ -706,6 +726,146 @@ def time_score_lookup(n=2_625_536,
         print(json.dumps(line), flush=True)
 
 
+def _recolour_case(F, n, leaves, bundles=False, cat_words=0, max_bin=255,
+                   column_bins=255, sentinel=256, seed=40):
+    """A wave's per-leaf records as `learner/wave.py` step 4 hands them
+    to `ops/recolour.py pack_table` (NumPy, `[leaves]` each), and the
+    rows: `leaf_id [n]`, `binned [F, n]` uint8.  A third of the leaves do
+    not split; missing types are none / zero / NaN in turn; under
+    bundles a member's range is `[offset, offset + num_bin)` of codes up
+    to `column_bins`, so codes fall inside it, outside it and on its
+    ends."""
+    rng = np.random.RandomState(seed)
+    i32 = np.int32
+    num_bin = rng.randint(2, max_bin + 1, leaves).astype(i32)
+    fields = dict(
+        split_sel=rng.rand(leaves) < 2 / 3,
+        column=rng.randint(0, F, leaves).astype(i32),
+        threshold=(rng.rand(leaves) * num_bin).astype(i32),
+        default_left=rng.rand(leaves) < 0.5,
+        new_leaf=rng.randint(0, sentinel, leaves).astype(i32),
+        rank=rng.randint(0, leaves, leaves).astype(i32),
+        small_left=rng.rand(leaves) < 0.5,
+        missing_type=(np.arange(leaves) % 3).astype(i32),
+        default_bin=(rng.rand(leaves) * num_bin).astype(i32),
+        num_bin=num_bin)
+    if bundles:
+        fields.update(
+            offset=rng.randint(0, column_bins - max_bin + 1,
+                               leaves).astype(i32),
+            zero_bin=(rng.rand(leaves) * num_bin).astype(i32))
+    if cat_words:
+        fields.update(
+            is_cat=rng.rand(leaves) < 0.5,
+            cat_bitset=rng.randint(-2 ** 31, 2 ** 31, (leaves, cat_words),
+                                   dtype=np.int64).astype(i32))
+    leaf_id = rng.randint(0, leaves, n).astype(i32)
+    binned = rng.randint(0, column_bins + 1 if bundles else max_bin,
+                         (F, n)).astype(np.uint8)
+    return fields, leaf_id, binned
+
+
+def _recolour_host(fields, leaf_id, binned, sentinel=256):
+    """The recolour's rule written out a row at a time in NumPy, from
+    the records themselves and not from the packed table: (leaf_id,
+    kslot) as `learner/wave.py` computed them before the rule moved to
+    `ops/recolour.py`."""
+    of = {k: v[leaf_id] for k, v in fields.items()}
+    fbin = binned[of["column"], np.arange(leaf_id.size)].astype(np.int32)
+    if "offset" in of:
+        local = fbin - of["offset"]
+        fbin = np.where((local >= 0) & (local < of["num_bin"]), local,
+                        of["zero_bin"])
+    is_missing = (((of["missing_type"] == 2) & (fbin == of["num_bin"] - 1))
+                  | ((of["missing_type"] == 1)
+                     & (fbin == of["default_bin"])))
+    go_left = np.where(is_missing, of["default_left"],
+                       fbin <= of["threshold"])
+    if "cat_bitset" in of:
+        W = of["cat_bitset"].shape[1]
+        word = np.take_along_axis(
+            of["cat_bitset"], np.clip(fbin // 32, 0, W - 1)[:, None], 1)[:, 0]
+        go_left = np.where(of["is_cat"], ((word >> (fbin % 32)) & 1) > 0,
+                           go_left)
+    sel = of["split_sel"]
+    return (np.where(sel & ~go_left, of["new_leaf"], leaf_id),
+            np.where(sel & (go_left == of["small_left"]), of["rank"],
+                     sentinel).astype(np.int32))
+
+
+def _recolour_forms(fields, leaf_id, binned, max_bin=255, column_bins=255,
+                    sentinel=256):
+    """[(name, fn)] of the recolour's two device forms on this case, each
+    `fn()` -> (leaf_id, kslot) on the device."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.recolour import (pack_table, recolour_wave,
+                                           recolour_xla, table_layout)
+    cat = fields.get("cat_bitset")
+    layout = table_layout(
+        num_columns=binned.shape[0], max_bin=max_bin,
+        column_bins=column_bins, num_slots=fields["rank"].size,
+        sentinel=sentinel, has_bundles="offset" in fields,
+        cat_words=0 if cat is None else cat.shape[1])
+    tab = pack_table(layout, **{k: jnp.asarray(v) for k, v in fields.items()})
+    rows = (jnp.asarray(leaf_id), jnp.asarray(binned))
+    xla = jax.jit(recolour_xla, static_argnames=("layout",))
+    return [("kernel", lambda: recolour_wave(tab, *rows, layout=layout)),
+            ("xla", lambda: xla(tab, *rows, layout=layout))]
+
+
+def _recolour_mismatches():
+    """Names of the cases in which a form of the recolour differs from
+    the host's rule in any row; [] when all agree."""
+    cases = {
+        "plain_5x8": dict(F=5, n=1536, leaves=8),
+        "plain_28x256": dict(F=28, n=2048, leaves=256),
+        "bundled_12x64": dict(F=12, n=2048, leaves=64, bundles=True,
+                              max_bin=63),
+        "categorical_7x16": dict(F=7, n=1024, leaves=16, cat_words=8),
+        "column_blocks_300x16": dict(F=300, n=1024, leaves=16)}
+    bad = []
+    for name, case in cases.items():
+        fields, leaf_id, binned = _recolour_case(**case)
+        want = _recolour_host(fields, leaf_id, binned)
+        for form, fn in _recolour_forms(fields, leaf_id, binned,
+                                        case.get("max_bin", 255)):
+            got = fn()
+            if not all(np.array_equal(np.asarray(g), w)
+                       for g, w in zip(got, want)):
+                bad.append(f"recolour_{name}_{form}")
+    return bad
+
+
+def time_recolour(calls=10):
+    """Check 10's forms at each cell's own shape: rows that differ from
+    the host's rule, ms a call."""
+    import time
+    import jax
+    shapes = (("higgs", dict(F=28, n=2_625_536)),
+              ("mslr", dict(F=137, n=2_271_232, max_bin=63)),
+              ("epsilon", dict(F=2000, n=400_384, max_bin=63)),
+              ("expo", dict(F=12, n=11_000_832, bundles=True, max_bin=63)))
+    for name, shape in shapes:
+        for leaves in (8, 256):
+            fields, leaf_id, binned = _recolour_case(leaves=leaves, **shape)
+            want = _recolour_host(fields, leaf_id, binned)
+            line = f"recolour {name} [{shape['F']}, {shape['n']}] leaves={leaves}:"
+            for form, fn in _recolour_forms(fields, leaf_id, binned,
+                                            shape.get("max_bin", 255)):
+                got = jax.block_until_ready(fn())
+                differ = [int((np.asarray(g) != w).sum())
+                          for g, w in zip(got, want)]
+                t0 = time.perf_counter()
+                for _ in range(calls):
+                    out = fn()
+                jax.block_until_ready(out)
+                ms = (time.perf_counter() - t0) / calls * 1e3
+                line += (f" {form} {ms:.3f} ms a call, rows that differ "
+                         f"from the host's rule (leaf_id, kslot) {differ};")
+            print(line, file=sys.stderr)
+
+
 def _wide_reference(binned_blk, slot, gh, B, slots):
     """[slots, fb, B, 2] float32 histograms of a block of features, as
     plain one-hot products in float32 (`highest`: the MXU's six-pass
@@ -796,6 +956,8 @@ if __name__ == "__main__":
         time_rank_gradients()
     elif "--classed" in sys.argv[1:]:
         time_classed_kernel()
+    elif "--recolour" in sys.argv[1:]:
+        time_recolour()
     elif "--roof" in sys.argv[1:]:
         from tools.hist_roof_probe import time_roof
         time_roof()
