@@ -463,12 +463,19 @@ class GBDT:
         # column, whatever the kernels pad it to (the benchmark's
         # `hist_mxu_roofline` counts useful MXU work from it)
         _metrics.inc("hist_codes", int(np.sum(column_codes)))
+        # categorical columns and their bins: what a scanned leaf's
+        # categorical scan must read (the benchmark's cat_scan_roofline)
+        cat_index = np.nonzero(self.f_is_cat)[0].astype(np.int32)
+        if len(cat_index):
+            _metrics.inc("cat_features", len(cat_index))
+            _metrics.inc("cat_bins", int(self.f_num_bin[cat_index].sum()))
         self.meta = FeatureMeta(
             num_bin=jnp.asarray(self.f_num_bin),
             missing_type=jnp.asarray(self.f_missing_type),
             default_bin=jnp.asarray(self.f_default_bin),
             penalty=jnp.asarray(penalty),
             is_cat=jnp.asarray(self.f_is_cat),
+            cat_index=jnp.asarray(cat_index) if len(cat_index) else None,
             monotone=jnp.asarray(mono),
             cegb_coupled=jnp.asarray(coupled),
             cegb_lazy=jnp.asarray(lazy),
@@ -503,7 +510,6 @@ class GBDT:
                 path_smooth=config.path_smooth,
                 has_categorical=bool(self.f_is_cat.any()),
                 has_missing=bool((self.f_missing_type != 0).any()),
-                cat_features=tuple(np.nonzero(self.f_is_cat)[0].tolist()),
                 max_cat_to_onehot=config.max_cat_to_onehot,
                 max_cat_threshold=config.max_cat_threshold,
                 cat_l2=config.cat_l2, cat_smooth=config.cat_smooth,
@@ -1461,6 +1467,9 @@ class GBDT:
         num_leaves = int(arrays.num_leaves)
         if num_leaves <= 1:
             return None
+        _metrics.inc("splits_total", num_leaves - 1)
+        _metrics.inc("cat_splits_total",
+                     int(np.count_nonzero(split_is_cat[:num_leaves - 1])))
         ds = self.train_data
         L = self.config.num_leaves
         tree = Tree(max(L, 2))

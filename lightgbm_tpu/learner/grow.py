@@ -56,6 +56,9 @@ class FeatureMeta(NamedTuple):
     default_bin: jnp.ndarray    # [F] int32
     penalty: jnp.ndarray        # [F] float32 (feature_contri)
     is_cat: jnp.ndarray = None  # [F] bool (None when no categorical)
+    # the categorical features' indices (int32 [F_cat]): data, so only
+    # their count is static in a program, never which columns they are
+    cat_index: jnp.ndarray = None
     monotone: jnp.ndarray = None  # [F] int32 -1/0/+1 (None when unused)
     cegb_coupled: jnp.ndarray = None  # [F] float32 coupled penalties
     cegb_lazy: jnp.ndarray = None     # [F] float32 lazy per-row penalties
@@ -542,7 +545,8 @@ def grow_tree_impl(binned: jnp.ndarray, grad: jnp.ndarray,
                                    meta.num_bin, meta.missing_type,
                                    meta.default_bin, meta.penalty, cm,
                                    sum_g, sum_h, cnt, parent_out, sp,
-                                   is_cat_feature=meta.is_cat, **kw)
+                                   is_cat_feature=meta.is_cat,
+                                   cat_index=meta.cat_index, **kw)
 
     # pow2 bucket ladder for the partitioned engine; the last bucket covers
     # the whole row range (used by the root split)

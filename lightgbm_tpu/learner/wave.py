@@ -283,7 +283,7 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
         return find_best_split(
             h, meta.num_bin, meta.missing_type, meta.default_bin,
             meta.penalty, cm, sg, sh, c, po, sp,
-            is_cat_feature=meta.is_cat, **kw)
+            is_cat_feature=meta.is_cat, cat_index=meta.cat_index, **kw)
 
     best_vm = jax.vmap(_best_one,
                        in_axes=(0, 0, 0, 0, 0,

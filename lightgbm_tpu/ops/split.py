@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from ..observability import global_registry
+from ..utils.timer import global_timer
 
 K_EPSILON = 1e-15  # ref: include/LightGBM/meta.h:54
 K_MIN_SCORE = -jnp.inf
@@ -77,10 +78,6 @@ class SplitParams(NamedTuple):
     # forward-scan candidates (hpp:1031), so False skips that scan at
     # trace time
     has_missing: bool = True
-    # static inner-feature indices of the categorical features: the scan
-    # (argsort + two sequential prefix scans) runs only over these rows,
-    # not all F features; () falls back to scanning every feature
-    cat_features: tuple = ()
     max_cat_to_onehot: int = 4
     # monotone constraints, basic mode (ref: monotone_constraints.hpp:465
     # BasicLeafConstraints; feature_histogram.hpp:758 GetSplitGains USE_MC):
@@ -523,7 +520,7 @@ def _choose_feature(per_feature, feature_penalty, col_mask, sum_g, sum_h,
     if cat is not None:
         # categorical features replace their numerical scan results;
         # double-guard with is_cat_f (a numerical feature listed in
-        # cat_features must keep its numerical result)
+        # cat_index must keep its numerical result)
         (ci, is_cat_f, cgain, clg, clh, clc, c_onehot, c_ohbin, c_fwd,
          c_plen, c_ub, c_order) = cat
         catset = jnp.zeros(num_features, bool).at[ci].set(True) & is_cat_f
@@ -660,6 +657,7 @@ def find_best_split(hist: jnp.ndarray, num_bin: jnp.ndarray,
                     mono_penalty: jnp.ndarray = None,
                     cegb_lazy_cost: jnp.ndarray = None,
                     rand_cat_u: jnp.ndarray = None,
+                    cat_index: jnp.ndarray = None,
                     return_feature_gains: bool = False) -> SplitResult:
     """Scan all (feature, threshold, direction) candidates; return the leaf's best.
 
@@ -678,6 +676,8 @@ def find_best_split(hist: jnp.ndarray, num_bin: jnp.ndarray,
         ref: feature_histogram.hpp:169 FindBestThreshold).
       num_data: actual row count in leaf (int32).
       parent_output: leaf's current output (for path smoothing).
+      cat_index: [F_cat] int32, the categorical features' indices
+        (`FeatureMeta.cat_index`); None scans every feature as one.
     """
     _count_traced_scan("generic")
     num_features, max_bin, _ = hist.shape
@@ -702,18 +702,24 @@ def find_best_split(hist: jnp.ndarray, num_bin: jnp.ndarray,
     cat = None
     if params.has_categorical:
         # the expensive scan (argsort + two sequential prefix scans) runs
-        # only over the categorical rows, gathered into a static
-        # F_cat-sized subarray; results scatter back into the [F] arrays
-        cat_idx = (params.cat_features if params.cat_features
-                   else tuple(range(num_features)))
-        ci = jnp.asarray(cat_idx, jnp.int32)
-        cat = (ci, is_cat_feature) + _cat_best_split(
-            hist[ci, :, 0], hist[ci, :, 1],
-            num_data.astype(jnp.float32) / sum_h,
-            num_bin[ci], sum_g, sum_h, num_data, parent_output,
-            _min_gain_shift(sum_g, sum_h, num_data, parent_output, params),
-            params,
-            rand_u=None if rand_cat_u is None else rand_cat_u[ci])
+        # only over the categorical rows, gathered into an [F_cat, B]
+        # subarray; results scatter back into the [F] arrays.  Which
+        # columns they are is data and only their count is static, so
+        # two column orders of one data set are one program.  `Cat::scan`
+        # is a part of the caller's `Tree::split_find`; entered in this
+        # jitted body, a vmap over leaves reaches the ops' names as
+        # `vmap(jit(find_best_split))/Cat.scan`, which a part reader matches
+        with global_timer.device_scope("Cat::scan"):
+            ci = (jnp.arange(num_features, dtype=jnp.int32)
+                  if cat_index is None else cat_index.astype(jnp.int32))
+            cat = (ci, is_cat_feature) + _cat_best_split(
+                hist[ci, :, 0], hist[ci, :, 1],
+                num_data.astype(jnp.float32) / sum_h,
+                num_bin[ci], sum_g, sum_h, num_data, parent_output,
+                _min_gain_shift(sum_g, sum_h, num_data, parent_output,
+                                params),
+                params,
+                rand_u=None if rand_cat_u is None else rand_cat_u[ci])
 
     clamp_winner = None
     if params.has_monotone:

@@ -79,9 +79,11 @@ def voting_hist_elect(binned, gh, member_mask, col_mask, parent_output,
     f32 = jnp.float32
     is_cat = (meta.is_cat if meta.is_cat is not None
               else jnp.zeros_like(meta.num_bin, bool))
+    cat_index = (meta.cat_index if meta.cat_index is not None
+                 else jnp.arange(meta.num_bin.shape[0], dtype=jnp.int32))
 
     def local_fn(b_l, gh_l, mask_l, num_bin, missing_type, default_bin,
-                 penalty, is_cat_f, cm, parent_out):
+                 penalty, is_cat_f, cat_index, cm, parent_out):
         # local leaf sums + histogram over this device's row shard
         hist_l = build_histogram(b_l, gh_l, mask_l, max_bin=max_bin,
                                  method=hist_method)
@@ -91,7 +93,7 @@ def voting_hist_elect(binned, gh, member_mask, col_mask, parent_output,
         gains = find_best_split(
             hist_l, num_bin, missing_type, default_bin,
             penalty, cm, sum_g_l, sum_h_l, cnt_l, parent_out,
-            sp_local, is_cat_feature=is_cat_f,
+            sp_local, is_cat_feature=is_cat_f, cat_index=cat_index,
             return_feature_gains=True)                      # [F]
         # count-weighted gain (ref: GlobalVoting :165: gain * count/mean)
         cnt_g = jax.lax.psum(cnt_l, axis)
@@ -125,8 +127,8 @@ def voting_hist_elect(binned, gh, member_mask, col_mask, parent_output,
     return jax.shard_map(
         local_fn, mesh=spec.mesh,
         in_specs=(P(None, axis), P(axis, None), P(axis),
-                  repl, repl, repl, repl, repl, repl, repl),
+                  repl, repl, repl, repl, repl, repl, repl, repl),
         out_specs=(P(), P()), check_vma=False)(
             binned, gh, member_mask, meta.num_bin, meta.missing_type,
-            meta.default_bin, meta.penalty, is_cat, col_mask,
+            meta.default_bin, meta.penalty, is_cat, cat_index, col_mask,
             jnp.asarray(parent_output, f32))

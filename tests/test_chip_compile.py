@@ -75,12 +75,12 @@ def _grow_args(row, by_row, repl, F=F, N=N):
             _sds((F,), "bool", repl), meta)
 
 
-def _grow_params(max_bin=B, **kw):
+def _grow_params(max_bin=B, split=None, **kw):
     from lightgbm_tpu.learner import GrowParams
     from lightgbm_tpu.ops.split import SplitParams
     return GrowParams(num_leaves=LEAVES, max_bin=max_bin,
                       hist_method="pallas",
-                      split=SplitParams(min_data_in_leaf=20), **kw)
+                      split=split or SplitParams(min_data_in_leaf=20), **kw)
 
 
 @pytest.mark.parametrize("num_slots", [8, 64, 255])
@@ -287,7 +287,17 @@ def test_classed_wave_kernel_compiles_within_scoped_vmem(one_chip, codes,
     assert compiled.as_text().count("tpu_custom_call") >= plan.groups
 
 
-def test_grow_program_is_one_for_two_column_orders(one_chip):
+# the categorical cell (`expo-cat8-b63.train_cat`): 8 columns, 6 of them
+# categorical with up to 189 bins (the 99%-coverage rule), at Expo's rows
+CAT_CODES, CAT_COLUMNS = (13, 32, 8, 23, 189, 170, 63, 63), 6
+
+
+@pytest.mark.parametrize("codes,rows,max_bin,cat,onehot_rows,full,hl,temp", [
+    (MSLR_CODES, MSLR_N, 63, 0, 7392, 7, 2, None),
+    (CAT_CODES, EXPO_N, 189, CAT_COLUMNS, 592, 7, 2, 1_773_102_080)])
+def test_grow_program_is_one_for_two_column_orders(one_chip, codes, rows,
+                                                   max_bin, cat, onehot_rows,
+                                                   full, hl, temp):
     """What refused PR 37: the grow program at the ranking cell's shape,
     lowered from the code counts of two column orders (the 45 few-code
     columns at other indices, as `--seed` moves them), is the same
@@ -295,37 +305,59 @@ def test_grow_program_is_one_for_two_column_orders(one_chip):
     the data in `FeatureMeta.hist_order` / `hist_inverse` and in the
     class-ordered copy of the bins — so the second order finds the
     first's executable in the compile cache.  It compiles, the full
-    kernel classed (`f32[7392, .]` results) beside the decomposed one."""
+    kernel classed (`f32[7392, .]` results) beside the decomposed one.
+
+    The categorical cell's shape too (PR 41): `uint8[8, 11,000,832]`,
+    255 leaves, the six categorical columns' indices data
+    (`FeatureMeta.cat_index`) and only their count static, 6 bitset
+    words; the scan carries its part `Cat.scan` under
+    `Tree.split_find`, the recolour is the kernel with the bitset route
+    inside it, and the temporaries are `temp` B plus 10%."""
     from lightgbm_tpu.learner import FeatureMeta
     from lightgbm_tpu.learner.wave import grow_tree_wave
     from lightgbm_tpu.ops.histogram import hist_classes_of
-    F = len(MSLR_CODES)
+    from lightgbm_tpu.ops.split import SplitParams, cat_bitset_words
+    F = len(codes)
     by_col = {k: _sds((F,), "int32", one_chip)
               for k in ("num_bin", "missing_type", "default_bin",
                         "hist_order", "hist_inverse")}
+    if cat:
+        by_col.update(is_cat=_sds((F,), "bool", one_chip),
+                      cat_index=_sds((cat,), "int32", one_chip))
     meta = FeatureMeta(penalty=_sds((F,), "float32", one_chip), **by_col)
-    row = _sds((MSLR_N,), "float32", one_chip)
-    bins = _sds((F, MSLR_N), "uint8", one_chip)
+    row = _sds((rows,), "float32", one_chip)
+    bins = _sds((F, rows), "uint8", one_chip)
     args = (bins, row, row, row, _sds((F,), "bool", one_chip), meta)
+    split = SplitParams(min_data_in_leaf=20, has_categorical=bool(cat))
     texts, orders = [], []
     for seed in (1, 2):
-        codes = np.random.RandomState(seed).permutation(MSLR_CODES)
-        classes, order = hist_classes_of(codes)
+        order_codes = np.random.RandomState(seed).permutation(codes)
+        classes, order = hist_classes_of(order_codes)
         orders.append(order)
         lowered = grow_tree_wave.lower(
-            *args, params=_grow_params(max_bin=63, hist_classes=classes),
+            *args, params=_grow_params(max_bin=max_bin, hist_classes=classes,
+                                       split=split),
             binned_classed=bins)    # as a booster hands it over
         texts.append(lowered.as_text())
     assert not np.array_equal(*orders)
     assert texts[0] == texts[1]
-    text = lowered.compile().as_text()
+    compiled = lowered.compile()
+    text = compiled.as_text()
     calls = re.findall(r"^\s*%([\w.\-]+) = \((f32\[[\d,]+\]).*"
                        r'custom_call_target="tpu_custom_call"', text, re.M)
-    full = [shape for name, shape in calls
-            if name.startswith("build_histogram_wave.")]
-    assert len(full) == 7 and all(s.startswith("f32[7392,") for s in full)
+    kernels = [shape for name, shape in calls
+               if name.startswith("build_histogram_wave.")]
+    assert len(kernels) == full and all(
+        s.startswith(f"f32[{onehot_rows},") for s in kernels)
     assert sum(name.startswith("build_histogram_wave_hl.")
-               for name, _ in calls) == 2
+               for name, _ in calls) == hl
+    if cat:
+        assert cat_bitset_words(max_bin) == 6
+        assert "/Tree.split_find/" in text and "/Cat.scan/" in text
+        assert "vmap(Cat." not in text      # no reader of parts matches that
+        assert re.search(r"/Tree\.split_find/[^\"]*/Cat\.scan/", text)
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < int(temp * 1.1), mem
 
 
 def test_bucketize_program_compiles_at_the_wide_shape(one_chip):

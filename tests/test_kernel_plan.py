@@ -33,6 +33,12 @@ MSLR_63 = (137, 63)
 MSLR_CLASSES = ((16, 24), (32, 7), (64, 106))
 EXPO_CLASSES = ((16, 2), (32, 1), (48, 2), (64, 3), (128, 1), (208, 1),
                 (256, 2))
+# the categorical cell (`expo-cat8-b63.train_cat`): the same rows as 8
+# columns, 6 categoricals whose 99%-coverage rule takes two past 63 bins
+# (the issue's scratch run: 189 and 170), so the kernel's max_bin is 189
+CAT_CODES = (13, 32, 8, 23, 189, 170, 63, 63)
+CAT_8 = (8, max(CAT_CODES))
+CAT_CLASSES = ((16, 2), (32, 2), (64, 2), (176, 1), (192, 1))
 
 
 def _ladder_plan(shape, true_slots, **kw):
@@ -141,8 +147,11 @@ def test_classes_are_the_sorted_multiset_of_the_columns_codes():
     (MSLR_63, MSLR_CLASSES, 7392, (1,)),
     # 1,200 for 3,072: the 2-, 4- and 8-slot waves leave `_hl`, whose
     # 64 / 80 / 96 lane-units are over 0.6 x 100 codes a column
-    (EXPO_BUNDLED, EXPO_CLASSES, 1200, (1,))])
-def test_classed_plans_of_the_two_cells(shape, classes, rows, hl_slots):
+    (EXPO_BUNDLED, EXPO_CLASSES, 1200, (1,)),
+    # 592 for 1,536: `_hl` keeps the 1-slot waves, the full kernel the
+    # rest, in one block
+    (CAT_8, CAT_CLASSES, 592, (1,))])
+def test_classed_plans_of_the_cells(shape, classes, rows, hl_slots):
     for ts in LADDER:
         plan = _ladder_plan(shape, ts, hist_classes=classes)
         assert plan.fits
@@ -264,7 +273,7 @@ def _growth(shape=HIGGS_255, **kw):
 @pytest.mark.parametrize("shape,row_mesh", [
     (HIGGS_255, False), (HIGGS_63, False), (EPSILON_63, False),
     (HIGGS_255, True),      # the four dense configurations; dp4 shards rows
-    (EXPO_BUNDLED, False), (EXPO_BUNDLED_13, False)])
+    (EXPO_BUNDLED, False), (EXPO_BUNDLED_13, False), (CAT_8, False)])
 def test_a_tpu_takes_wave_and_pallas_for_every_configuration(shape,
                                                              row_mesh):
     assert _growth(shape, row_mesh=row_mesh) == (
@@ -410,11 +419,16 @@ def _grow_call_signature(g):
     return seen[0]
 
 
-@pytest.mark.parametrize("codes,max_bin,classes,rows", [
-    (MSLR_CODES, 63, MSLR_CLASSES, 3000),
-    (EXPO_CODES, 255, EXPO_CLASSES, 8000)])
+@pytest.mark.parametrize("codes,max_bin,classes,rows,categorical", [
+    (MSLR_CODES, 63, MSLR_CLASSES, 3000, ()),
+    (EXPO_CODES, 255, EXPO_CLASSES, 8000, ()),
+    # the categorical cell's columns: six categoricals of 12 / 31 / 7 /
+    # 22 / 188 / 169 codes (a bin each, and the "other" bin 0, which the
+    # 99%-coverage rule gives the last of the two wide ones' codes)
+    (CAT_CODES, 63, CAT_CLASSES, 8000, (0, 1, 2, 3, 4, 5))])
 def test_column_order_reaches_the_grow_program_as_data(codes, max_bin,
-                                                       classes, rows):
+                                                       classes, rows,
+                                                       categorical):
     """PR 37 was refused for this: the benchmark's `--seed` permutes the
     feature columns, a per-column layout static in column order made
     every seed a new program, and `setup_s` paid a cold compile (38.6 s
@@ -422,22 +436,37 @@ def test_column_order_reaches_the_grow_program_as_data(codes, max_bin,
     permutations of one table hold EQUAL `GrowParams` (the jitted
     entry's static argument) and hand the grow entry arguments of equal
     structure, shapes and dtypes: one trace, one executable, one entry
-    in the compile cache.  Only `hist_order` / `hist_inverse` differ."""
+    in the compile cache.  Only `hist_order` / `hist_inverse` differ,
+    and under categorical columns (handed over as `categorical_feature`
+    where the order puts them) `cat_index`, whose LENGTH alone is
+    static: until PR 41 their indices were a static tuple."""
     X = _table_with_codes(codes, rows)
+    for c in categorical:
+        X[:, c] = np.arange(rows) % (codes[c] - 1)  # each category evenly
     y = (X[:, 0] > np.median(X[:, 0])).astype(np.float32)
     params = {"objective": "binary", "num_leaves": 15, "max_bin": max_bin,
               "min_data_in_bin": 1, "min_data_in_leaf": 5,
               "tpu_growth_strategy": "wave", "verbosity": -1}
-    boosters = []
+    boosters, num_bin = [], None
     for seed in (None, 1, 2):
         order = (np.arange(len(codes)) if seed is None
                  else np.random.RandomState(seed).permutation(len(codes)))
+        where = np.argsort(order)
+        cats = sorted(int(where[c]) for c in categorical)
         g = lgb.Booster(params=params, train_set=lgb.Dataset(
-            X[:, order], label=y, params=params))._gbdt
-        assert g.f_num_bin.tolist() == [codes[i] for i in order]
+            X[:, order], label=y, params=params,
+            categorical_feature=cats))._gbdt
+        num_bin = num_bin or g.f_num_bin.tolist()
+        assert g.f_num_bin.tolist() == [num_bin[i] for i in order]
+        assert (g.meta.cat_index is None if not categorical
+                else np.asarray(g.meta.cat_index).tolist() == cats)
         boosters.append((g, _grow_call_signature(g)))
     first, signature = boosters[0]
+    # the 99%-coverage rule gives the two wide categoricals' last code to
+    # the "other" bin; every other column keeps its codes
+    assert num_bin == [b - (bool(categorical) and b > 100) for b in codes]
     assert first.grow_params.hist_classes == classes
+    assert first.grow_params.split.has_categorical == bool(categorical)
     assert first.growth_strategy == "wave"
     orders = set()
     for g, sig in boosters:

@@ -396,8 +396,9 @@ def test_per_leaf_inputs_trace_the_generic_scan(mode):
     if mode == "leafwise":
         fn = grow_tree_impl
     elif mode == "categorical":
-        sp = sp._replace(has_categorical=True, cat_features=(1,))
-        meta_kw = dict(is_cat=sds((F,), "bool"))
+        sp = sp._replace(has_categorical=True)
+        meta_kw = dict(is_cat=sds((F,), "bool"),
+                       cat_index=sds((1,), "int32"))
     elif mode == "monotone":
         sp = sp._replace(has_monotone=True)
         meta_kw = dict(monotone=sds((F,), "int32"))

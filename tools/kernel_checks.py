@@ -866,6 +866,164 @@ def time_recolour(calls=10):
             print(line, file=sys.stderr)
 
 
+# the categorical cell's columns (`expo-cat8-b63.train_cat`): bins a
+# categorical column after upstream's 99%-coverage rule, the kernel's
+# `max_bin` (the largest) and the configuration's categorical parameters
+CAT_WIDTHS = (13, 32, 8, 23, 189, 170)
+CAT_PARAMS = {"min_data_in_leaf": 20, "min_sum_hessian_in_leaf": 1e-3,
+              "lambda_l2": 0.0, "max_cat_to_onehot": 4,
+              "max_cat_threshold": 32, "cat_smooth": 10, "cat_l2": 10,
+              "min_data_per_group": 100}
+
+
+def _cat_scan_case(num_bin, rows, rare, seed):
+    """One categorical column of one leaf as tree 0's root sees it: raw
+    categories 0 .. num_bin - 2 kept in bins 1 .. num_bin - 1, skewed
+    counts, a binary label whose rate moves with the category, the
+    gradients of that label at a constant score and one hessian for every
+    row.  With `rare`, a third of the kept categories hold fewer rows
+    than `cat_smooth` and 1% of the rows are of categories the map does
+    not keep ("other", bin 0).  Returns (x, g, h, spec) with `spec` the
+    column's bin map as `categorical_first_tree` takes it."""
+    from benchmarks.references.categorical_first_tree import (
+        _first_tree_gradients)
+    rng = np.random.RandomState(seed)
+    k = num_bin - 1
+    cnt = rng.multinomial(rows, rng.dirichlet(np.full(k, 0.7)))
+    if rare:
+        few = rng.choice(k, max(k // 3, 1), replace=False)
+        cnt[few] = rng.randint(1, CAT_PARAMS["cat_smooth"], few.size)
+    x = np.repeat(np.arange(k), cnt)
+    if rare:
+        x = np.concatenate([x, k + rng.randint(0, 10, rows // 100)])
+    x = x[rng.permutation(x.size)].astype(np.float64)
+    rate = 1.0 / (1.0 + np.exp(-(rng.randn(k + 10) - 1.4)))
+    y = (rng.rand(x.size) < rate[x.astype(np.int64)]).astype(np.float32)
+    _, g, h = _first_tree_gradients(y)
+    spec = {"num_bin": num_bin, "cat_to_bin": {c: c + 1 for c in range(k)},
+            "bin_to_cat": [-1] + list(range(k))}
+    return x, g, h, spec
+
+
+def _cat_scan_program(hists, num_bin, sums, n, B, mapped):
+    """`ops/split.py find_best_split` on leaves of one categorical column
+    each ([N, 1, B, 2]): a leaf a call, as the leaf-wise engine calls it,
+    or with the leaf axis mapped, as the wave engine's `vmap` does."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.split import SplitParams, find_best_split
+    keys = ("min_data_in_leaf", "min_sum_hessian_in_leaf", "lambda_l2",
+            "max_cat_to_onehot", "max_cat_threshold", "cat_smooth",
+            "cat_l2", "min_data_per_group")
+    sp = SplitParams(has_categorical=True,
+                     **{k: CAT_PARAMS[k] for k in keys})
+    one = jnp.ones(1, jnp.int32)
+
+    def scan(h, sg, sh, cnt):
+        return find_best_split(
+            h, num_bin * one, 2 * one, one, jnp.ones(1, jnp.float32),
+            jnp.ones(1, bool), sg, sh, cnt, jnp.zeros((), jnp.float32), sp,
+            is_cat_feature=jnp.ones(1, bool), cat_index=0 * one)
+    args = (jnp.asarray(hists), jnp.asarray(sums[:, 0], jnp.float32),
+            jnp.asarray(sums[:, 1], jnp.float32), jnp.asarray(n, jnp.int32))
+    if mapped:
+        return jax.jit(jax.vmap(scan))(*args)
+    out = [jax.jit(scan)(*(a[i] for a in args)) for i in range(len(n))]
+    return jax.tree_util.tree_map(lambda *v: jnp.stack(v), *out)
+
+
+def _cat_scan_mismatches(widths=CAT_WIDTHS, rows=20_000, leaves=4, B=None,
+                         seed=41):
+    """Names of the cases in which the categorical scan's choice differs
+    from the float64 rule of `benchmarks/references/
+    categorical_first_tree.py` (written from upstream's description, not
+    from `ops/split.py`): the left category set (short of an exact tie),
+    its count exactly, the gain to 1e-4; [] when all agree.  `leaves`
+    cases a width, half of them with rare and "other" categories, on both
+    engines' ways of calling the scan."""
+    from benchmarks.references.categorical_first_tree import (
+        EXACT_TIE_REL, _candidates_categorical)
+    B = B or max(widths)
+    bad = []
+    for nb in widths:
+        hists = np.zeros((leaves, 1, B, 2), np.float32)
+        sums, n, want = np.zeros((leaves, 2)), [], []
+        for i in range(leaves):
+            x, g, h, spec = _cat_scan_case(nb, rows, i % 2 == 1,
+                                           seed + 97 * nb + i)
+            codes = np.where(x < nb - 1, x + 1, 0).astype(np.int64)
+            hists[i, 0, :, 0] = np.bincount(codes, g, B)
+            hists[i, 0, :, 1] = np.bincount(codes, h, B)
+            sums[i] = g.sum(), h.sum()
+            n.append(x.size)
+            cands = _candidates_categorical(x, spec, g, h, g.sum(), h.sum(),
+                                            x.size, CAT_PARAMS)
+            want.append(cands)
+        for mapped in (False, True):
+            got = _cat_scan_program(hists, nb, sums, np.array(n), B, mapped)
+            engine = "wave" if mapped else "leafwise"
+            for i, cands in enumerate(want):
+                name = f"cat_scan_{nb}bins_leaf{i}_{engine}"
+                if not cands:
+                    if float(got.gain[i]) > 0:
+                        bad.append(name + "_split_where_none")
+                    continue
+                best = max(c[0] for c in cands)
+                words = np.asarray(got.cat_bitset[i]).astype(np.int64)
+                cats = {b - 1 for b in range(1, nb)
+                        if (words[b // 32] >> (b % 32)) & 1}
+                # the program's set among the rule's candidates: the best,
+                # or one that ties it exactly (a set and its complement
+                # are one partition, reached from either end)
+                mine = [c for c in cands if c[2] == cats]
+                if not bool(got.is_cat[i]):
+                    bad.append(name + "_not_categorical")
+                if not mine or best - mine[0][0] > EXACT_TIE_REL * best:
+                    bad.append(name + "_set")
+                elif int(got.left_count[i]) != int(mine[0][1]):
+                    bad.append(name + "_left_count")
+                if abs(float(got.gain[i]) - best) > 1e-4 * abs(best):
+                    bad.append(name + "_gain")
+    return bad
+
+
+def time_categorical(calls=10):
+    """The categorical cell's two chip checks: the recolour's bitset route
+    at its shape (8 columns of up to 189 bins, 11,000,832 rows, 6 bitset
+    words; 8 / 64 / 256 leaves), rows of `leaf_id` / `kslot` that differ
+    from the NumPy rule and ms a call of each form; and the categorical
+    scan against the float64 rule at the cell's widths.  Prints a
+    verdict, "ok" or "fail:<which>"."""
+    import time
+    import jax
+    failures = []
+    shape = dict(F=8, n=11_000_832, max_bin=189, column_bins=189,
+                 cat_words=6)
+    for leaves in (8, 64, 256):
+        fields, leaf_id, binned = _recolour_case(leaves=leaves, **shape)
+        want = _recolour_host(fields, leaf_id, binned)
+        line = f"recolour categorical [8, {shape['n']}] leaves={leaves}:"
+        for form, fn in _recolour_forms(fields, leaf_id, binned, 189, 189):
+            got = jax.block_until_ready(fn())
+            differ = [int((np.asarray(g) != w).sum())
+                      for g, w in zip(got, want)]
+            if any(differ):
+                failures.append(f"recolour_categorical_{leaves}_{form}")
+            t0 = time.perf_counter()
+            for _ in range(calls):
+                out = fn()
+            jax.block_until_ready(out)
+            ms = (time.perf_counter() - t0) / calls * 1e3
+            line += (f" {form} {ms:.3f} ms a call, rows that differ from "
+                     f"the host's rule (leaf_id, kslot) {differ};")
+        print(line, file=sys.stderr)
+    scan_bad = _cat_scan_mismatches(rows=200_000)
+    print(f"categorical scan at widths {CAT_WIDTHS}, 200,000 rows a leaf: "
+          f"mismatches {scan_bad}", file=sys.stderr)
+    failures += scan_bad
+    print("ok" if not failures else "fail:" + ",".join(failures))
+
+
 def _wide_reference(binned_blk, slot, gh, B, slots):
     """[slots, fb, B, 2] float32 histograms of a block of features, as
     plain one-hot products in float32 (`highest`: the MXU's six-pass
@@ -958,6 +1116,8 @@ if __name__ == "__main__":
         time_classed_kernel()
     elif "--recolour" in sys.argv[1:]:
         time_recolour()
+    elif "--categorical" in sys.argv[1:]:
+        time_categorical()
     elif "--roof" in sys.argv[1:]:
         from tools.hist_roof_probe import time_roof
         time_roof()
