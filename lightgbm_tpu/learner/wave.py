@@ -164,10 +164,11 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
                     hist_inverse=meta.hist_inverse)
                if hist_classes else {})
     binned_rm = None
-    # both of the plan's `wave_hl` gates only close as the slots grow:
-    # where one slot is refused (2,000 features: its ungrouped blocks
-    # count 116 MB of VMEM) no wave of this tree can use the row-major
-    # copy, and it is not built (0.8 GB there)
+    # the plan's `wave_hl` gates (VMEM, and its FLOP against the full
+    # call's) only close as the slots grow: where one slot is refused
+    # (2,000 features: its ungrouped blocks count 116 MB of VMEM; the
+    # classed cells: the full call is the cheaper) no wave of this tree
+    # can use the row-major copy, and it is not built (0.8 GB at 2,000)
     hl_at_one_slot = plan_wave_kernel(
         binned.shape[0], hist_B, 1, 1, int8=use_int8,
         hist_classes=hist_classes).kernel == "wave_hl"

@@ -522,23 +522,45 @@ def wave_slot_pad(num_slots: int) -> int:
 # the hi one-hot [F, Bh, Rt] bf16, the [Rt, Wd] expander products (d and
 # wt in f32, sc in bf16) and the f32 accumulator must fit together
 _HL_VMEM = 12 << 20
-# measured crossover of the decomposed kernel's materialized volume
-# against the full kernel's F*B *(old chip)* — against what the full
-# kernel really builds a column, which with several classes is their
-# mean and not `max_bin`; re-tuning it is ROADMAP S1 (2)
+# The decomposed kernel takes a wave where its dots ask at most this
+# share of the full kernel's MXU FLOP a row, both tile-padded as
+# `mxu_flop_per_row` reckons them (the full call at the padded slots,
+# classed where the table is).  `_hl` runs 1.3-3x over its reckoning
+# (short dots; one feature a dot at 137 columns) where the full kernel
+# runs within 20% of its, so the share is a line between measured
+# pairs, not a time ratio.  On a v5e (`tools/kernel_checks.py --hl`,
+# PERF.md section 6) `_hl` won at shares 0.27 / 0.38 / 0.50 (255 bins,
+# 28 columns, 1 / 2 / 4 slots: 9.30 / 11.41 / 14.46 ms against 25.3) and
+# 0.54 (63 bins, 1 slot: 6.59 against 6.96); the full kernel at 0.64
+# (137 columns of one class, 1 slot: 26.68 against 43.24), 0.75-0.76
+# (the one-hot, categorical and ranking cells' classed calls, 1 slot:
+# 20.43 / 11.65 / 22.71 against 24.84 / 22.14 / 43.23), 0.82 (63 bins,
+# 2 slots: 6.94 against 7.86) and at every larger share it was timed at
+# (1.0: 255 bins, 8 slots, 25.35 against 26.65)
 _HL_CROSSOVER = 0.6
 # The spike waves (learner/wave.py) name their true slot count to the plan
 # only up to this many and take the full kernel past it, whatever the
 # crossover says: `_wave_kernel_hl`'s advantage was measured to vanish by
-# 16 slots.  The ladder's waves have no such cap (they differ from this
-# where 0.6 * max_bin admits more than 16 slots, max_bin > ~430):
-# ROADMAP S1 (2) settles both together.
+# 16 slots.  The ladder's waves have no such cap.
 _HL_SPIKE_MAX_SLOTS = 16
 
 
 def spike_true_slots(true_slots: int) -> Optional[int]:
     """The `true_slots` a spike wave hands `plan_wave_kernel`."""
     return true_slots if true_slots <= _HL_SPIKE_MAX_SLOTS else None
+
+
+def hl_vmem(num_features: int, max_bin: int, true_slots: int, C: int = 2,
+            row_tile: int = 512) -> Optional[int]:
+    """VMEM bytes of `_wave_kernel_hl`'s blocks at this call, or None
+    where that kernel cannot take it: past `_HL_VMEM`, or a hi part past
+    256 codes, which would overflow the feature-packed M dimension (and
+    such giant `max_bin` configs gain nothing from decomposition)."""
+    Bh, Bl = hl_split_of(max_bin, true_slots, C)
+    Wd = num_features * Bl * C * true_slots
+    vmem = (num_features * Bh * row_tile * 2 + row_tile * Wd * 10
+            + Wd * Bh * 4)
+    return vmem if Bh <= 256 and vmem <= _HL_VMEM else None
 
 
 # A column's class: its codes rounded up to whole bf16 sublane tiles (16
@@ -625,8 +647,9 @@ def plan_wave_kernel(num_features: int, max_bin: int, num_slots: int,
     `num_slots` is the padded computed-slot bound (the output's), and
     `true_slots` the unpadded one where the caller knows it: only then,
     and never for int8 operands, can the decomposed kernel take the wave
-    — when its materialized volume is meaningfully below what the full
-    kernel builds a column and its ungrouped blocks fit VMEM.  Otherwise
+    — when the MXU FLOP a row its dots ask (`mxu_flop_per_row`, at the
+    true slots) is at most `_HL_CROSSOVER` of what the full kernel's ask
+    in the same call, and its ungrouped blocks fit VMEM.  Otherwise
     the full kernel runs, as one full-F block where that fits (no padding
     of F to the 8-sublane granule: 12.5% of one-hot volume and MXU rows
     at 28 features, and fewer grid cells) and else in feature groups.
@@ -642,7 +665,7 @@ def plan_wave_kernel(num_features: int, max_bin: int, num_slots: int,
     — `sum(codes x columns) x _onehot_row_bytes` a block — a group is a
     run of class-ordered columns within `_FULL_F_VMEM` (each its own
     one-block call, so a column is never padded), and the decomposed
-    kernel is held against the classed volume a column, not `max_bin`."""
+    kernel is held against the classed call's FLOP, not `max_bin`'s."""
     if int8 or len(hist_classes) < 2:
         hist_classes = ()
     unit = _wave_unit_bytes(max_bin, num_slots, C, row_tile)
@@ -668,28 +691,19 @@ def plan_wave_kernel(num_features: int, max_bin: int, num_slots: int,
             Fg = _pick_feature_group(Fp, unit, _GROUP_VMEM)
         groups, onehot_rows = Fp // Fg, Fp * _bin_pad(max_bin)[0]
         full_vmem = Fg * unit
-    kernel, split, vmem = "wave", None, full_vmem
-    if true_slots is not None:
-        Bh, Bl = split = hl_split_of(max_bin, true_slots, C)
-        CS = C * true_slots
-        Wd = num_features * Bl * CS
-        hl_vmem = (num_features * Bh * row_tile * 2 + row_tile * Wd * 10
-                   + num_features * Bh * Bl * CS * 4)
-        # what the full kernel builds a column: `max_bin` with one
-        # class, the classed mean with several (100 codes at the one-hot
-        # cell's 12 columns, where `max_bin` is 255)
-        full_codes = (onehot_rows / num_features if hist_classes
-                      else max_bin)
-        # Bh > 256 would overflow the feature-packed M dimension (and
-        # such giant max_bin configs gain nothing from decomposition
-        # anyway)
-        if (not int8 and Bh <= 256
-                and Bh + Bl * CS <= _HL_CROSSOVER * full_codes
-                and hl_vmem <= _HL_VMEM):
-            kernel, vmem = "wave_hl", hl_vmem
-    return WaveKernelPlan(kernel, Fp, Fg, groups, split, vmem,
-                          8 * unit <= _SCOPED_VMEM, onehot_rows,
-                          class_groups)
+    plan = WaveKernelPlan("wave", Fp, Fg, groups, None, full_vmem,
+                          8 * unit <= _SCOPED_VMEM, onehot_rows, class_groups)
+    if true_slots is None:
+        return plan
+    plan = plan._replace(hl_split=hl_split_of(max_bin, true_slots, C))
+    vmem = hl_vmem(num_features, max_bin, true_slots, C, row_tile)
+    hl = plan._replace(kernel="wave_hl", vmem_bytes=vmem)
+    if (not int8 and vmem is not None
+            and mxu_flop_per_row(hl, num_features, true_slots, C)
+            <= _HL_CROSSOVER * mxu_flop_per_row(plan, num_features,
+                                                num_slots, C)):
+        return hl
+    return plan
 
 
 def _hl_pack(num_features: int, Bh: int) -> int:
@@ -976,8 +990,8 @@ def wave_histograms(binned_fm: jnp.ndarray, binned_rm: jnp.ndarray,
     the kernels are the jitted entries and carry the scopes).  Operands as
     `build_histogram_wave`'s, plus `binned_rm` [n, F] — built by the
     caller where the plan gives `wave_hl` at one slot, which every
-    `wave_hl` wave of the same shape implies (both gates only close as
-    the slots grow) — and the wave's `true_slots` where it knows them.
+    `wave_hl` wave of the same shape implies (its gates only close as the
+    slots grow) — and the wave's `true_slots` where it knows them.
     `quant_scales` selects the full kernel's int8 operands.  Where the
     plan cuts `hist_classes` into class groups the full kernel reads
     `binned_classed`, the caller's `binned_fm[hist_order]`, and its

@@ -258,7 +258,7 @@ def test_xla_recolour_carries_the_bundle_route_part(one_chip):
 # the ranking and one-hot cells' device columns by code count
 # (`mslr-2270k-b63.train_rank`, `expo-onehot700-b63.train_sparse`) and
 # their padded rows
-from tools.kernel_checks import EXPO_CODES, MSLR_CODES  # noqa: E402
+from tools.kernel_checks import CAT_CODES, EXPO_CODES, MSLR_CODES  # noqa: E402
 MSLR_N, EXPO_N = 2_271_232, 11_000_832
 
 
@@ -289,12 +289,12 @@ def test_classed_wave_kernel_compiles_within_scoped_vmem(one_chip, codes,
 
 # the categorical cell (`expo-cat8-b63.train_cat`): 8 columns, 6 of them
 # categorical with up to 189 bins (the 99%-coverage rule), at Expo's rows
-CAT_CODES, CAT_COLUMNS = (13, 32, 8, 23, 189, 170, 63, 63), 6
+CAT_COLUMNS = 6
 
 
 @pytest.mark.parametrize("codes,rows,max_bin,cat,onehot_rows,full,hl,temp", [
-    (MSLR_CODES, MSLR_N, 63, 0, 7392, 7, 2, None),
-    (CAT_CODES, EXPO_N, 189, CAT_COLUMNS, 592, 7, 2, 1_773_102_080)])
+    (MSLR_CODES, MSLR_N, 63, 0, 7392, 9, 0, None),
+    (CAT_CODES, EXPO_N, 189, CAT_COLUMNS, 592, 9, 0, 335_155_200)])
 def test_grow_program_is_one_for_two_column_orders(one_chip, codes, rows,
                                                    max_bin, cat, onehot_rows,
                                                    full, hl, temp):
@@ -304,15 +304,19 @@ def test_grow_program_is_one_for_two_column_orders(one_chip, codes, rows,
     module text — classes are static as a sorted multiset, the order is
     the data in `FeatureMeta.hist_order` / `hist_inverse` and in the
     class-ordered copy of the bins — so the second order finds the
-    first's executable in the compile cache.  It compiles, the full
-    kernel classed (`f32[7392, .]` results) beside the decomposed one.
+    first's executable in the compile cache.  It compiles, every wave
+    through the full kernel classed (`f32[7392, .]` results): the
+    decomposed one would ask 0.76 of its FLOP at one slot, and no
+    row-major copy of the bins is made.
 
     The categorical cell's shape too (PR 41): `uint8[8, 11,000,832]`,
     255 leaves, the six categorical columns' indices data
     (`FeatureMeta.cat_index`) and only their count static, 6 bitset
     words; the scan carries its part `Cat.scan` under
     `Tree.split_find`, the recolour is the kernel with the bitset route
-    inside it, and the temporaries are `temp` B plus 10%."""
+    inside it, and the temporaries are `temp` B plus 10% (1,773,102,080
+    while the 1-slot waves took `_hl`: its row-major copy of the bins,
+    `u8[11,000,832, 8]`, is 128 lanes wide in the chip's tiling)."""
     from lightgbm_tpu.learner import FeatureMeta
     from lightgbm_tpu.learner.wave import grow_tree_wave
     from lightgbm_tpu.ops.histogram import hist_classes_of
@@ -351,6 +355,7 @@ def test_grow_program_is_one_for_two_column_orders(one_chip, codes, rows,
         s.startswith(f"f32[{onehot_rows},") for s in kernels)
     assert sum(name.startswith("build_histogram_wave_hl.")
                for name, _ in calls) == hl
+    assert f"u8[{rows},{F}]" not in text
     if cat:
         assert cat_bitset_words(max_bin) == 6
         assert "/Tree.split_find/" in text and "/Cat.scan/" in text
@@ -567,9 +572,10 @@ def test_every_kernel_call_carries_what_it_asks_of_the_mxu(
     On one chip and under `shard_map` on four, every histogram
     custom-call of the grow program holds the part inside
     `Tree.histogram`, and the
-    ladder's labels are the 255-leaf tree's: 1, 1, 2, 4, 8 true slots
-    through `_hl`, 16 to 64 through the full kernel at 1,837,056 FLOP a
-    row, and the `while_loop`'s 128-slot wave, which names none."""
+    ladder's labels are the 255-leaf tree's: 1, 1, 2, 4 true slots
+    through `_hl`, 8 to 64 through the full kernel at 1,837,056 FLOP a
+    row (which `_hl` would ask at 8 too), and the `while_loop`'s 128-slot
+    wave, which names none."""
     labels = _kernel_labels(request.getfixturevalue(program))
     assert len(labels) >= 9
     assert all(part is not None for _, part in labels), labels
@@ -577,11 +583,10 @@ def test_every_kernel_call_carries_what_it_asks_of_the_mxu(
     for name, part in labels:
         by_kernel.setdefault(re.sub(r"\.\d+$", "", name), []).append(part)
     assert sorted(by_kernel["build_histogram_wave_hl"]) == [
-        (2, 493568, 1), (2, 493568, 1), (4, 690176, 1), (8, 919552, 1),
-        (16, 1837056, 1)]
+        (2, 493568, 1), (2, 493568, 1), (4, 690176, 1), (8, 919552, 1)]
     assert sorted(by_kernel["build_histogram_wave"]) == [
-        (32, 1837056, 1), (64, 1837056, 1), (128, 1837056, 1),
-        (256, 3672064, 1)]
+        (16, 1837056, 1), (32, 1837056, 1), (64, 1837056, 1),
+        (128, 1837056, 1), (256, 3672064, 1)]
 
 
 def test_each_kernel_call_holds_its_count_where_the_cache_key_sees_it(

@@ -89,6 +89,13 @@ def test_class_groups_each_run_their_count_dot():
         2 * 7392 * 256 + 2 * COUNT_DOT)
 
 
+def _hl_plan(shape, slots):
+    """The decomposed kernel's plan of a call, whichever kernel the plan
+    gives it (as `build_histogram_wave_hl` takes it)."""
+    return plan_wave_kernel(*shape, wave_slot_pad(slots), slots)._replace(
+        kernel="wave_hl")
+
+
 @pytest.mark.parametrize("shape,slots,split,pack,by_hand", [
     # main: 7 dots [4 x 32, Rt] x [Rt, 4 x 8 x 2 -> 128]; Wd 28 x 8 x 2 =
     # 448 -> 512; d: K 29 -> 128; wt: K 2 -> 128
@@ -105,8 +112,8 @@ def test_class_groups_each_run_their_count_dot():
 def test_decomposed_kernel_count_by_hand(shape, slots, split, pack,
                                          by_hand):
     from lightgbm_tpu.ops.histogram import _hl_pack
-    plan = plan_wave_kernel(*shape, wave_slot_pad(slots), slots)
-    assert (plan.kernel, plan.hl_split) == ("wave_hl", split)
+    plan = _hl_plan(shape, slots)
+    assert plan.hl_split == split
     assert _hl_pack(shape[0], split[0]) == pack
     assert mxu_flop_per_row(plan, shape[0], slots) == by_hand
 
@@ -114,10 +121,11 @@ def test_decomposed_kernel_count_by_hand(shape, slots, split, pack,
 def test_decomposed_kernel_at_8_slots_asks_what_the_full_kernel_asks():
     """255 bins, 8 slots: 1,837,056 FLOP a row through either kernel —
     and the ledger's calls cost the same (`_hl` 25.75 ms, the full
-    kernel 25.25-25.39: ROADMAP S1 (2))."""
-    hl = plan_wave_kernel(28, 255, 8, 8)
+    kernel 25.25-25.39: ROADMAP S1 (2)), so the plan gives the wave the
+    full kernel."""
+    hl = _hl_plan((28, 255), 8)
     full = plan_wave_kernel(28, 255, 8)
-    assert (hl.kernel, full.kernel) == ("wave_hl", "wave")
+    assert plan_wave_kernel(28, 255, 8, 8) == full._replace(hl_split=(64, 4))
     assert (mxu_flop_per_row(hl, 28, 8) == mxu_flop_per_row(full, 28, 8)
             == 1_837_056)
 
@@ -158,8 +166,10 @@ def _ladder_labels(codes, max_bin, n=1024):
 def test_label_is_one_for_three_column_orders(codes, max_bin, onehot):
     """Correction (m): the label is a function of shapes and the sorted
     class multiset, so every `--seed` of a cell traces the same program
-    text.  The waves of 2 and 4 true slots are both padded to 8 and say
-    n = 2 x 2 and 2 x 4, not 2 x 8."""
+    text.  The waves of 1, 2 and 4 true slots are all padded to 8 and say
+    n = 2 x 1, 2 x 2 and 2 x 4, not 2 x 8; every wave takes the full
+    kernel in both cells, the 1-slot ones too (`_hl` would ask 0.75 /
+    0.76 of its FLOP)."""
     codes = np.asarray(codes)
     tables = [codes] + [codes[np.random.RandomState(s).permutation(
         len(codes))] for s in (1, 2)]
@@ -167,16 +177,11 @@ def test_label_is_one_for_three_column_orders(codes, max_bin, onehot):
     ladders = [_ladder_labels(tuple(t.tolist()), max_bin) for t in tables]
     assert ladders[0] == ladders[1] == ladders[2]
     full = 2 * onehot * 128 + COUNT_DOT
-    assert wave_slot_pad(2) == wave_slot_pad(4) == 8
-    for ts in (2, 4, 8, 16, 64):
+    assert wave_slot_pad(1) == wave_slot_pad(2) == wave_slot_pad(4) == 8
+    for ts in (1, 2, 4, 8, 16, 64):
         assert ladders[0][ts] == f"Hist.mxu_n{2 * ts}_f{full}_e1"
     assert ladders[0][128] == (
         f"Hist.mxu_n256_f{2 * onehot * 256 + COUNT_DOT}_e1")
-    # the 1-slot waves keep the decomposed kernel in both cells
-    plan = plan_wave_kernel(len(codes), max_bin, 8, 1,
-                            hist_classes=hist_classes_of(codes)[0])
-    assert ladders[0][1] == "Hist.mxu_n2_f%d_e1" % mxu_flop_per_row(
-        plan, len(codes), 1)
 
 
 def test_a_wave_that_names_no_true_slots_says_its_padded_bound():

@@ -18,7 +18,7 @@ import lightgbm_tpu as lgb
 from lightgbm_tpu.learner import plan_growth
 from lightgbm_tpu.ops.histogram import (hist_classes_of, plan_wave_kernel,
                                         spike_true_slots, wave_slot_pad)
-from tools.kernel_checks import EXPO_CODES, MSLR_CODES
+from tools.kernel_checks import CAT_CODES, EXPO_CODES, MSLR_CODES
 
 HIGGS_255, HIGGS_63, EPSILON_63 = (28, 255), (28, 63), (2000, 63)
 # the one-hot cell under EFB: device columns x the largest column's codes
@@ -34,9 +34,8 @@ MSLR_CLASSES = ((16, 24), (32, 7), (64, 106))
 EXPO_CLASSES = ((16, 2), (32, 1), (48, 2), (64, 3), (128, 1), (208, 1),
                 (256, 2))
 # the categorical cell (`expo-cat8-b63.train_cat`): the same rows as 8
-# columns, 6 categoricals whose 99%-coverage rule takes two past 63 bins
-# (the issue's scratch run: 189 and 170), so the kernel's max_bin is 189
-CAT_CODES = (13, 32, 8, 23, 189, 170, 63, 63)
+# columns (`CAT_CODES`), 6 categoricals whose 99%-coverage rule takes two
+# past 63 bins (189 and 170), so the kernel's max_bin is 189
 CAT_8 = (8, max(CAT_CODES))
 CAT_CLASSES = ((16, 2), (32, 2), (64, 2), (176, 1), (192, 1))
 
@@ -49,16 +48,19 @@ def _ladder_plan(shape, true_slots, **kw):
 
 # ------------------------------------------------ (a) the benchmark's shapes
 @pytest.mark.parametrize("shape,hl_splits", [
-    # 255 bins: `_hl` [28,64,64] / [28,64,32] in the ledger are (64, 4) at
-    # 8 and 4 slots; `wave` from 16 slots up
-    (HIGGS_255, {1: (32, 8), 2: (32, 8), 4: (64, 4), 8: (64, 4)}),
-    # 63 bins: `_hl` [28,16,16] only, for the 1 / 1 / 2-slot waves
-    (HIGGS_63, {1: (16, 4), 2: (16, 4)}),
+    # 255 bins: `_hl` [28,64,32] on the chip is (64, 4) at 4 slots; at 8
+    # it asks the full kernel's FLOP (share 1.0: 25.75 against 25.25 ms),
+    # so `wave` from 8 slots up
+    (HIGGS_255, {1: (32, 8), 2: (32, 8), 4: (64, 4)}),
+    # 63 bins: `_hl` [28,16,16] only for the 1-slot waves (share 0.54); at
+    # 2 slots 0.82 (6.92 against the full kernel's 6.84 ms)
+    (HIGGS_63, {1: (16, 4)}),
     # Epsilon: never `_hl` (its ungrouped blocks want 116 MB at one slot)
     (EPSILON_63, {}),
-    # bundle columns at 255 codes: Higgs-255's splits on fewer columns
-    (EXPO_BUNDLED, {1: (32, 8), 2: (32, 8), 4: (64, 4), 8: (64, 4)}),
-    (EXPO_BUNDLED_13, {1: (32, 8), 2: (32, 8), 4: (64, 4), 8: (64, 4)}),
+    # bundle columns at 255 codes, one class: Higgs-255's splits on fewer
+    # columns (the cell's own columns are classed: below)
+    (EXPO_BUNDLED, {1: (32, 8), 2: (32, 8), 4: (64, 4)}),
+    (EXPO_BUNDLED_13, {1: (32, 8), 2: (32, 8), 4: (64, 4)}),
 ])
 def test_ladder_kernels_are_the_ledgers(shape, hl_splits):
     for ts in LADDER:
@@ -109,13 +111,14 @@ def test_epsilon_feature_groups(slots, group, groups):
 
 def test_ranking_cell_unclassed_plan():
     """(137, 63) with every column at 63 bins — the program before the
-    one-hot was classed, and any 137-column table of one class: `_hl`
-    for the 1-slot waves alone (2 slots: 11.2 MB of expander products
-    against `_HL_VMEM`), one 137-column block to 64 slots, three groups
-    of 48 over 144 padded columns at 128."""
+    one-hot was classed, and any 137-column table of one class: never
+    `_hl` (at 1 slot it asks 0.64 of the full call's FLOP, one feature a
+    dot, and took 41.1 ms against 26.6; at 2 slots 11.2 MB of expander
+    products are past `_HL_VMEM`), one 137-column block to 64 slots,
+    three groups of 48 over 144 padded columns at 128."""
     for ts in LADDER:
         plan = _ladder_plan(MSLR_63, ts)
-        assert plan.kernel == ("wave_hl" if ts == 1 else "wave")
+        assert plan.kernel == "wave"
         full = plan_wave_kernel(*MSLR_63, wave_slot_pad(ts))
         assert (full.feature_pad, full.feature_group, full.groups,
                 full.onehot_rows) == ((144, 48, 3, 9216) if ts == 128
@@ -142,15 +145,16 @@ def test_classes_are_the_sorted_multiset_of_the_columns_codes():
 
 
 @pytest.mark.parametrize("shape,classes,rows,hl_slots", [
-    # 7,392 one-hot rows a tile for 8,768: `_hl` keeps the 1-slot waves
-    # (24 <= 0.6 x 54.0 codes a column; at 2 slots `_HL_VMEM` refuses)
-    (MSLR_63, MSLR_CLASSES, 7392, (1,)),
-    # 1,200 for 3,072: the 2-, 4- and 8-slot waves leave `_hl`, whose
-    # 64 / 80 / 96 lane-units are over 0.6 x 100 codes a column
-    (EXPO_BUNDLED, EXPO_CLASSES, 1200, (1,)),
-    # 592 for 1,536: `_hl` keeps the 1-slot waves, the full kernel the
-    # rest, in one block
-    (CAT_8, CAT_CLASSES, 592, (1,))])
+    # 7,392 one-hot rows a tile for 8,768: the 1-slot waves leave `_hl`
+    # too, which asks 0.76 of the classed call's FLOP (41.1 against 22.6
+    # ms on the chip); at 2 slots `_HL_VMEM` refuses it
+    (MSLR_63, MSLR_CLASSES, 7392, ()),
+    # 1,200 for 3,072: share 0.75 at one slot (21.75 against 20.33 ms),
+    # 0.96 and up from two
+    (EXPO_BUNDLED, EXPO_CLASSES, 1200, ()),
+    # 592 for 1,536: share 0.76 at one slot (19.23 against 11.55 ms);
+    # every wave the full kernel's, in one block
+    (CAT_8, CAT_CLASSES, 592, ())])
 def test_classed_plans_of_the_cells(shape, classes, rows, hl_slots):
     for ts in LADDER:
         plan = _ladder_plan(shape, ts, hist_classes=classes)
@@ -168,6 +172,31 @@ def test_classed_plans_of_the_cells(shape, classes, rows, hl_slots):
     # the decomposed kernel itself is the unclassed plan's, split and all
     assert (_ladder_plan(shape, 1, hist_classes=classes).hl_split
             == _ladder_plan(shape, 1).hl_split)
+
+
+# (shape, classes, true slots, the kernel that was faster on a v5e): the
+# pairs of calls measured at the cells' shapes (ms a call), each at the
+# share of the full kernel's padded MXU FLOP that `_hl` asks
+MEASURED_WINNERS = [
+    (HIGGS_255, (), 1, "wave_hl"),      # 0.27: 8.29 against 25.3
+    (HIGGS_255, (), 2, "wave_hl"),      # 0.38: 10.48 against 25.3
+    (HIGGS_255, (), 4, "wave_hl"),      # 0.50: 13.55 against 25.3
+    (HIGGS_255, (), 8, "wave"),         # 1.00: 25.75 against 25.25
+    (HIGGS_63, (), 1, "wave_hl"),       # 0.54: 5.58 against 6.84
+    (HIGGS_63, (), 2, "wave"),          # 0.82: 6.92 against 6.84
+    (EXPO_BUNDLED, EXPO_CLASSES, 1, "wave"),    # 0.75: 21.75 against 20.33
+    (CAT_8, CAT_CLASSES, 1, "wave"),    # 0.76: 19.23 against 11.55
+    (MSLR_63, MSLR_CLASSES, 1, "wave"),         # 0.76: 41.12 against 22.60
+    # one class (the cell before its columns were classed): 0.64, one
+    # feature a dot: 41.1 against 26.6
+    (MSLR_63, (), 1, "wave"),
+    (EPSILON_63, (), 1, "wave"),        # 2.4 (and `_HL_VMEM` refuses)
+]
+
+
+@pytest.mark.parametrize("shape,classes,slots,winner", MEASURED_WINNERS)
+def test_plan_takes_the_measured_winner(shape, classes, slots, winner):
+    assert _ladder_plan(shape, slots, hist_classes=classes).kernel == winner
 
 
 def test_ranking_cell_chain_tail_is_two_class_groups():

@@ -12,6 +12,7 @@ import functools
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
@@ -23,7 +24,11 @@ from lightgbm_tpu.ops.split import MISSING_NONE, SplitParams
 def interpret_pallas(monkeypatch):
     """Every `pl.pallas_call` traced inside the test interprets its
     kernel.  Shapes in this file are used by no other test, so no jit
-    cache entry traced without it can be hit (or left behind)."""
+    cache entry traced with it is left behind; the caches are cleared
+    first, so none traced without it is hit either (`kernel_checks.py`'s
+    recolour at 28 x 2,048 is also what `test_wide_dense.py` traces, and
+    a worker may have run that file first)."""
+    jax.clear_caches()
     monkeypatch.setattr(pl, "pallas_call",
                         functools.partial(pl.pallas_call, interpret=True))
 

@@ -63,7 +63,9 @@ def test_waves_equal_the_kernel_calls_in_interpret_mode(monkeypatch):
     # the kernels `ops/histogram.py wave_histograms` dispatches to
     calls = _counting(monkeypatch, ["build_histogram_wave",
                                     "build_histogram_wave_hl"], histogram)
-    n, F, B, L = 4096, 6, 32, 21                   # shapes of no other test
+    # shapes of no other test; at 127 bins the 1- and 2-slot waves take
+    # `_hl` and the rest the full kernel, so both are dispatched
+    n, F, B, L = 4096, 6, 127, 21
     rng = np.random.RandomState(5)
     Xu = rng.rand(n, F)
     binned = np.clip((Xu * B).astype(np.int64), 0, B - 1).T.astype(np.uint8)

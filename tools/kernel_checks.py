@@ -87,6 +87,13 @@ minutes) runs check 10's two forms at each cell's own shape (28 / 137 /
 2,000 plain columns, 12 bundle columns; 8 and 256 leaves): rows whose
 `leaf_id` or `kslot` differ between the kernel, the XLA form and NumPy,
 and ms a call of each form.
+
+`time_hl_crossover()` (`python tools/kernel_checks.py --hl`; a few
+minutes) times the two kernels a ladder wave can take — the full one,
+classed where the cell is, and the decomposed one — at each cell's
+kernel shape and 1 / 2 / 4 / 8 true slots, compares their histograms
+bit for bit on operands whose sums are exact, and prints the winner
+beside the FLOP share the plan holds against `_HL_CROSSOVER`.
 """
 import os
 import sys
@@ -870,6 +877,8 @@ def time_recolour(calls=10):
 # categorical column after upstream's 99%-coverage rule, the kernel's
 # `max_bin` (the largest) and the configuration's categorical parameters
 CAT_WIDTHS = (13, 32, 8, 23, 189, 170)
+# and its eight device columns' codes: the two numerics at 63 bins
+CAT_CODES = CAT_WIDTHS + (63, 63)
 CAT_PARAMS = {"min_data_in_leaf": 20, "min_sum_hessian_in_leaf": 1e-3,
               "lambda_l2": 0.0, "max_cat_to_onehot": 4,
               "max_cat_threshold": 32, "cat_smooth": 10, "cat_l2": 10,
@@ -1107,6 +1116,133 @@ def run_wide_checks(n=400_384, F=2000, B=63, slot_counts=(1, 8, 128),
     return "ok" if not failures else "fail:" + ",".join(failures)
 
 
+# (name, the device columns' code counts, `hist_B`, padded rows, classed):
+# the kernel shapes of the seven cells (`dp4` runs `b255`'s on each chip),
+# the classed ones as their boosters hold them, and the ranking cell's
+# columns as one class (137 columns, one a decomposed dot), whose FLOP
+# share sits nearest the crossover
+HL_SHAPES = (
+    ("b255", (255,) * 28, 255, 2_625_536, True),
+    ("b63", (63,) * 28, 63, 2_625_536, True),
+    ("eps63", (63,) * 2000, 63, 400_384, True),
+    ("mslr63", MSLR_CODES, 63, 2_271_232, True),
+    ("mslr63-one-class", MSLR_CODES, 63, 2_271_232, False),
+    ("expo63", EXPO_CODES, 255, 11_000_832, True),
+    ("expocat63", CAT_CODES, 189, 11_000_832, True),
+)
+
+
+def _hl_operands(codes, n, slots, seed=42):
+    """Bins drawing each column's codes, a fifth of the rows outside every
+    computed slot (gradient, hessian and mask 0, as the recolour leaves
+    them), and gradients and hessians on multiples of 1/8 with zero mean:
+    every float32 sum of them is exact in any order, so two kernels that
+    sum alike agree bit for bit.  Made on the device."""
+    import jax
+    import jax.numpy as jnp
+    kb, ks, kw, kg, kh = jax.random.split(jax.random.PRNGKey(seed), 5)
+    i32, f32 = jnp.int32, jnp.float32
+    binned = jax.random.randint(kb, (len(codes), n), 0,
+                                jnp.asarray(codes, i32)[:, None]
+                                ).astype(jnp.uint8)
+    inside = (jax.random.uniform(kw, (n,)) < 0.8).astype(f32)
+    slot = jax.random.randint(ks, (n,), 0, slots, i32)
+    g, h = (jax.random.randint(k, (n,), -8, 9).astype(f32) / 8
+            for k in (kg, kh))
+    return binned, slot, jnp.stack([g * inside, h * inside, inside])
+
+
+def time_hl_crossover(shapes=HL_SHAPES, slot_counts=(1, 2, 4, 8), calls=10):
+    """The two wave kernels a ladder wave can take, at each cell's kernel
+    shape and 1 / 2 / 4 / 8 true slots: ms a call of the full kernel
+    (`build_histogram_wave` at the padded slots, classed where the cell
+    is) and of the decomposed one (`build_histogram_wave_hl`, where its
+    VMEM gate admits the call), entries of their histograms and counts
+    that differ, and beside the measured winner the share of the full
+    kernel's padded MXU FLOP the decomposed one asks and the kernel
+    `plan_wave_kernel` gives.  The reading behind `_HL_CROSSOVER`.
+    Returns "ok" or "fail:<which>"; the readings go to stderr."""
+    import time
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.histogram import (
+        build_histogram_wave, build_histogram_wave_hl, class_ordered,
+        hist_classes_of, hl_split_of, hl_vmem, mxu_flop_per_row,
+        plan_wave_kernel, wave_histograms, wave_slot_pad)
+
+    def ms_a_call(fn, *args):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / calls * 1e3
+
+    failures = []
+    for name, codes, max_bin, n, classed in shapes:
+        F = len(codes)
+        classes, order = hist_classes_of(codes)
+        classes = classes if classed and len(classes) > 1 else ()
+        for slots in slot_counts:
+            NLp = wave_slot_pad(slots)
+            full_plan = plan_wave_kernel(F, max_bin, NLp,
+                                         hist_classes=classes)
+            hl_plan = full_plan._replace(
+                kernel="wave_hl", hl_split=hl_split_of(max_bin, slots, 2))
+            share = (mxu_flop_per_row(hl_plan, F, slots)
+                     / mxu_flop_per_row(full_plan, F, NLp))
+            picked = plan_wave_kernel(F, max_bin, NLp, slots,
+                                      hist_classes=classes).kernel
+            line = (f"hl {name} [{F}, {n}] B={max_bin} slots={slots}: "
+                    f"FLOP share {share:.3f}, plan {picked}")
+            try:
+                binned, slot, gh = _hl_operands(codes, n, slots)
+                handed = {}
+                if classes:
+                    handed = dict(
+                        hist_classes=classes,
+                        binned_classed=class_ordered(binned,
+                                                     jnp.asarray(order)),
+                        hist_inverse=jnp.asarray(np.argsort(order),
+                                                 jnp.int32))
+                full_ms = ms_a_call(
+                    lambda b, s, g: build_histogram_wave(
+                        b, s, g, max_bin=max_bin, num_slots=NLp,
+                        hist_classes=classes),
+                    handed.get("binned_classed", binned), slot, gh)
+                line += f"; full {full_ms:.3f} ms"
+                if hl_vmem(F, max_bin, slots) is None:
+                    print(line + "; hl refused by its VMEM gate",
+                          file=sys.stderr, flush=True)
+                    continue
+                binned_rm = binned.T
+                hl_ms = ms_a_call(
+                    lambda b, r, s, g: build_histogram_wave_hl(
+                        b, r, s, g, max_bin=max_bin, num_slots=slots,
+                        out_slots=NLp),
+                    binned, binned_rm, slot, gh)
+                want = wave_histograms(binned, None, slot, gh,
+                                       max_bin=max_bin, num_slots=NLp,
+                                       **handed)
+                got = build_histogram_wave_hl(binned, binned_rm, slot, gh,
+                                              max_bin=max_bin,
+                                              num_slots=slots, out_slots=NLp)
+                differ = [int(jnp.sum(a != b)) for a, b in zip(got, want)]
+                winner = "wave_hl" if hl_ms < full_ms else "wave"
+                print(line + f"; hl {hl_ms:.3f} ms; winner {winner}; "
+                      f"entries that differ (sums, counts) {differ}",
+                      file=sys.stderr, flush=True)
+                if any(differ) or not bool(jnp.any(want[0])):
+                    failures.append(f"hl_differs_{name}_{slots}")
+                if picked != winner:
+                    failures.append(f"plan_misses_{name}_{slots}")
+            except Exception as e:    # noqa: BLE001 - named in the verdict
+                traceback.print_exc()
+                failures.append(f"hl_raised_{name}_{slots}"
+                                f"({type(e).__name__})")
+    return "ok" if not failures else "fail:" + ",".join(failures)
+
+
 if __name__ == "__main__":
     if "--score-lookup" in sys.argv[1:]:
         time_score_lookup()
@@ -1121,6 +1257,8 @@ if __name__ == "__main__":
     elif "--roof" in sys.argv[1:]:
         from tools.hist_roof_probe import time_roof
         time_roof()
+    elif "--hl" in sys.argv[1:]:
+        print(time_hl_crossover())
     else:
         print(run_wide_checks() if "--wide" in sys.argv[1:]
               else run_checks())
