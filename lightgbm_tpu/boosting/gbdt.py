@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from ..config import Config
-from ..io.binning import BIN_CATEGORICAL
+from ..io.binning import BIN_CATEGORICAL, MISSING_NAN
 from ..io.dataset import Dataset
 from ..learner import (FeatureMeta, GrowParams, grow_tree,
                        grow_tree_donated, grow_tree_wave,
@@ -469,6 +469,12 @@ class GBDT:
         if len(cat_index):
             _metrics.inc("cat_features", len(cat_index))
             _metrics.inc("cat_bins", int(self.f_num_bin[cat_index].sum()))
+        # numerical columns whose last bin holds NaN: where the gain scan
+        # runs its forward (missing goes right) pass
+        self._nan_numerical = ((self.f_missing_type == MISSING_NAN)
+                               & ~self.f_is_cat)
+        if self._nan_numerical.any():
+            _metrics.inc("missing_features", int(self._nan_numerical.sum()))
         self.meta = FeatureMeta(
             num_bin=jnp.asarray(self.f_num_bin),
             missing_type=jnp.asarray(self.f_missing_type),
@@ -1470,6 +1476,11 @@ class GBDT:
         _metrics.inc("splits_total", num_leaves - 1)
         _metrics.inc("cat_splits_total",
                      int(np.count_nonzero(split_is_cat[:num_leaves - 1])))
+        # numerical splits that send NaN right: the forward scan's winners
+        _metrics.inc("missing_right_splits_total", int(np.count_nonzero(
+            self._nan_numerical[split_feature[:num_leaves - 1]]
+            & (default_left[:num_leaves - 1] == 0)
+            & (split_is_cat[:num_leaves - 1] == 0))))
         ds = self.train_data
         L = self.config.num_leaves
         tree = Tree(max(L, 2))

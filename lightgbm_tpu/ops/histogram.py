@@ -473,10 +473,17 @@ def hl_split_of(max_bin: int, num_slots: int, C: int):
 # call as above, so its gate is `_FULL_F_VMEM`: the ranking cell's 7,392
 # rows count 15.1 MB at 128 slots and compile as one block (unclassed:
 # 144 padded columns in three groups), the one-hot cell's 1,200 count
-# 2.5 MB (tests/test_chip_compile.py compiles both to 255 slots).
+# 2.5 MB (tests/test_chip_compile.py compiles both to 255 slots).  Its
+# bins block counts too, where a group is wide: `u8[cols, Rt]` twice
+# (pipelined), the columns to the 32-row uint8 tile, and a fixed
+# `_CLASSED_FIXED_VMEM` (the gh and slot blocks) — a 968-column table's
+# 305-column group of 8,176 one-hot rows at 128 slots counted 15.97 MB
+# by its rows and was refused at 16.29 MiB; the ranking cell's 106
+# columns of 5,408 rows at 256 slots compile with 8 KB to spare.
 _SCOPED_VMEM = 16 << 20     # the compiler's limit for one kernel
 _FULL_F_VMEM = 16 << 20     # one full-F block when F * unit fits this
 _GROUP_VMEM = 6 << 20       # else feature groups of at most this
+_CLASSED_FIXED_VMEM = 24 << 10
 
 
 def _bin_pad(max_bin: int) -> Tuple[int, int]:
@@ -620,15 +627,26 @@ class WaveKernelPlan(NamedTuple):
     class_groups: tuple = ()
 
 
-def _class_groups(hist_classes: tuple, row_bytes: int, budget: int) -> tuple:
-    """The class-ordered columns cut into runs whose one-hot rows, at
-    `row_bytes` each, fit `budget`: each group a tuple of (codes,
+def _class_group_vmem(rows: int, columns: int, row_bytes: int,
+                      row_tile: int) -> int:
+    """VMEM bytes of a class group's call: its one-hot rows at
+    `row_bytes`, its bins block `u8[columns, row_tile]` twice (the
+    columns to the 32-row uint8 tile) and the fixed blocks."""
+    return (rows * row_bytes + _round_up(columns, 32) * 2 * row_tile
+            + _CLASSED_FIXED_VMEM)
+
+
+def _class_groups(hist_classes: tuple, row_bytes: int, budget: int,
+                  row_tile: int = 512) -> tuple:
+    """The class-ordered columns cut into runs whose calls
+    (`_class_group_vmem`) fit `budget`: each group a tuple of (codes,
     columns).  A column past the budget alone stands alone (`fits` is
     what says it cannot run)."""
-    room = budget // row_bytes
     groups, rows = [[]], 0
     for codes in (c for c, cols in hist_classes for _ in range(cols)):
-        if groups[-1] and rows + codes > room:
+        if groups[-1] and _class_group_vmem(
+                rows + codes, len(groups[-1]) + 1, row_bytes,
+                row_tile) > budget:
             groups.append([])
             rows = 0
         groups[-1].append(codes)
@@ -662,7 +680,8 @@ def plan_wave_kernel(num_features: int, max_bin: int, num_slots: int,
     `max_bin` for every column.  With several (never for int8 operands:
     that arm keeps one class) the full kernel builds each column's
     one-hot at its class's codes: VMEM is counted on the classed one-hot
-    — `sum(codes x columns) x _onehot_row_bytes` a block — a group is a
+    — `sum(codes x columns) x _onehot_row_bytes` a block, and its bins
+    block (`_class_group_vmem`) — a group is a
     run of class-ordered columns within `_FULL_F_VMEM` (each its own
     one-block call, so a column is never padded), and the decomposed
     kernel is held against the classed call's FLOP, not `max_bin`'s."""
@@ -673,12 +692,15 @@ def plan_wave_kernel(num_features: int, max_bin: int, num_slots: int,
     if hist_classes:
         assert sum(k for _, k in hist_classes) == num_features
         row_bytes = _onehot_row_bytes(num_slots, C, row_tile)
-        class_groups = _class_groups(hist_classes, row_bytes, _FULL_F_VMEM)
+        class_groups = _class_groups(hist_classes, row_bytes, _FULL_F_VMEM,
+                                     row_tile)
         group_rows = [sum(c * k for c, k in g) for g in class_groups]
         Fp, Fg = num_features, max(sum(k for _, k in g)
                                    for g in class_groups)
         groups, onehot_rows = len(class_groups), sum(group_rows)
-        full_vmem = max(group_rows) * row_bytes
+        full_vmem = max(_class_group_vmem(r, sum(k for _, k in g),
+                                          row_bytes, row_tile)
+                        for r, g in zip(group_rows, class_groups))
     else:
         if num_features * unit <= _FULL_F_VMEM:
             Fp = Fg = num_features

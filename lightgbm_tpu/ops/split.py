@@ -470,7 +470,9 @@ def _scan_thresholds(slab, num_bins: int, shape, col: _Columns,
         gain, thr, lg, lh_raw, lc = rev
         return gain, thr, jnp.ones(shape, bool), lg, lh_raw, lc
 
-    # ---- FORWARD scan: left = inclusive prefix at tau; missing goes right
+    # ---- FORWARD scan: left = inclusive prefix at tau; missing goes right.
+    # `Split::missing_right` is a part of the caller's `Tree::split_find`
+    # (the benchmark's `missing_scan_ms` reads it)
     def fwd_step(tau, carry):
         left_g, left_h, left_c, best = carry
         g, h, c = bin_sums(tau)
@@ -481,7 +483,9 @@ def _scan_thresholds(slab, num_bins: int, shape, col: _Columns,
         gain = candidate_gain(*left, tau_ok, tau)
         return left + (keep_better(best, (gain, tau) + left),)
 
-    fwd = jax.lax.fori_loop(0, steps, fwd_step, (zf, zf, zi, no_best))[3]
+    with global_timer.device_scope("Split::missing_right"):
+        fwd = jax.lax.fori_loop(0, steps, fwd_step,
+                                (zf, zf, zi, no_best))[3]
     # forward replaces reverse only on strictly larger gain (ref: hpp:1031)
     use_fwd = fwd[0] > rev[0]
     gain, thr, lg, lh_raw, lc = (jnp.where(use_fwd, f, r)

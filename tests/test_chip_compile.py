@@ -365,21 +365,98 @@ def test_grow_program_is_one_for_two_column_orders(one_chip, codes, rows,
         assert mem.temp_size_in_bytes < int(temp * 1.1), mem
 
 
+def _bucketize_memory(one_chip, n_pad, features, bounds):
+    """The bucketize program's memory analysis at [n_pad, features]
+    float32 with `bounds` bin bounds a column, compiled for the chip."""
+    from lightgbm_tpu.io.device_bin import _bucketize_program
+    return _bucketize_program().lower(
+        _sds((n_pad, features), "float32", one_chip),
+        _sds((features, bounds), "float32", one_chip),
+        _sds((features,), "bool", one_chip),
+        _sds((features,), "int32", one_chip), 1 << 16).compile(
+        ).memory_analysis()
+
+
 def test_bucketize_program_compiles_at_the_wide_shape(one_chip):
     """`io/device_bin.py` at 458,752 padded rows x 2,000 features: the
     float matrix (3.67 GB), its transposed copy and the bins fit the
     chip together, and the [65,536, 2,000, 62] compare is fused into its
     count (unfused it is 8.1 GB of its own: the temporaries read
     7,428,338,176 B with it fused)."""
-    from lightgbm_tpu.io.device_bin import _bucketize_program
-    n_pad = 458_752
-    compiled = _bucketize_program().lower(
-        _sds((n_pad, WIDE_F), "float32", one_chip),
-        _sds((WIDE_F, WIDE_B - 1), "float32", one_chip),
-        _sds((WIDE_F,), "bool", one_chip),
-        _sds((WIDE_F,), "int32", one_chip), 1 << 16).compile()
-    mem = compiled.memory_analysis()
+    mem = _bucketize_memory(one_chip, 458_752, WIDE_F, WIDE_B - 1)
     assert mem.temp_size_in_bytes < int(7_428_338_176 * 1.05), mem
+
+
+# the missing-value cell (`bosch-1000k-b63.train_missing`): 968 columns,
+# every one with a NaN bin, as the generator's columns bin at 63 bins
+# (the driver's `hist_classes` fact): 225 of up to 16 codes, 17 of up to
+# 32, 726 of 63; 1,000,000 rows
+MISSING_CLASSES = ((16, 225), (32, 17), (64, 726))
+MISSING_F, MISSING_N = 968, 1_000_448
+
+
+def test_bucketize_program_compiles_at_the_missing_cell_shape(one_chip):
+    """Device binning at 1,048,576 padded rows x 968 NaN-typed columns
+    (which columns hold a NaN bin, `nan_bin`, and read NaN as 0,
+    `nan_zero`, is data): the float matrix (4.06 GB, donated), the bins
+    (1.02 GB) and the temporaries, 6,090,416,640 B, fit the chip: the
+    cell's set-up peak, about 11.2 GB of 16.9."""
+    mem = _bucketize_memory(one_chip, 1 << 20, MISSING_F, 62)
+    assert mem.temp_size_in_bytes < int(6_090_416_640 * 1.05), mem
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < 12 << 30
+
+
+def test_grow_program_compiles_at_the_missing_cell_shape(one_chip):
+    """The whole-tree program at the missing-value cell's shape:
+    `uint8[968, 1,000,448]` in three histogram classes, every column
+    NaN-typed (`has_missing`), 255 leaves.  Two column orders lower to
+    one module (the classes are a sorted multiset, the order data).
+    Every wave takes the full kernel in class groups, each a call whose
+    one-hot rows AND bins block fit the scoped VMEM (a 305-column group
+    at 128 slots was refused for its bins block: PERF.md section 6); the
+    gain scan's forward pass carries its part `Split.missing_right`
+    under `Tree.split_find`; the temporaries are 961,283,072 B plus 10%."""
+    from lightgbm_tpu.learner import FeatureMeta
+    from lightgbm_tpu.learner.wave import grow_tree_wave
+    from lightgbm_tpu.ops.histogram import (hist_classes_of,
+                                            plan_wave_kernel)
+    from lightgbm_tpu.ops.split import SplitParams
+    F, rows = MISSING_F, MISSING_N
+    codes = np.repeat([c for c, _ in MISSING_CLASSES],
+                      [k for _, k in MISSING_CLASSES])
+    for slots in (8, 64, 128, 256):
+        plan = plan_wave_kernel(F, 63, slots, hist_classes=MISSING_CLASSES)
+        assert plan.groups > 1 and plan.vmem_bytes <= 16 << 20
+    by_col = {k: _sds((F,), "int32", one_chip)
+              for k in ("num_bin", "missing_type", "default_bin",
+                        "hist_order", "hist_inverse")}
+    meta = FeatureMeta(penalty=_sds((F,), "float32", one_chip), **by_col)
+    row = _sds((rows,), "float32", one_chip)
+    bins = _sds((F, rows), "uint8", one_chip)
+    args = (bins, row, row, row, _sds((F,), "bool", one_chip), meta)
+    split = SplitParams(min_data_in_leaf=20, has_missing=True)
+    texts = []
+    for seed in (1, 2):
+        classes, _ = hist_classes_of(
+            np.random.RandomState(seed).permutation(codes))
+        assert classes == MISSING_CLASSES
+        lowered = grow_tree_wave.lower(
+            *args, params=_grow_params(max_bin=63, hist_classes=classes,
+                                       split=split),
+            binned_classed=bins)
+        texts.append(lowered.as_text())
+    assert texts[0] == texts[1]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    calls = re.findall(r"^\s*%([\w.\-]+) = .*custom_call_target="
+                       r'"tpu_custom_call"', text, re.M)
+    assert {re.sub(r"\.\d+$", "", c) for c in calls} == {
+        "build_histogram_wave", "recolour_wave"}
+    assert re.search(r"/Tree\.split_find/[^\"]*/Split\.missing_right/", text)
+    assert "vmap(Split." not in text    # no reader of parts matches that
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < int(961_283_072 * 1.1), mem
 
 
 def _top_level_instructions(text):

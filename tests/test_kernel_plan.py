@@ -167,7 +167,11 @@ def test_classed_plans_of_the_cells(shape, classes, rows, hl_slots):
                 full.groups, full.onehot_rows) == (
                     "wave", shape[0], shape[0], 1, rows)
         assert full.class_groups == (classes,)
-        assert full.vmem_bytes == rows * (wave_slot_pad(ts) * 8 + 1024)
+        # its one-hot rows, its bins block (the columns to the uint8
+        # tile's 32, twice 512 B) and the fixed blocks
+        assert full.vmem_bytes == (rows * (wave_slot_pad(ts) * 8 + 1024)
+                                   + -(-shape[0] // 32) * 32 * 1024
+                                   + (24 << 10))
         assert full.vmem_bytes <= 16 << 20
     # the decomposed kernel itself is the unclassed plan's, split and all
     assert (_ladder_plan(shape, 1, hist_classes=classes).hl_split
@@ -201,13 +205,15 @@ def test_plan_takes_the_measured_winner(shape, classes, slots, winner):
 
 def test_ranking_cell_chain_tail_is_two_class_groups():
     """256 slots (the chain tail's traced call): 3,072 B a one-hot row,
-    so 5,461 rows a call — the 64-code class is cut after 75 columns."""
+    so 5,461 rows a call — the 64-code class is cut after 75 columns.
+    The call's VMEM counts its bins block too: 106 columns, to 128 rows
+    of the uint8 tile, twice 512 B, and the fixed blocks."""
     plan = plan_wave_kernel(*MSLR_63, 256, hist_classes=MSLR_CLASSES)
     assert plan.class_groups == (((16, 24), (32, 7), (64, 75)),
                                  ((64, 31),))
     assert (plan.groups, plan.feature_group, plan.onehot_rows) == (
         2, 106, 7392)
-    assert plan.vmem_bytes == 5408 * 3072
+    assert plan.vmem_bytes == 5408 * 3072 + 128 * 1024 + (24 << 10)
 
 
 @pytest.mark.parametrize("shape", [HIGGS_255, HIGGS_63, EPSILON_63,
