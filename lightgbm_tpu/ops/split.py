@@ -169,6 +169,42 @@ def _round_int(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.floor(x + 0.5).astype(jnp.int32)
 
 
+# the sorted-subset scan's recurrences run as straight-line code up to
+# this many steps (upstream's default max_cat_threshold), past it as a
+# loop over blocks of this many: a large max_cat_threshold compiles no
+# more steps than the default
+_CAT_UNROLL = 32
+
+
+def _rows_of(a, idx):
+    """a[idx] along the leading axis without a gather: integers by a
+    masked reduce, floats by a one-hot selection matmul at HIGHEST
+    precision (the wave engine's cache idiom: one nonzero a row, so no
+    accumulation and the float32 operand comes back bit for bit, but for
+    a -0.0, which the zeros added to it make +0.0)."""
+    hit = idx[:, None] == jnp.arange(a.shape[0], dtype=jnp.int32)[None, :]
+    if jnp.issubdtype(a.dtype, jnp.integer):
+        hit = hit.reshape(hit.shape + (1,) * (a.ndim - 1))
+        return jnp.sum(jnp.where(hit, a[None], 0), axis=1, dtype=a.dtype)
+    return jnp.tensordot(hit.astype(a.dtype), a, axes=1,
+                         precision=jax.lax.Precision.HIGHEST)
+
+
+def _shift_left(x, shift):
+    """Row r of x [F, B] moved left by shift[r] (0 <= shift < B): entry i
+    reads x[r, i + shift[r]], zeros past the end.  One select between two
+    static shifts a bit of the shift, as `ranking.py _read_block` moves a
+    query; the widths shrink with it."""
+    F, B = x.shape
+    bits = max(B - 1, 1).bit_length()
+    x = jnp.pad(x, ((0, 0), (0, (1 << bits) - 1)))
+    for k in reversed(range(bits)):
+        step = 1 << k
+        x = jnp.where((shift >> k & 1).astype(bool)[:, None],
+                      x[:, step:], x[:, :-step])
+    return x[:, :B]
+
+
 def _cat_best_split(grad, hess, cnt_factor, num_bin, sum_g, sum_h, num_data,
                     parent_output, min_gain_shift, p: SplitParams,
                     rand_u=None):
@@ -177,6 +213,17 @@ def _cat_best_split(grad, hess, cnt_factor, num_bin, sum_g, sum_h, num_data,
 
     Bin 0 is the NaN/other bin and never enters a left set (the reference
     scans actual bins [1, num_bin); unseen/NaN categories route right).
+
+    Dense tensor work with no data-dependent gather and, up to
+    `_CAT_UNROLL` steps, no loop: ONE stable sort along the bins, keyed
+    on grad / (hess + cat_smooth), carries the sums, counts and bin
+    numbers; the backward view is that flipped and each row moved left
+    by `B - used_bin` (static-shift selects), stacked under the forward
+    one; the left sums (adds in the reference's order) and the group
+    counter's resets run step by step as straight-line code, the gates
+    and gains of all steps at once; the chosen prefix is read by a
+    masked reduce.  Bit for bit what the reference's loop computes in
+    float32 (`tests/test_cat_scan_dense.py`).
 
     Under extra_trees (USE_RAND), rand_u is [F, 2] uniforms: one draw
     picks the single one-hot candidate bin (rand.NextInt(bin_start,
@@ -223,20 +270,22 @@ def _cat_best_split(grad, hess, cnt_factor, num_bin, sum_g, sum_h, num_data,
                          sum_g - grad, sum_h - hess - K_EPSILON,
                          num_data - cnt, oh_ok, p)
     oh_best = jnp.argmax(oh_gain, axis=1).astype(i32)
-    take1 = lambda a, idx: jnp.take_along_axis(a, idx[:, None], 1)[:, 0]
-    oh_best_gain = take1(oh_gain, oh_best)
+    take1 = lambda a: _take_one(a, oh_best[:, None])
+    oh_best_gain = take1(oh_gain)
 
     # ---- sorted-subset mode ----
-    # categories with enough data, stably sorted by grad/(hess+cat_smooth)
+    # categories with enough data, stably sorted by grad/(hess+cat_smooth);
+    # the sums and the bin numbers ride the sort as payloads
     valid = in_range & (cnt >= p.cat_smooth)
     ratio = jnp.where(valid, grad / (hess + p.cat_smooth), jnp.inf)
-    order = jnp.argsort(ratio, axis=1, stable=True).astype(i32)  # [F, B]
-    sg_s = jnp.take_along_axis(grad, order, 1)
-    sh_s = jnp.take_along_axis(hess, order, 1)
-    sc_s = jnp.take_along_axis(cnt, order, 1)
+    _, sg_s, sh_s, sc_s, order = jax.lax.sort(
+        (ratio, grad, hess, cnt, jnp.broadcast_to(bins, (F, B))),
+        dimension=1, is_stable=True, num_keys=1)
     used_bin = jnp.sum(valid, axis=1, dtype=i32)                # [F]
     max_num_cat = jnp.minimum(p.max_cat_threshold, (used_bin + 1) // 2)
     steps = min(p.max_cat_threshold, B)
+    global_registry.inc("cat_scan_unrolled_traces" if steps <= _CAT_UNROLL
+                        else "cat_scan_looped_traces")
     if p.extra_trees and rand_u is not None:
         # single random prefix length in [0, max_threshold) where
         # max_threshold = max(min(max_num_cat, used_bin) - 1, 0)
@@ -245,73 +294,73 @@ def _cat_best_split(grad, hess, cnt_factor, num_bin, sum_g, sum_h, num_data,
         sub_rand = jnp.clip((rand_u[:, 1] * jnp.maximum(max_thr, 1.0))
                             .astype(i32), 0,
                             jnp.maximum(max_thr.astype(i32) - 1, 0))
+    # both views as one slab of 2F columns, the forward one (smallest
+    # ratio first) over the backward one: entry i of the flipped sort
+    # moved left by B - used_bin is sorted[used_bin - 1 - i] (the clip
+    # makes used_bin = 0 read sorted[0] at i = 0; past used_bin the gains
+    # are masked).  Steps lead: [steps, 2F].
+    back = jnp.minimum(B - used_bin, B - 1)
+    g_d, h_d, c_d = (
+        jnp.concatenate([v, _shift_left(v[:, ::-1], back)])[:, :steps].T
+        for v in (sg_s, sh_s, sc_s))
+    in_limit = jnp.tile(jnp.minimum(used_bin, max_num_cat), 2)
+    if p.extra_trees and rand_u is not None:
+        sub_rand = jnp.tile(sub_rand, 2)
 
-    def scan_dir(fwd: bool):
-        if fwd:
-            g_d, h_d, c_d = sg_s, sh_s, sc_s
-        else:  # from the largest-ratio end over the VALID entries
-            pos = used_bin[:, None] - 1 - jnp.arange(B, dtype=i32)[None, :]
-            idx = jnp.clip(pos, 0, B - 1)
-            g_d = jnp.take_along_axis(sg_s, idx, 1)
-            h_d = jnp.take_along_axis(sh_s, idx, 1)
-            c_d = jnp.take_along_axis(sc_s, idx, 1)
+    def running(carry, x):
+        """The left sums, one add a step: the reference's order."""
+        return (tuple(a + b for a, b in zip(carry, x)),) * 2
 
-        def step(carry, i):
-            cum, lg, lh, lc = carry
-            lg = lg + g_d[:, i]
-            lh = lh + h_d[:, i]
-            lc = lc + c_d[:, i]
-            cum = cum + c_d[:, i]
-            rc = num_data - lc
-            rh = sum_h - lh
-            rg = sum_g - lg
-            # the reference's break conditions (right side shrinking) are
-            # monotone in i, so masking == breaking; the group counter
-            # resets whenever a candidate reaches evaluation, even if its
-            # gain then fails min_gain_shift (cpp:296-318 order)
-            left_ok = ((lc >= p.min_data_in_leaf)
-                       & (lh >= p.min_sum_hessian_in_leaf))
-            right_ok = ((rc >= p.min_data_in_leaf)
-                        & (rc >= p.min_data_per_group)
-                        & (rh >= p.min_sum_hessian_in_leaf))
-            in_limit = i < jnp.minimum(used_bin, max_num_cat)
-            eligible = (in_limit & left_ok & right_ok
-                        & (cum >= p.min_data_per_group))
-            raw = (leaf_gain(lg, lh, lc.astype(f32), parent_output, pcat)
-                   + leaf_gain(rg, rh, rc.astype(f32), parent_output, pcat))
-            gain_ok = eligible & (raw > min_gain_shift)
-            if p.extra_trees and rand_u is not None:
-                # USE_RAND: only the random prefix scores, but the group
-                # counter still resets on skipped candidates (cpp:310-317)
-                gain_ok = gain_ok & (i == sub_rand)
-            gain = jnp.where(gain_ok, raw, K_MIN_SCORE)
-            cum = jnp.where(eligible, 0, cum)
-            return (cum, lg, lh, lc), (gain, lg, lh, lc)
+    def group(cum, x):
+        """The group counter resets whenever a candidate reaches
+        evaluation, even if its gain then fails min_gain_shift
+        (cpp:296-318 order)."""
+        c, ok = x
+        cum = cum + c
+        eligible = ok & (cum >= p.min_data_per_group)
+        return jnp.where(eligible, 0, cum), eligible
 
-        init = (jnp.zeros(F, i32), jnp.zeros(F, f32),
-                jnp.full(F, K_EPSILON, f32), jnp.zeros(F, i32))
-        _, (gains, lgs, lhs, lcs) = jax.lax.scan(
-            step, init, jnp.arange(steps, dtype=i32))
-        # [steps, F] -> best prefix per feature
-        best_i = jnp.argmax(gains, axis=0).astype(i32)
-        takeS = lambda a: jnp.take_along_axis(a, best_i[None, :], 0)[0]
-        return takeS(gains), takeS(lgs), takeS(lhs), takeS(lcs), best_i
-
-    fw_gain, fw_lg, fw_lh, fw_lc, fw_i = scan_dir(True)
-    bw_gain, bw_lg, bw_lh, bw_lc, bw_i = scan_dir(False)
-    use_fw = fw_gain >= bw_gain
-    so_gain = jnp.where(use_fw, fw_gain, bw_gain)
-    so_lg = jnp.where(use_fw, fw_lg, bw_lg)
-    so_lh = jnp.where(use_fw, fw_lh, bw_lh)
-    so_lc = jnp.where(use_fw, fw_lc, bw_lc)
-    so_i = jnp.where(use_fw, fw_i, bw_i)
+    # the two recurrences step by step: straight-line code up to
+    # _CAT_UNROLL steps, a loop over blocks of _CAT_UNROLL past it;
+    # everything else for all the steps at once
+    unroll = min(steps, _CAT_UNROLL)
+    step_i = jnp.arange(steps, dtype=i32)[:, None]
+    _, (lg, lh, lc) = jax.lax.scan(
+        running, (jnp.zeros(2 * F, f32), jnp.full(2 * F, K_EPSILON, f32),
+                  jnp.zeros(2 * F, i32)), (g_d, h_d, c_d), unroll=unroll)
+    rc = num_data - lc
+    rh = sum_h - lh
+    # the reference's break conditions (right side shrinking) are
+    # monotone in the step, so masking == breaking
+    ok = ((step_i < in_limit)
+          & (lc >= p.min_data_in_leaf) & (lh >= p.min_sum_hessian_in_leaf)
+          & (rc >= p.min_data_in_leaf) & (rc >= p.min_data_per_group)
+          & (rh >= p.min_sum_hessian_in_leaf))
+    _, eligible = jax.lax.scan(group, jnp.zeros(2 * F, i32), (c_d, ok),
+                               unroll=unroll)
+    raw = (leaf_gain(lg, lh, lc.astype(f32), parent_output, pcat)
+           + leaf_gain(sum_g - lg, rh, rc.astype(f32), parent_output, pcat))
+    gain_ok = eligible & (raw > min_gain_shift)
+    if p.extra_trees and rand_u is not None:
+        # USE_RAND: only the random prefix scores, but the group counter
+        # still resets on skipped candidates (cpp:310-317)
+        gain_ok = gain_ok & (step_i == sub_rand)
+    gains = jnp.where(gain_ok, raw, K_MIN_SCORE)
+    # each column's best prefix (the first of equal gains), in each view
+    best_i = jnp.argmax(gains, axis=0).astype(i32)
+    best = [_take_one(v.T, best_i[:, None]) for v in (gains, lg, lh, lc)]
+    # forward wins ties: the backward view replaces it only on a
+    # strictly larger gain
+    use_fw = best[0][:F] >= best[0][F:]
+    so_gain, so_lg, so_lh, so_lc, so_i = (
+        jnp.where(use_fw, v[:F], v[F:]) for v in best + [best_i])
 
     # per-feature mode choice is static in num_bin (hpp use_onehot)
     use_onehot = num_bin <= p.max_cat_to_onehot
     gain = jnp.where(use_onehot, oh_best_gain, so_gain)
-    left_g = jnp.where(use_onehot, take1(grad, oh_best), so_lg)
-    left_h = jnp.where(use_onehot, take1(hess, oh_best) + K_EPSILON, so_lh)
-    left_c = jnp.where(use_onehot, take1(cnt, oh_best), so_lc)
+    left_g = jnp.where(use_onehot, take1(grad), so_lg)
+    left_h = jnp.where(use_onehot, take1(hess) + K_EPSILON, so_lh)
+    left_c = jnp.where(use_onehot, take1(cnt), so_lc)
     return (gain, left_g, left_h, left_c, use_onehot, oh_best, use_fw,
             so_i, used_bin, order)
 
@@ -705,25 +754,30 @@ def find_best_split(hist: jnp.ndarray, num_bin: jnp.ndarray,
 
     cat = None
     if params.has_categorical:
-        # the expensive scan (argsort + two sequential prefix scans) runs
-        # only over the categorical rows, gathered into an [F_cat, B]
-        # subarray; results scatter back into the [F] arrays.  Which
-        # columns they are is data and only their count is static, so
-        # two column orders of one data set are one program.  `Cat::scan`
-        # is a part of the caller's `Tree::split_find`; entered in this
-        # jitted body, a vmap over leaves reaches the ops' names as
+        # the sorted-subset scan runs only over the categorical rows,
+        # selected into an [F_cat, B] subarray (`_rows_of`: no gather);
+        # results scatter back into the [F] arrays.  Which columns they
+        # are is data and only their count is static, so two column
+        # orders of one data set are one program.  `Cat::scan` is a part
+        # of the caller's `Tree::split_find`; entered in this jitted
+        # body, a vmap over leaves reaches the ops' names as
         # `vmap(jit(find_best_split))/Cat.scan`, which a part reader matches
         with global_timer.device_scope("Cat::scan"):
-            ci = (jnp.arange(num_features, dtype=jnp.int32)
-                  if cat_index is None else cat_index.astype(jnp.int32))
+            if cat_index is None:
+                ci = jnp.arange(num_features, dtype=jnp.int32)
+                rows = lambda a: a
+            else:
+                ci = cat_index.astype(jnp.int32)
+                rows = functools.partial(_rows_of, idx=ci)
+            cat_hist = rows(hist)
             cat = (ci, is_cat_feature) + _cat_best_split(
-                hist[ci, :, 0], hist[ci, :, 1],
+                cat_hist[:, :, 0], cat_hist[:, :, 1],
                 num_data.astype(jnp.float32) / sum_h,
-                num_bin[ci], sum_g, sum_h, num_data, parent_output,
+                rows(num_bin), sum_g, sum_h, num_data, parent_output,
                 _min_gain_shift(sum_g, sum_h, num_data, parent_output,
                                 params),
                 params,
-                rand_u=None if rand_cat_u is None else rand_cat_u[ci])
+                rand_u=None if rand_cat_u is None else rows(rand_cat_u))
 
     clamp_winner = None
     if params.has_monotone:

@@ -23,6 +23,7 @@ collect.  Everything built from the topology is built in a fixture or a
 test, in this one file, in this process.
 """
 
+import collections
 import re
 
 import numpy as np
@@ -287,6 +288,22 @@ def test_classed_wave_kernel_compiles_within_scoped_vmem(one_chip, codes,
     assert compiled.as_text().count("tpu_custom_call") >= plan.groups
 
 
+_HLO_OP = re.compile(
+    r"^\s*(?:ROOT\s+)?%[\w.\-]+ = (?:\(.*?\)|\S+) ([\w\-]+)\(")
+
+
+def _ops_under(text, part):
+    """{opcode: count} of the compiled module's instructions, fused ones
+    included, whose op_name holds `part`."""
+    kinds = collections.Counter()
+    for line in text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        op = _HLO_OP.match(line)
+        if name and op and part in name.group(1) + "/":
+            kinds[op.group(1)] += 1
+    return kinds
+
+
 # the categorical cell (`expo-cat8-b63.train_cat`): 8 columns, 6 of them
 # categorical with up to 189 bins (the 99%-coverage rule), at Expo's rows
 CAT_COLUMNS = 6
@@ -361,6 +378,10 @@ def test_grow_program_is_one_for_two_column_orders(one_chip, codes, rows,
         assert "/Tree.split_find/" in text and "/Cat.scan/" in text
         assert "vmap(Cat." not in text      # no reader of parts matches that
         assert re.search(r"/Tree\.split_find/[^\"]*/Cat\.scan/", text)
+        # the scan is dense work: one sort, and under `Cat.scan` no
+        # gather and no loop at the default max_cat_threshold
+        kinds = _ops_under(text, "/Cat.scan/")
+        assert kinds["sort"] and not kinds["gather"] + kinds["while"], kinds
         mem = compiled.memory_analysis()
         assert mem.temp_size_in_bytes < int(temp * 1.1), mem
 
