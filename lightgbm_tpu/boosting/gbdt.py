@@ -824,10 +824,13 @@ class GBDT:
                 as_f32(t.leaf_parent), as_f32(t.leaf_depth),
                 as_f32(t.split_is_cat),
                 as_f32(t.cat_bitset.reshape(-1)),
-                # the wave engine's own count of the waves it ran rides
-                # along (no transfer of its own)
-                as_f32(jnp.asarray(0 if t.waves is None
-                                   else t.waves)[None])])
+                # the wave engine's own counts of the waves it ran, of
+                # those whose histogram call ran narrower than the wave's
+                # bound, and of those calls' N tiles ride along (no
+                # transfer of their own)
+                as_f32(jnp.stack([jnp.asarray(0 if c is None else c)
+                                  for c in (t.waves, t.narrowed_waves,
+                                            t.slot_tiles)]))])
         if jax.process_count() > 1 and self.mesh is not None:
             # multi-process SPMD: GSPMD may assign the packed buffer a
             # sharding spanning other processes' devices, which the host
@@ -1431,7 +1434,9 @@ class GBDT:
     def _packed_to_tree(self, flat: np.ndarray) -> Optional[Tree]:
         """Decode the packed flat tree buffer into a host Tree."""
         ints = flat.view(np.int32)
-        _metrics.inc("waves_total", int(ints[-1]))
+        _metrics.inc("waves_total", int(ints[-3]))
+        _metrics.inc("hist_narrowed_waves_total", int(ints[-2]))
+        _metrics.inc("hist_slot_tiles_total", int(ints[-1]))
         L = self.config.num_leaves
         ni = max(L - 1, 1)
         W = self._cat_words

@@ -283,8 +283,11 @@ class TreeArrays(NamedTuple):
     split_is_cat: jnp.ndarray = None  # [L-1] bool (categorical split)
     cat_bitset: jnp.ndarray = None    # [L-1, W] int32 bins-left bitsets
     # what growing it took (wave engine only; rides the packed tree to the
-    # registry's waves_total, boosting/gbdt.py)
+    # registry's waves_total, hist_narrowed_waves_total and
+    # hist_slot_tiles_total, boosting/gbdt.py)
     waves: jnp.ndarray = None         # scalar int32: waves that ran
+    narrowed_waves: jnp.ndarray = None  # of them, run below their bound
+    slot_tiles: jnp.ndarray = None    # 128-wide N tiles of their calls
 
 
 class _PendingSplits(NamedTuple):

@@ -183,24 +183,30 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
     quant = (dict(quant_bins=params.quant_bins, quant_scales=quant_scales)
              if use_int8 else {})
 
-    def hists_of(kslot, ghm, num_slots, true_slots=None):
+    def hists_of(kslot, ghm, num_slots, true_slots=None, live_slots=None):
         """Group-space histograms for the COMPUTED (compact) slots only;
         rows outside computed leaves carry zeroed gh channels.  The full
         per-leaf set is completed by sibling subtraction at the cache.
         `true_slots` (<= num_slots) is the unpadded computed-slot bound:
         when it is small the decomposed hi/lo kernel streams far less
-        VMEM volume (ops/histogram.py plan_wave_kernel picks)."""
+        VMEM volume (ops/histogram.py plan_wave_kernel picks).
+        `live_slots` (traced) is the count the wave really computes; the
+        kernel's call runs at the narrowest width that holds it
+        (`wave_histograms`), whose slots come back as the third value."""
         with global_timer.device_scope("Tree::histogram"):
             if use_pallas:
-                H, cnt = wave_histograms(
+                H, cnt, width = wave_histograms(
                     binned, binned_rm, kslot, ghm, max_bin=hist_B,
-                    num_slots=num_slots, true_slots=true_slots, **quant,
-                    **classed)
+                    num_slots=num_slots, true_slots=true_slots,
+                    live_slots=live_slots, **quant, **classed)
             else:
                 H, cnt = _hist_wave_xla(binned, kslot, ghm, max_bin=hist_B,
                                         num_slots=num_slots)
-            # shard-local -> global (psum is a no-op single-device)
-            return _psum(H), _psum(cnt)
+                width = jnp.asarray(num_slots, i32)
+            # shard-local -> global (psum is a no-op single-device); the
+            # switch's index is replicated state, so every shard ran the
+            # same width
+            return _psum(H), _psum(cnt), width
 
     if sp.extra_trees:
         _extra_key = jax.random.PRNGKey(sp.extra_seed)
@@ -385,7 +391,9 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
         leaf_depth=jnp.zeros(Lp, i32),
         split_is_cat=jnp.zeros(ni, bool),
         cat_bitset=jnp.zeros((ni, W), i32),
-        waves=jnp.asarray(0, i32))
+        waves=jnp.asarray(0, i32),
+        narrowed_waves=jnp.asarray(0, i32),
+        slot_tiles=jnp.asarray(0, i32))
 
     # per-leaf running sums / outputs for the gain scan
     leaf_sum_g0 = jnp.zeros(Lp, f32).at[0].set(sum_g0)
@@ -425,12 +433,16 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
         assigned during the PREVIOUS wave's recolor (rows outside a
         computed leaf carry the out-of-range sentinel Lp, which matches no
         slot one-hot bucket — no per-row gather or gh masking needed
-        here)."""
-        H, cnt = hists_of(kslot, gh, Kb, Ks)           # [Kb, F', B', 2]
+        here).  The previous wave's splits are the live slots (its pair
+        ranks run 0 .. splits - 1), which the kernel's call is narrowed
+        to; the root wave has none.  Returns the cache and the slots the
+        call ran at."""
+        live = None if first else jnp.sum(pend_sel, dtype=i32)
+        H, cnt, width = hists_of(kslot, gh, Kb, Ks, live)  # [Kb, F', B', 2]
         with global_timer.device_scope("Tree::cache"):
-            return _complete_cache(H, cnt, cache_h, cache_c, pend_sel,
-                                   pend_new, pend_rank, pend_sl, Kb,
-                                   first)
+            return (*_complete_cache(H, cnt, cache_h, cache_c, pend_sel,
+                                     pend_new, pend_rank, pend_sl, Kb,
+                                     first), width)
 
     def _complete_cache(H, cnt, cache_h, cache_c, pend_sel, pend_new,
                         pend_rank, pend_sl, Kb, first):
@@ -513,9 +525,9 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
         #    whose histograms changed (all of them on the first wave /
         #    non-incremental modes; DataPartition cnt_leaf_data exactness
         #    rides the count cache)
-        cache_h, cache_c = wave_hists(kslot, cache_h, cache_c, pend_sel,
-                                      pend_new, pend_rank, pend_sl, Kb,
-                                      first, Ks)
+        cache_h, cache_c, width = wave_hists(kslot, cache_h, cache_c,
+                                             pend_sel, pend_new, pend_rank,
+                                             pend_sl, Kb, first, Ks)
         counts = jnp.round(cache_c[:NLp]).astype(i32)
         active = jnp.arange(NLp, dtype=i32) < NL
         rb = (_rand_bins(tree.num_leaves)[:NLp] if sp.extra_trees else None)
@@ -706,8 +718,12 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
             leaf_count=leaf_count, leaf_parent=leaf_parent,
             leaf_depth=leaf_depth,
             split_is_cat=split_is_cat, cat_bitset=cat_bitset,
-            # one histogram pass over the rows per wave
-            waves=t.waves + 1)
+            # one histogram pass over the rows per wave, and what its
+            # call ran at: narrower than the bound Kb, and its 128-wide
+            # N tiles (2 channels x the padded slots)
+            waves=t.waves + 1,
+            narrowed_waves=t.narrowed_waves + (width < Kb).astype(i32),
+            slot_tiles=t.slot_tiles + (2 * width + 127) // 128)
 
         # 4. recolour rows (ops/recolour.py): the wave's per-leaf records
         # as one small byte table, each row's record read from it by the
@@ -1000,7 +1016,8 @@ def grow_tree_wave_impl(binned: jnp.ndarray, grad: jnp.ndarray,
             leaf_depth=leaf_depth_f,
             split_is_cat=gat(tree.split_is_cat, False),
             cat_bitset=gat(tree.cat_bitset),
-            waves=tree.waves)
+            waves=tree.waves, narrowed_waves=tree.narrowed_waves,
+            slot_tiles=tree.slot_tiles)
 
         # rows: overgrown leaf slot -> nearest kept ancestor's side leaf.
         # Walk up until the current node is kept (or the root is passed);

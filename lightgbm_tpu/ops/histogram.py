@@ -1001,9 +1001,24 @@ def build_histogram_wave(binned_fm: jnp.ndarray, slot: jnp.ndarray,
     return hist, cnt[0, :num_slots]
 
 
+def narrow_widths(num_slots: int) -> Tuple[int, ...]:
+    """The slot widths a wave of static bound `num_slots` may run its
+    histogram call at: 64, 128, 256, ... below `num_slots`, then
+    `num_slots` itself.  To 64 slots the call's N (2 channels x the
+    slots) is one 128-wide MXU tile whatever it holds, so a bound of 64
+    or less has one width; past it each halving of the width halves the
+    N tiles the call's dots pay for."""
+    widths, w = [], 64
+    while w < num_slots:
+        widths.append(w)
+        w *= 2
+    return tuple(widths) + (num_slots,)
+
+
 def wave_histograms(binned_fm: jnp.ndarray, binned_rm: jnp.ndarray,
                     slot: jnp.ndarray, gh: jnp.ndarray, *, max_bin: int,
                     num_slots: int, true_slots: Optional[int] = None,
+                    live_slots: jnp.ndarray = None,
                     quant_bins: int = 0, quant_scales: jnp.ndarray = None,
                     hist_classes: tuple = (),
                     binned_classed: jnp.ndarray = None,
@@ -1019,27 +1034,60 @@ def wave_histograms(binned_fm: jnp.ndarray, binned_rm: jnp.ndarray,
     `binned_classed`, the caller's `binned_fm[hist_order]`, and its
     histograms come back through `hist_inverse` (the order's inverse):
     every caller sees its own column order, whatever kernel ran.
-    Returns (hist [num_slots, F, B, C] float32, counts [num_slots])."""
+
+    `live_slots` (traced int32 scalar) is the count of slots the wave
+    really computes: every row's `slot` is below it or out of range (the
+    slots are compact ranks).  Where it is given and the wave is bounded
+    past 64 slots, one `lax.switch` runs the call at the narrowest
+    of `narrow_widths(num_slots)` that holds the live slots — the plan
+    of that width, its labels by `mxu_call_scope`'s rule at that width —
+    and zero-pads its histograms and counts back to `num_slots`: the
+    same numbers (a column's sum does not depend on how many other
+    columns a dot carries), at the N tiles the wave uses and not at
+    its static bound's.
+    Returns (hist [num_slots, F, B, C] float32, counts [num_slots],
+    width): `width` the slots the call ran at (int32 scalar)."""
     F, C = binned_fm.shape[0], gh.shape[0] - 1
-    plan = plan_wave_kernel(F, max_bin, num_slots, true_slots,
-                            int8=quant_scales is not None, C=C,
-                            hist_classes=hist_classes)
-    # what the call asks of the MXU rides its kernel events' `op_name`
-    with mxu_call_scope(plan, F, num_slots, true_slots, C):
-        if plan.kernel == "wave_hl":
-            return build_histogram_wave_hl(
-                binned_fm, binned_rm, slot, gh, max_bin=max_bin,
-                num_slots=true_slots, out_slots=num_slots)
-        # Rt stays 512: 1024 is ~3% faster on small slot counts but
-        # exceeds the 16 MB scoped-VMEM limit at 128 slots
-        if plan.class_groups:
-            hist, cnt = build_histogram_wave(
-                binned_classed, slot, gh, max_bin=max_bin,
-                num_slots=num_slots, hist_classes=hist_classes)
-            return jnp.take(hist, hist_inverse, axis=1, mode="clip"), cnt
-        return build_histogram_wave(
-            binned_fm, slot, gh, max_bin=max_bin, num_slots=num_slots,
-            quant_bins=quant_bins, quant_scales=quant_scales)
+
+    def call(width, true_width):
+        plan = plan_wave_kernel(F, max_bin, width, true_width,
+                                int8=quant_scales is not None, C=C,
+                                hist_classes=hist_classes)
+        # what the call asks of the MXU rides its kernel events' `op_name`
+        with mxu_call_scope(plan, F, width, true_width, C):
+            if plan.kernel == "wave_hl":
+                return build_histogram_wave_hl(
+                    binned_fm, binned_rm, slot, gh, max_bin=max_bin,
+                    num_slots=true_width, out_slots=width)
+            # Rt stays 512: 1024 is ~3% faster on small slot counts but
+            # exceeds the 16 MB scoped-VMEM limit at 128 slots
+            if plan.class_groups:
+                hist, cnt = build_histogram_wave(
+                    binned_classed, slot, gh, max_bin=max_bin,
+                    num_slots=width, hist_classes=hist_classes)
+                return jnp.take(hist, hist_inverse, axis=1, mode="clip"), cnt
+            return build_histogram_wave(
+                binned_fm, slot, gh, max_bin=max_bin, num_slots=width,
+                quant_bins=quant_bins, quant_scales=quant_scales)
+
+    widths = narrow_widths(num_slots)
+    if live_slots is None or len(widths) == 1:
+        return (*call(num_slots, true_slots),
+                jnp.asarray(num_slots, jnp.int32))
+
+    def at(width):
+        def branch():
+            hist, cnt = call(width, true_slots if width == num_slots
+                             else None)
+            pad = num_slots - width
+            return (jnp.pad(hist, ((0, pad), (0, 0), (0, 0), (0, 0))),
+                    jnp.pad(cnt, (0, pad)))
+        return branch
+    # the number of widths below the live slots: the narrowest that
+    # holds them
+    index = sum((live_slots > w).astype(jnp.int32) for w in widths[:-1])
+    hist, cnt = jax.lax.switch(index, [at(w) for w in widths])
+    return hist, cnt, jnp.asarray(widths, jnp.int32)[index]
 
 
 @functools.partial(jax.jit, static_argnames=("max_bin", "method", "row_chunk"))

@@ -310,8 +310,8 @@ CAT_COLUMNS = 6
 
 
 @pytest.mark.parametrize("codes,rows,max_bin,cat,onehot_rows,full,hl,temp", [
-    (MSLR_CODES, MSLR_N, 63, 0, 7392, 9, 0, None),
-    (CAT_CODES, EXPO_N, 189, CAT_COLUMNS, 592, 9, 0, 335_155_200)])
+    (MSLR_CODES, MSLR_N, 63, 0, 7392, 10, 0, None),
+    (CAT_CODES, EXPO_N, 189, CAT_COLUMNS, 592, 10, 0, 335_155_200)])
 def test_grow_program_is_one_for_two_column_orders(one_chip, codes, rows,
                                                    max_bin, cat, onehot_rows,
                                                    full, hl, temp):
@@ -322,7 +322,8 @@ def test_grow_program_is_one_for_two_column_orders(one_chip, codes, rows,
     the data in `FeatureMeta.hist_order` / `hist_inverse` and in the
     class-ordered copy of the bins — so the second order finds the
     first's executable in the compile cache.  It compiles, every wave
-    through the full kernel classed (`f32[7392, .]` results): the
+    through the full kernel classed (`f32[7392, .]` results; ten calls
+    for nine waves, the chain-tail wave's at 64 and at 128 slots): the
     decomposed one would ask 0.76 of its FLOP at one slot, and no
     row-major copy of the bins is made.
 
@@ -539,8 +540,10 @@ def test_grow_program_names_its_kernels_and_scopes(grow_compiled):
     """What the benchmark's trace readers hold on to: each Pallas
     custom-call is named after its kernel (`pallas_call(name=...)`; the
     profiler's event name starts with the instruction's), a histogram
-    and a recolour a wave, 9 waves a tree, and the ops around them carry
-    the program's scopes in `op_name`.  `recolour_wave` is not a
+    and a recolour a wave, 9 waves a tree (the chain-tail wave's
+    histogram at two widths, 64 and 128 slots: one call a branch of its
+    switch), and the ops around them carry the program's scopes in
+    `op_name`.  `recolour_wave` is not a
     `^%build_histogram`: the benchmark reads it by its scope, under
     `Tree.partition`, which the custom call's own `op_name` holds."""
     calls = _kernel_calls(grow_compiled)
@@ -550,7 +553,7 @@ def test_grow_program_names_its_kernels_and_scopes(grow_compiled):
     assert heads == ["build_histogram_wave", "build_histogram_wave_hl",
                      "recolour_wave"]
     assert sum(n.startswith("recolour_wave") for n in names) == 9
-    assert len(calls) == 18
+    assert len(calls) == 19
     for name, call in zip(names, calls):
         if name.startswith("recolour_wave"):
             assert re.search(r'op_name="[^"]*/Tree\.partition/[^"]*"', call)
@@ -651,11 +654,14 @@ def _hist_kernel_calls(compiled):
 def _kernel_labels(compiled):
     """[(instruction, (n, f, e) or None)] of the program's histogram
     custom-calls: the `Hist.mxu_n<n>_f<f>_e<e>` part in each one's
-    `op_name` (`ops/histogram.py mxu_call_scope`)."""
+    `op_name` (`ops/histogram.py mxu_call_scope`), inside
+    `Tree.histogram` — past the `cond/branch_<i>_fun` of the switch that
+    picks a wave's width, where it has one."""
     out = []
     for instr in _hist_kernel_calls(compiled):
         name = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", instr).group(1)
         part = re.search(r'op_name="[^"]*/Tree\.histogram/'
+                         r'(?:cond/branch_\d+_fun/)?'
                          r'Hist\.mxu_n(\d+)_f(\d+)_e(\d+)/[^"]*"', instr)
         out.append((name, part and tuple(map(int, part.groups()))))
     return out
@@ -672,8 +678,9 @@ def test_every_kernel_call_carries_what_it_asks_of_the_mxu(
     `Tree.histogram`, and the
     ladder's labels are the 255-leaf tree's: 1, 1, 2, 4 true slots
     through `_hl`, 8 to 64 through the full kernel at 1,837,056 FLOP a
-    row (which `_hl` would ask at 8 too), and the `while_loop`'s 128-slot
-    wave, which names none."""
+    row (which `_hl` would ask at 8 too), and the `while_loop`'s wave,
+    which names no true slots, at its two widths: 64 slots (one N tile,
+    where its live slots fit) and its bound of 128."""
     labels = _kernel_labels(request.getfixturevalue(program))
     assert len(labels) >= 9
     assert all(part is not None for _, part in labels), labels
@@ -684,7 +691,7 @@ def test_every_kernel_call_carries_what_it_asks_of_the_mxu(
         (2, 493568, 1), (2, 493568, 1), (4, 690176, 1), (8, 919552, 1)]
     assert sorted(by_kernel["build_histogram_wave"]) == [
         (16, 1837056, 1), (32, 1837056, 1), (64, 1837056, 1),
-        (128, 1837056, 1), (256, 3672064, 1)]
+        (128, 1837056, 1), (128, 1837056, 1), (256, 3672064, 1)]
 
 
 def test_each_kernel_call_holds_its_count_where_the_cache_key_sees_it(
@@ -696,7 +703,7 @@ def test_each_kernel_call_holds_its_count_where_the_cache_key_sees_it(
     the `f` of the call's label."""
     stated = [re.search(r'kernel_metadata=\{\s*"mxu_flop_per_row":"(\d+)"',
                         instr) for instr in _hist_kernel_calls(grow_compiled)]
-    assert len(stated) == 9 and all(stated)
+    assert len(stated) == 10 and all(stated)
     assert [int(m.group(1)) for m in stated] == [
         f for _, (_, f, _) in _kernel_labels(grow_compiled)]
 
@@ -710,7 +717,7 @@ def test_cost_analysis_still_counts_no_kernel(grow_compiled):
     PR 39), so it is left out: `observability/costmodel.py`'s `roofline`
     events go on reporting an MFU without the kernels (ROADMAP D2)."""
     kernels = sum(f * N for _, (_, f, _) in _kernel_labels(grow_compiled))
-    assert kernels == 13_617_152 * N
+    assert kernels == 15_454_208 * N
     assert grow_compiled.cost_analysis()["flops"] < 0.01 * kernels
 
 

@@ -48,6 +48,17 @@ def test_wide_kernel_checks_pass_in_interpret_mode(interpret_pallas):
     assert run_wide_checks(n=1024, F=200) == "ok"
 
 
+def test_narrow_kernel_checks_pass_in_interpret_mode(interpret_pallas):
+    """tools/kernel_checks.py --narrow, which runs on the chip at the
+    missing-value cell's 968 columns and at 28 x 255 bins: here at the
+    interpreter's size, one classed table and one unclassed, each at
+    64, 128 and 256 live slots of a 256-slot bound."""
+    from tools.kernel_checks import time_narrow
+    shapes = (("classed", (16, 63, 32, 16, 63, 9, 30, 63), 63, 1024, True),
+              ("one_class", (255,) * 3, 255, 1024, False))
+    assert time_narrow(shapes, calls=1) == "ok"
+
+
 @pytest.mark.parametrize("num_slots", [1, 2, 4, 8, 64, 255])
 def test_wave_kernels_equal_numpy_exactly(interpret_pallas, num_slots):
     """One operand contract — binned [F, n], slot [n], gh [C+1, n] with
@@ -300,6 +311,60 @@ def test_booster_hands_the_class_ordered_bins_to_every_tree(
             assert entry._cache_size() - lowered_before == 1
         models.append(booster.model_to_string())
     assert models[0] == models[1]
+
+
+# --------------------- a wave's call at the width of its live slots
+@pytest.mark.parametrize("classed", [False, True],
+                         ids=["one_class", "three_classes"])
+@pytest.mark.parametrize("num_slots,live", [
+    (num_slots, live) for num_slots in (128, 256)
+    for live in (1, 63, 64, 65, 127, 128, 129, 191) if live <= num_slots])
+def test_wave_call_at_its_live_slots_equals_the_full_call(
+        interpret_pallas, num_slots, live, classed):
+    """`wave_histograms` handed the wave's live slot count runs its call
+    at the narrowest of 64 / 128 / ... / `num_slots` that holds them and
+    pads back: histograms and counts bit for bit those of the call at the
+    full bound, on rows whose slots are compact ranks below `live` or the
+    out-of-range sentinel — one column class, and the missing-value
+    cell's three (16 / 32 / 64 codes) in a mixed column order."""
+    from lightgbm_tpu.ops.histogram import (class_ordered, hist_classes_of,
+                                            narrow_widths, wave_histograms,
+                                            wave_slot_pad)
+    n = 1024                                       # shapes of no other test
+    codes = np.array([63, 16, 32, 63, 9, 63, 30, 16] if classed
+                     else [47] * 5)
+    max_bin = int(codes.max())
+    rng = np.random.RandomState(live + num_slots)
+    binned = np.stack([rng.randint(0, c, n) for c in codes]).astype(np.uint8)
+    slot = np.where(rng.rand(n) < 0.8, rng.randint(0, live, n),
+                    wave_slot_pad(511)).astype(np.int32)
+    mask = (rng.rand(n) < 0.9).astype(np.float32)
+    gh = np.stack([rng.randint(-16, 17, n) / 8.0 * mask,
+                   rng.randint(1, 17, n) / 8.0 * mask, mask])
+    handed = {}
+    if classed:
+        classes, order = hist_classes_of(codes)
+        assert classes == ((16, 3), (32, 2), (64, 3))
+        handed = dict(hist_classes=classes,
+                      binned_classed=class_ordered(jnp.asarray(binned),
+                                                   jnp.asarray(order)),
+                      hist_inverse=jnp.asarray(np.argsort(order), jnp.int32))
+
+    @jax.jit
+    def call(slot, gh, live_slots=None):
+        return wave_histograms(jnp.asarray(binned), None, slot, gh,
+                               max_bin=max_bin, num_slots=num_slots,
+                               live_slots=live_slots, **handed)
+    args = (jnp.asarray(slot), jnp.asarray(gh, jnp.float32))
+    *want, full = call(*args)
+    *got, width = call(*args, jnp.asarray(live, jnp.int32))
+    assert int(full) == num_slots
+    assert int(width) == min(w for w in narrow_widths(num_slots) if w >= live)
+    for name, a, b in zip(("hist", "counts"), got, want):
+        np.testing.assert_array_equal(np.asarray(a).view(np.uint32),
+                                      np.asarray(b).view(np.uint32),
+                                      err_msg=name)
+    assert np.asarray(want[0]).any() and int(np.asarray(want[1]).sum()) > 0
 
 
 # ------------------- tools/hist_roof_probe.py: the chip probe's variants

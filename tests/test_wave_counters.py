@@ -1,6 +1,9 @@
 """The wave engine counts its own work: `TreeArrays.waves` (waves that
 ran: one full histogram pass over the rows each) rides the packed tree to
-the registry's `waves_total`, beside `trees_grown`."""
+the registry's `waves_total`, beside `trees_grown`; `narrowed_waves` and
+`slot_tiles` (waves whose histogram call ran narrower than the wave's
+static bound, and the 128-wide N tiles the calls ran) to
+`hist_narrowed_waves_total` and `hist_slot_tiles_total`."""
 
 import functools
 import math
@@ -99,3 +102,54 @@ def test_the_leafwise_engine_packs_zero_waves():
     assert bst._gbdt.growth_strategy == "leafwise"
     assert global_registry.counter("waves_total") == before
     np.testing.assert_allclose(bst.predict(X[:5]).shape, (5,))
+
+
+def test_a_chain_tail_wave_runs_at_its_live_slots(monkeypatch):
+    """87 leaves overgrown to 131 (`wave_prune`): the ladder's waves are
+    bounded by 8 to 64 slots and the chain-tail wave by 72; on 4,096 rows
+    at `min_data_in_leaf=20` the ladder falls short and the tail wave
+    computes the few splits the last wave made, so its call runs at 64
+    slots (one N tile, not two).  Each call's width, as the executed
+    `wave_histograms` returned it, is what the registry counts, and the
+    tiles fall below those of every call at its bound."""
+    from lightgbm_tpu.boosting import gbdt
+    from lightgbm_tpu.learner import plan_growth
+    monkeypatch.setattr(pl, "pallas_call",
+                        functools.partial(pl.pallas_call, interpret=True))
+    monkeypatch.setattr(gbdt, "plan_growth",
+                        lambda **kw: plan_growth(**{**kw, "backend": "tpu"}))
+    calls = []
+    real = wave.wave_histograms
+
+    def twin(*args, num_slots, **kw):
+        hist, cnt, width = real(*args, num_slots=num_slots, **kw)
+        jax.debug.callback(lambda w: calls.append((num_slots, int(w))),
+                           width)
+        return hist, cnt, width
+    monkeypatch.setattr(wave, "wave_histograms", twin)
+    rng = np.random.RandomState(11)
+    n, F = 4096, 6                                 # shapes of no other test
+    X = rng.rand(n, F).astype(np.float32)
+    y = (X[:, 0] + 0.3 * X[:, 1] * X[:, 2] + 0.3 * rng.randn(n) > 0.6)
+    names = ("waves_total", "hist_narrowed_waves_total",
+             "hist_slot_tiles_total", "trees_grown")
+    before = {k: global_registry.counter(k) for k in names}
+    bst = lgb.train({"objective": "binary", "num_leaves": 87, "max_bin": 31,
+                     "min_data_in_leaf": 20, "tpu_growth_strategy": "wave",
+                     "verbosity": -1},
+                    lgb.Dataset(X, label=y.astype(np.float32)),
+                    num_boost_round=1)
+    assert bst._gbdt.grow_params.hist_method == "pallas"
+    bst._gbdt._sync_model()
+    jax.effects_barrier()
+    got = {k: global_registry.counter(k) - v for k, v in before.items()}
+    assert got["trees_grown"] == 1
+    assert got["waves_total"] == len(calls) > math.ceil(math.log2(131))
+    narrowed = [(b, w) for b, w in calls if w < b]
+    assert narrowed and all((b, w) == (72, 64) for b, w in narrowed)
+    assert got["hist_narrowed_waves_total"] == len(narrowed)
+
+    def tiles(slots):
+        return -(-2 * slots // 128)
+    assert got["hist_slot_tiles_total"] == sum(tiles(w) for _, w in calls)
+    assert got["hist_slot_tiles_total"] < sum(tiles(b) for b, _ in calls)

@@ -94,6 +94,12 @@ classed where the cell is, and the decomposed one — at each cell's
 kernel shape and 1 / 2 / 4 / 8 true slots, compares their histograms
 bit for bit on operands whose sums are exact, and prints the winner
 beside the FLOP share the plan holds against `_HL_CROSSOVER`.
+
+`time_narrow()` (`python tools/kernel_checks.py --narrow`; two minutes)
+runs a 256-slot wave's call at the width of its live slots (64, 128, and
+the bound's 256 through the switch) against the call at the bound, at the
+missing-value cell's three classes (`[968, 1,048,576]`) and at
+`[28 x 256]` (2,625,536 rows): bits compared, ms a call of each.
 """
 import os
 import sys
@@ -387,7 +393,7 @@ def _classed_calls(binned, codes, max_bin):
     def call(extra):
         return lambda slot, gh, num_slots, **quant: wave_histograms(
             binned, None, slot, gh, max_bin=max_bin, num_slots=num_slots,
-            **quant, **extra)
+            **quant, **extra)[:2]
     return call(handed), call({})
 
 
@@ -1243,6 +1249,86 @@ def time_hl_crossover(shapes=HL_SHAPES, slot_counts=(1, 2, 4, 8), calls=10):
     return "ok" if not failures else "fail:" + ",".join(failures)
 
 
+# the missing-value cell's device columns by code count (225 of up to 16
+# codes, 17 of up to 32, 726 of 63: three histogram classes) and its
+# padded rows; the 255-bin Higgs cells' 28 columns, unclassed
+BOSCH_CODES = (16,) * 225 + (32,) * 17 + (63,) * 726
+NARROW_SHAPES = (
+    ("bosch63", BOSCH_CODES, 63, 1_048_576, True),
+    ("b255", (255,) * 28, 255, 2_625_536, False),
+)
+
+
+def time_narrow(shapes=NARROW_SHAPES, num_slots=256, lives=(64, 128, 256),
+                calls=10):
+    """A wave bounded by `num_slots` whose live slots are fewer runs its
+    call at the narrowest width that holds them (`wave_histograms`'s
+    `live_slots`): at each shape, with the rows' slots below each of
+    `lives`, the call through the switch (64- and 128-wide branches, and
+    the bound's own) against the call at the bound without it —
+    histograms and counts bit for bit on operands whose sums are exact
+    in any order, and ms a call of each.  Each side is one program a
+    shape, the operands its arguments.  Returns "ok" or "fail:<which>";
+    the readings go to stderr."""
+    import functools
+    import time
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.histogram import (class_ordered, hist_classes_of,
+                                            wave_histograms)
+
+    def ms_a_call(fn, *args):
+        out = jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            again = fn(*args)
+        jax.block_until_ready(again)
+        return out, (time.perf_counter() - t0) / calls * 1e3
+
+    @functools.partial(jax.jit, static_argnames=("max_bin", "hist_classes"))
+    def call(binned, binned_classed, hist_inverse, slot, gh, live, *,
+             max_bin, hist_classes):
+        handed = (dict(hist_classes=hist_classes,
+                       binned_classed=binned_classed,
+                       hist_inverse=hist_inverse) if hist_classes else {})
+        return wave_histograms(binned, None, slot, gh, max_bin=max_bin,
+                               num_slots=num_slots, live_slots=live,
+                               **handed)
+
+    failures = []
+    for name, codes, max_bin, n, classed in shapes:
+        classes, order = hist_classes_of(codes)
+        binned, slot, gh = _hl_operands(codes, n, num_slots)
+        kw = dict(max_bin=max_bin, hist_classes=classes if classed else ())
+        fixed = ((class_ordered(binned, jnp.asarray(order)),
+                  jnp.asarray(np.argsort(order), jnp.int32))
+                 if classed else (None, None))
+        for live in lives:
+            line = (f"narrow {name} [{len(codes)}, {n}] "
+                    f"bound={num_slots} live={live}")
+            try:
+                args = (binned, *fixed, slot % live, gh)
+                (h, c, width), ms = ms_a_call(
+                    functools.partial(call, **kw), *args,
+                    jnp.asarray(live, jnp.int32))
+                (hf, cf, _), full_ms = ms_a_call(
+                    functools.partial(call, **kw), *args, None)
+                differ = [_bits_differ(h, hf), _bits_differ(c, cf)]
+                print(f"{line}: ran at {int(width)} slots {ms:.3f} ms, "
+                      f"at the bound {full_ms:.3f} ms a call; entries whose "
+                      f"bits differ (sums, counts) {differ}",
+                      file=sys.stderr, flush=True)
+                if any(differ) or not bool(jnp.any(hf)):
+                    failures.append(f"narrow_differs_{name}_{live}")
+                if int(width) != live:
+                    failures.append(f"narrow_width_{name}_{live}")
+            except Exception as e:    # noqa: BLE001 - named in the verdict
+                traceback.print_exc()
+                failures.append(f"narrow_raised_{name}_{live}"
+                                f"({type(e).__name__})")
+    return "ok" if not failures else "fail:" + ",".join(failures)
+
+
 if __name__ == "__main__":
     if "--score-lookup" in sys.argv[1:]:
         time_score_lookup()
@@ -1259,6 +1345,8 @@ if __name__ == "__main__":
         time_roof()
     elif "--hl" in sys.argv[1:]:
         print(time_hl_crossover())
+    elif "--narrow" in sys.argv[1:]:
+        print(time_narrow())
     else:
         print(run_wide_checks() if "--wide" in sys.argv[1:]
               else run_checks())
